@@ -4,7 +4,7 @@
 
 SHELL := /bin/bash
 
-.PHONY: verify selftest check smoke lint sanitize-smoke serve-smoke spec-smoke chaos-smoke tune-smoke pod-smoke overlap-smoke fleet-smoke disagg-smoke prefix-smoke autoscale-smoke trace-smoke guard-smoke sim-smoke controlplane-smoke
+.PHONY: verify selftest check smoke chip-smoke lint sanitize-smoke serve-smoke spec-smoke chaos-smoke tune-smoke pod-smoke overlap-smoke fleet-smoke disagg-smoke prefix-smoke autoscale-smoke trace-smoke guard-smoke sim-smoke controlplane-smoke
 
 # Tier-1 tests — verbatim from ROADMAP.md ("Tier-1 verify"). The lint,
 # sanitize-smoke, serve-smoke, spec-smoke, chaos-smoke, tune-smoke,
@@ -20,6 +20,15 @@ SHELL := /bin/bash
 # itself.
 verify: lint sanitize-smoke serve-smoke spec-smoke chaos-smoke tune-smoke pod-smoke overlap-smoke fleet-smoke disagg-smoke prefix-smoke autoscale-smoke trace-smoke guard-smoke sim-smoke controlplane-smoke
 	set -o pipefail; rm -f /tmp/_t1.log; timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=$${PIPESTATUS[0]}; echo DOTS_PASSED=$$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$$' /tmp/_t1.log | tr -cd . | wc -c); exit $$rc
+
+# The chip check: the main path once, at the full width of the 110M LM, on a
+# TPU — every Pallas kernel under Mosaic, the LM trainer, the serving
+# selftest and the checkpoint hand-off (chip_smoke.py's docstring). It has no
+# CPU mode and exits non-zero without a chip, so it is deliberately NOT a
+# prerequisite of `verify`. From a sandbox without an accelerator:
+#   chiprun -- python chip_smoke.py
+chip-smoke:
+	python chip_smoke.py
 
 # Static analysis gate (docs/ANALYSIS.md): dmt-lint enforces the repo's
 # JAX contracts (donation safety, zero-retrace, atomic IO, single-writer

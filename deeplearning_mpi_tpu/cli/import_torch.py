@@ -57,10 +57,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
-    if args.platform:
-        import jax
+    from deeplearning_mpi_tpu.runtime.bootstrap import select_platform
 
-        jax.config.update("jax_platforms", args.platform)
+    select_platform(args.platform)
 
     import jax
     import jax.numpy as jnp

@@ -176,9 +176,11 @@ def main(argv: list[str] | None = None) -> int:
         # The BHSD-native entry: Attention sees .layout == 'bhsd' and
         # projects q/k/v straight into the kernel layout — no BSHD round
         # trip in either pass (docs/PERF_ANALYSIS.md §8's transpose tax).
-        from deeplearning_mpi_tpu.ops.pallas import flash_attention_bhsd
+        # On a mesh of several devices the kernel runs under shard_map
+        # (GSPMD cannot partition a Mosaic call).
+        from deeplearning_mpi_tpu.parallel import make_flash_attention_fn
 
-        attention_fn = flash_attention_bhsd
+        attention_fn = make_flash_attention_fn(mesh)
     elif args.attention == "ring":
         from deeplearning_mpi_tpu.parallel import make_ring_attention_fn
 

@@ -49,7 +49,16 @@ __all__ = [
     "WarmupRegistry",
     "abstractify",
     "compile_program",
+    "mosaic_call_count",
 ]
+
+
+def mosaic_call_count(compiled: Any) -> int:
+    """Mosaic (Pallas TPU) custom calls in a compiled executable's HLO —
+    the evidence that a kernel was *taken*, not merely available: the Pallas
+    entry points return their XLA references without a word when a block
+    does not tile, and the interpreter lowers to plain HLO (count 0)."""
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
 def abstractify(tree: Any) -> Any:

@@ -509,6 +509,22 @@ def _allclose(a: jax.Array, b: jax.Array, dtype: Any) -> bool:
     )
 
 
+def _rejection(
+    fn: Callable[..., Any], args: tuple, oracle: jax.Array, dtype: Any
+) -> dict[str, str] | None:
+    """Why a candidate may not compete, or ``None``. A candidate the
+    compiler refuses (Mosaic's scoped-VMEM limit, a layout it cannot infer)
+    is recorded as ``"compile"`` with the reason — told apart from wrong
+    numerics and from merely slower — and does not end the search."""
+    try:
+        out = jax.block_until_ready(fn(*args))
+    except Exception as err:  # noqa: BLE001 — jaxlib/Mosaic raise their own types
+        return {"rejected": "compile", "error": " ".join(str(err).split())[:300]}
+    if not _allclose(out, oracle, dtype):
+        return {"rejected": "numerics"}
+    return None
+
+
 # -- flash attention ---------------------------------------------------------
 
 def attention_candidates(
@@ -568,10 +584,9 @@ def tune_flash_attention(
                 interpret=interpret,
             )
         )
-        if not _allclose(fn(q, k, v), oracle, dtype):
-            results.append(
-                {"block_q": bq, "block_k": bk, "rejected": "numerics"}
-            )
+        rejected = _rejection(fn, (q, k, v), oracle, dtype)
+        if rejected:
+            results.append({"block_q": bq, "block_k": bk, **rejected})
             continue
         secs = measure(fn, q, k, v, repeats=repeats)
         entry = {"block_q": bq, "block_k": bk, "seconds": secs}
@@ -655,10 +670,10 @@ def tune_flash_decode(
                 q, k_buf, v_buf, index, block=b, interpret=interpret
             )
         )
-        if not _allclose(fn(q, k_buf, v_buf, index), oracle, dtype):
+        rejected = _rejection(fn, (q, k_buf, v_buf, index), oracle, dtype)
+        if rejected:
             results.append(
-                {"schedule": "kernel", "block": fitted,
-                 "rejected": "numerics"}
+                {"schedule": "kernel", "block": fitted, **rejected}
             )
             continue
         secs = measure(fn, q, k_buf, v_buf, index, repeats=repeats)
@@ -762,10 +777,12 @@ def tune_decode_buckets(
                         0.0,
                     )
                 )
-                if not _allclose(fn(q, k_buf, v_buf, index), oracle, dtype):
+                rejected = _rejection(
+                    fn, (q, k_buf, v_buf, index), oracle, dtype
+                )
+                if rejected:
                     results.append(
-                        {"schedule": "kernel", "block": fitted,
-                         "rejected": "numerics"}
+                        {"schedule": "kernel", "block": fitted, **rejected}
                     )
                     continue
                 secs = measure(fn, q, k_buf, v_buf, index, repeats=repeats)

@@ -15,9 +15,9 @@ counts how often it actually saves a compile. This module is that owner:
   ``resilience/integrity.py``'s sha256 machinery), corrupt-entry
   quarantine, and size-bounded LRU eviction.
 
-Cache layout (jaxlib 0.4.x, verified on this toolchain): each executable
-is one ``jit_<name>-<hash>-cache`` file plus a ``-atime`` sibling the
-runtime touches on every cache READ — which is exactly the LRU signal
+Cache layout (jaxlib 0.9.0, re-checked against ``.jax_cache/``): each
+executable is one ``jit_<name>-<hash>-cache`` file plus a ``-atime`` sibling
+the runtime touches on every cache READ — which is exactly the LRU signal
 eviction wants, and exactly why the manifest covers only ``*-cache``
 files (the atime siblings legitimately change between verifications).
 """
@@ -40,7 +40,9 @@ __all__ = [
     "CACHE_SUFFIX",
     "CacheEntry",
     "CompileCache",
+    "DEFAULT_CACHE_DIR",
     "cache_dir",
+    "configure",
     "donation_safe",
     "enable",
 ]
@@ -53,6 +55,33 @@ ATIME_SUFFIX = "-atime"
 MANIFEST_NAME = "cache-manifest.json"
 #: Subdirectory corrupt entries are moved to (never deleted: evidence).
 QUARANTINE_DIR = "quarantine"
+
+
+#: Where the cache lives when ``JAX_COMPILATION_CACHE_DIR`` is unset: a fixed
+#: directory under the checkout (gitignored). The directory is part of the
+#: cache key, so it is never derived from ``tempfile``, a pid or the time —
+#: a cache that moves between runs never hits.
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def configure() -> Path:
+    """Place the persistent compilation cache; every entry point calls this
+    before its first backend use (``runtime.bootstrap.select_platform``).
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: jax has already read it, so nothing
+    is set in code and the operator's placement stands. Unset:
+    :data:`DEFAULT_CACHE_DIR`. Every program is cached however quickly it
+    compiled (a warm start should compile nothing) unless
+    ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` says otherwise."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        path = Path(env)
+    else:
+        path = DEFAULT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", str(path))
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 def cache_dir() -> Path | None:
@@ -102,9 +131,11 @@ def donation_safe(
     executing a cache-DESERIALIZED executable with donated inputs after an
     in-process orbax/tensorstore checkpoint restore corrupts the native
     heap — segfault or ``malloc()`` abort inside
-    ``ThunkExecutor::ProcessOutEdges`` (jaxlib 0.4.36; reproduced with a
-    30-line jit+orbax script; fresh-compiled executables and non-donating
-    deserialized ones are both immune). That sequence is exactly crash
+    ``ThunkExecutor::ProcessOutEdges`` (seen on jaxlib 0.4.36; fresh-compiled
+    executables and non-donating deserialized ones were both immune. A
+    restore-then-donated-step sketch ran clean 5/5 on jaxlib 0.9.0, but the
+    original script is not in the tree, so the veto stays as safety code
+    until a test retires it). That sequence is exactly crash
     auto-resume — train, crash, restore, retrain — under a warm compile
     cache, the configuration the test suite runs. Donation is a memory
     optimization, never semantics, so the guard costs only transient

@@ -221,11 +221,11 @@ def decode_attention(
             block = tuned_block
     if use_kernel:
         from deeplearning_mpi_tpu.ops.pallas.flash_decode import (
-            decode_block_fits,
             flash_decode,
+            kernel_decode_block,
         )
 
-        fitted = decode_block_fits(min(block, 1024), length)
+        fitted = kernel_decode_block(block, k_buf.shape, k_buf.dtype)
         if fitted is not None:
             return flash_decode(
                 q, k_buf, v_buf, index, block=fitted, window=window
@@ -349,11 +349,11 @@ def batched_decode_attention(
             block = tuned_block
     if use_kernel:
         from deeplearning_mpi_tpu.ops.pallas.flash_decode import (
-            decode_block_fits,
             flash_decode,
+            kernel_decode_block,
         )
 
-        fitted = decode_block_fits(min(block, 1024), length)
+        fitted = kernel_decode_block(block, k_buf.shape, k_buf.dtype)
         if fitted is not None:
             out = flash_decode(
                 q, k_buf, v_buf, jnp.maximum(index, 0), block=fitted,

@@ -72,16 +72,44 @@ def quantize_kv(buf: jax.Array) -> tuple[jax.Array, jax.Array]:
     return q.astype(jnp.int8), scales
 
 
+#: VMEM the K and V blocks may take, double-buffered (4 buffers). The v5e's
+#: scoped-VMEM limit is 16 MiB and a block is padded to its dtype's tile
+#: (Hkv 12 x D 64 in bf16 pads to 16 x 128: 4 KiB a row). At 1024 rows the
+#: buffers alone are 16 MiB and Mosaic refuses the kernel by 24 KB; at 512
+#: (8 MiB) it compiles in 6.6 s and runs in 16.7 ms a call; at 256 (4 MiB)
+#: it compiles in 1.8 s and runs in 2.4 ms (B8 L8192 bf16, one TPU v5 lite,
+#: chip run of PR 21) — the per-head strided slices of the block are relaid
+#: out on the VMEM stack beside it.
+_DECODE_KV_VMEM_BUDGET = 4 * 1024 * 1024
+
 #: Module default for the KV block — what an untuned :func:`flash_decode`
-#: call resolves to. A tuning DB entry for the buffer's exact (shape,
-#: dtype, backend) overrides it; an explicit ``block=`` kwarg overrides
-#: everything (``ops.attention`` passes the fitted block explicitly).
-DEFAULT_DECODE_BLOCK = 1024
+#: call resolves to: the most rows the budget above allows (a tile row is
+#: 4 KiB in every dtype), cut further by :func:`fit_decode_vmem` for wider
+#: heads. A tuning DB entry for the buffer's exact (shape, dtype, backend)
+#: overrides it; an explicit ``block=`` kwarg overrides everything and is
+#: used as given (a block Mosaic refuses is then a compile error, loudly).
+DEFAULT_DECODE_BLOCK = 256
+
+
+def fit_decode_vmem(block: int, kv_heads: int, head_dim: int, dtype) -> int:
+    """Halve ``block`` until the double-buffered, tile-padded K and V blocks
+    fit :data:`_DECODE_KV_VMEM_BUDGET` (halving keeps a power-of-two block a
+    divisor of the buffer it tiled)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 32 // itemsize  # rows of one (sublanes, 128) tile
+    row_bytes = (
+        -(-kv_heads // sublanes) * sublanes * -(-head_dim // 128) * 128
+        * itemsize
+    )
+    while block > 8 and 4 * block * row_bytes > _DECODE_KV_VMEM_BUDGET:
+        block //= 2
+    return block
 
 
 def resolve_decode_block(block: int | None, shape: tuple[int, ...], dtype) -> int:
     """Block resolution: explicit kwarg > tuning-DB ``flash_decode`` entry
-    for this ``[B, L, Hkv, D]`` buffer > module default. Never raises."""
+    for this ``[B, L, Hkv, D]`` buffer > the module default cut to VMEM
+    (:func:`fit_decode_vmem`). Never raises."""
     if block is not None:
         return block
     try:
@@ -94,7 +122,7 @@ def resolve_decode_block(block: int | None, shape: tuple[int, ...], dtype) -> in
             return int(tuned["block"])
     except Exception:
         pass
-    return DEFAULT_DECODE_BLOCK
+    return fit_decode_vmem(DEFAULT_DECODE_BLOCK, shape[2], shape[3], dtype)
 
 
 def _decode_kernel(
@@ -197,7 +225,7 @@ def flash_decode(
     only); returns ``[B, 1, H, D]``. Caller guarantees ``L % block == 0``
     (see :func:`decode_block_fits`). ``block=None`` resolves through
     :func:`resolve_decode_block` — a tuning-DB entry for this buffer shape
-    when installed, else the 1024 module default.
+    when installed, else the module default cut to what VMEM holds.
 
     ``index`` may be a scalar (every row at the same fill — the single-
     sequence CLI path) or ``[B]`` (per-row fills — continuous-batching
@@ -222,6 +250,11 @@ def flash_decode(
     block = resolve_decode_block(block, k_buf.shape, k_buf.dtype)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if block < 1 or length % block:
+        raise ValueError(
+            f"flash_decode: block {block} does not tile the {length}-row "
+            "cache buffer (see decode_block_fits)"
+        )
     n_blocks = length // block
     index = jnp.asarray(index, jnp.int32)
     if index.ndim == 0:
@@ -307,6 +340,16 @@ def flash_decode(
 _MIN_DECODE_BLOCK = 256
 
 
+def kernel_decode_block(block: int, shape: tuple[int, ...], dtype) -> int | None:
+    """The block the dispatchers hand the kernel for a ``[B, L, Hkv, D]``
+    buffer: ``block`` cut to VMEM (:func:`fit_decode_vmem`), then to a
+    divisor of ``L`` (:func:`decode_block_fits`); None = take the walk."""
+    _, length, kv_heads, head_dim = shape
+    return decode_block_fits(
+        fit_decode_vmem(block, kv_heads, head_dim, dtype), length
+    )
+
+
 def decode_block_fits(block: int, length: int) -> int | None:
     """Largest ``fit_block``-shrunk block that tiles ``length``, or None.
 
@@ -319,7 +362,7 @@ def decode_block_fits(block: int, length: int) -> int | None:
     b = fit_block(block, length)
     # Floor scales down with an explicitly small requested block (tests use
     # 16-row blocks on tiny buffers); the dispatcher's production request
-    # (1024) gets the full floor.
+    # gets the full floor.
     if length % b or b % 8 or b < min(_MIN_DECODE_BLOCK, block):
         return None
     return b

@@ -9,6 +9,9 @@ matrices over ``model``, sequence parallelism shards the token axis over
 """
 
 from deeplearning_mpi_tpu.parallel.expert_parallel import ep_spec  # noqa: F401
+from deeplearning_mpi_tpu.parallel.flash_sharded import (  # noqa: F401
+    make_flash_attention_fn,
+)
 from deeplearning_mpi_tpu.parallel.pipeline import (  # noqa: F401
     merge_microbatches,
     pipeline_apply,

@@ -18,10 +18,9 @@ Two implementations of the same semantics live here:
    (``lax.psum_scatter``), the optimizer update run on the 1/dp parameter
    and moment shards, the updated shards all-gathered back. Because each
    bucket is its own collective (instead of one fused GSPMD region), XLA's
-   latency-hiding scheduler (``runtime.compat.enable_latency_hiding``)
-   can slide bucket k's reduce-scatter under bucket k+1's gradient math and
-   the tail all-gathers under the next step's early forward once steps are
-   dispatched back-to-back.
+   latency-hiding scheduler can slide bucket k's reduce-scatter under
+   bucket k+1's gradient math and the tail all-gathers under the next
+   step's early forward once steps are dispatched back-to-back.
 
 The two paths are engineered to be **bit-identical** on CPU (asserted in
 ``tests/test_overlap.py`` and ``make overlap-smoke``), which pins down the

@@ -262,6 +262,23 @@ def scrub_rendezvous_env(env: MutableMapping[str, str]) -> None:
         env.pop(k, None)
 
 
+def workers_would_share_tpu(env: Mapping[str, str], workers: int) -> bool:
+    """Whether ``workers`` jax processes started on THIS host with ``env``
+    would each initialise the TPU. A chip belongs to one process at a time:
+    the spawners here hand every worker the same environment and assign no
+    chips, so the second worker dies or hangs on the device lock. Decided
+    without touching a backend — from ``JAX_PLATFORMS`` when the workers'
+    environment sets it, else from the chips jax itself would find."""
+    if workers < 2:
+        return False
+    platforms = env.get("JAX_PLATFORMS", "").strip()
+    if platforms:
+        return "tpu" in (p.strip().lower() for p in platforms.split(","))
+    from jax._src import hardware_utils
+
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0] > 0
+
+
 class LivenessTracker:
     """Pod-level liveness view over per-rank heartbeat payloads.
 

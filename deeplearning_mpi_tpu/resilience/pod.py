@@ -84,6 +84,7 @@ from deeplearning_mpi_tpu.resilience.cluster import (
     replay_journal,
     scrub_rendezvous_env,
     sigkill_group,
+    workers_would_share_tpu,
 )
 from deeplearning_mpi_tpu.resilience.faults import (
     ENV_RANK,
@@ -254,6 +255,14 @@ class PodSupervisor(ClusterSupervisor):
             # A world of one needs no rendezvous — and leftover coordinator
             # vars would make the lone survivor wait for peers forever.
             scrub_rendezvous_env(base)
+        if workers_would_share_tpu(base, world):
+            raise PodFailure(
+                f"refusing to spawn a world of {world} on this host: every "
+                "rank would initialise the TPU, and a chip belongs to one "
+                "process at a time. Run the ranks on CPU (JAX_PLATFORMS=cpu) "
+                "or one rank per host (one process drives all of a host's "
+                "chips)"
+            )
         procs: dict[int, subprocess.Popen] = {}
         handles: list[Any] = []
         for rank in range(world):

@@ -70,6 +70,18 @@ def _looks_like_tpu_pod() -> bool:
     return len([h for h in hostnames.split(",") if h.strip()]) > 1
 
 
+def select_platform(platform: str | None = None) -> None:
+    """Pre-backend set-up shared by every entry point: force ``platform``
+    when given (``"tpu"`` makes a TPU that fails to initialise an error
+    instead of a quiet CPU run) and place the compile cache
+    (``compiler.cache.configure``). Must run before the first backend use."""
+    from deeplearning_mpi_tpu.compiler import cache
+
+    if platform is not None:
+        jax.config.update("jax_platforms", platform)
+    cache.configure()
+
+
 def set_virtual_cpu_devices(n: int) -> None:
     """Force ``n`` fake CPU devices — the hardware-free multi-device path.
 
@@ -85,7 +97,7 @@ def set_virtual_cpu_devices(n: int) -> None:
     ]
     flags.append(f"--xla_force_host_platform_device_count={n}")
     os.environ["XLA_FLAGS"] = " ".join(flags)
-    jax.config.update("jax_platforms", "cpu")
+    select_platform("cpu")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,8 +141,7 @@ def init(
     """
     global _initialized_distributed
 
-    if platform is not None:
-        jax.config.update("jax_platforms", platform)
+    select_platform(platform)
 
     coordinator_address = coordinator_address or os.environ.get("COORDINATOR_ADDRESS")
     if num_processes is None and "NUM_PROCESSES" in os.environ:
@@ -159,10 +170,7 @@ def init(
             # default CPU backend has none) — the reference's gloo backend
             # switch, applied automatically so pod workers launched from a
             # plain training CLI just work.
-            try:
-                jax.config.update("jax_cpu_collectives_implementation", "gloo")
-            except Exception:  # noqa: BLE001 — older jax: flag absent
-                pass
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         # With all-None args on a TPU pod, jax auto-discovers topology from
         # TPU metadata — the no-flag path for real slices.
         jax.distributed.initialize(

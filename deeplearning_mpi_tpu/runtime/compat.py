@@ -1,21 +1,14 @@
-"""Version shims for the JAX APIs this codebase uses across releases.
+"""One spelling of the JAX APIs this codebase routes through a seam.
 
-The codebase targets the current JAX surface (``jax.shard_map`` with
-``check_vma``/``axis_names``, ``lax.pcast``, ``pltpu.CompilerParams``); on
-jax 0.4.x those names live elsewhere or don't exist yet
-(``jax.experimental.shard_map.shard_map`` with ``check_rep``/``auto``,
-no ``pcast``, ``pltpu.TPUCompilerParams``). One shim module resolves each
-name once at import and every call site routes through it, so the rest of
-the tree never version-checks:
+Every call site uses the names below rather than reaching into jax
+directly, so an upstream rename lands in one file:
 
-- :func:`shard_map` — the new keyword surface everywhere. ``check_vma``
-  maps to 0.4.x's ``check_rep``; ``axis_names`` (manual-over-these-axes)
-  maps to its complement ``auto`` (automatic-over-those-axes).
-- :func:`pcast` — varying-type casts exist only under the VMA checker;
-  where ``lax.pcast`` is absent the rep checker needs no cast and the
-  shim is an identity.
-- :func:`tpu_compiler_params` — the Pallas TPU compiler-params dataclass
-  under whichever of its two names this JAX exports.
+- :func:`shard_map` — ``jax.shard_map`` with ``check_vma`` / ``axis_names``
+  passed only when given (``None`` takes the library default).
+- :func:`axis_size` / :func:`pcast` — ``lax.axis_size`` and a tree-mapped
+  ``lax.pcast``.
+- :func:`tpu_compiler_params` — the Pallas TPU compiler-params dataclass.
+- :func:`buffer_donation_supported` — the compile-cache donation veto.
 """
 
 from __future__ import annotations
@@ -28,46 +21,10 @@ from jax import lax
 __all__ = [
     "axis_size",
     "buffer_donation_supported",
-    "enable_latency_hiding",
-    "LATENCY_HIDING_FLAGS",
     "pcast",
     "shard_map",
     "tpu_compiler_params",
 ]
-
-#: XLA flags that let the scheduler slide the explicit ZeRO-1 collectives
-#: (parallel.zero.make_overlapped_train_step's per-bucket reduce-scatters
-#: and tail all-gathers) under independent compute. No-ops on CPU.
-LATENCY_HIDING_FLAGS = (
-    "--xla_tpu_enable_latency_hiding_scheduler=true",
-    "--xla_tpu_enable_async_collective_fusion=true",
-)
-
-
-def enable_latency_hiding(flags: tuple[str, ...] = LATENCY_HIDING_FLAGS) -> bool:
-    """Merge latency-hiding-scheduler flags into ``XLA_FLAGS``.
-
-    Same merge idiom as ``runtime.bootstrap.set_virtual_cpu_devices``: any
-    existing setting of the same flag key is replaced, everything else in
-    ``XLA_FLAGS`` is preserved. Must run before the first backend use to
-    affect this process (XLA reads the env at backend init); it is still
-    worth calling late for the benefit of spawned workers, so the return
-    value reports whether the backend had already initialized (False =
-    too late for this process). Best-effort by design — callers never gate
-    correctness on it.
-    """
-    import os
-
-    existing = os.environ.get("XLA_FLAGS", "").split()
-    keys = {f.split("=", 1)[0] for f in flags}
-    kept = [f for f in existing if f.split("=", 1)[0] not in keys]
-    os.environ["XLA_FLAGS"] = " ".join(kept + list(flags))
-    try:
-        from jax._src import xla_bridge
-
-        return not xla_bridge._backends  # noqa: SLF001 — introspection only
-    except Exception:  # noqa: BLE001 — unknown JAX internals: assume in time
-        return True
 
 
 def buffer_donation_supported() -> bool:
@@ -85,12 +42,6 @@ def buffer_donation_supported() -> bool:
 
     return donation_safe()
 
-_NEW_SHARD_MAP = getattr(jax, "shard_map", None)
-if _NEW_SHARD_MAP is None:
-    from jax.experimental.shard_map import shard_map as _OLD_SHARD_MAP
-else:
-    _OLD_SHARD_MAP = None
-
 
 def shard_map(
     f,
@@ -101,54 +52,31 @@ def shard_map(
     check_vma: bool | None = None,
     axis_names: Any = None,
 ):
-    """``jax.shard_map`` with the current keyword surface on every JAX.
-
-    ``axis_names`` (when given) is the set of mesh axes the function is
-    MANUAL over — the new-API meaning; on 0.4.x it becomes the complement
-    ``auto`` set. ``check_vma=None`` takes the library default.
-    """
-    if _NEW_SHARD_MAP is not None:
-        kw: dict[str, Any] = {}
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return _NEW_SHARD_MAP(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-    kw = {}
+    """``jax.shard_map``. ``axis_names`` (when given) is the set of mesh
+    axes the function is MANUAL over; ``check_vma=None`` takes the library
+    default."""
+    kw: dict[str, Any] = {}
     if check_vma is not None:
-        kw["check_rep"] = check_vma
+        kw["check_vma"] = check_vma
     if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _OLD_SHARD_MAP(
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
     )
 
 
 def axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis, inside shard_map/pmap bodies.
-
-    ``lax.axis_size`` where it exists; otherwise ``lax.psum(1, axis)``,
-    which constant-folds to a Python int for non-tracer operands on 0.4.x.
-    """
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static size of a named mesh axis, inside shard_map/pmap bodies."""
+    return lax.axis_size(axis_name)
 
 
 def pcast(x, axis_names, *, to: str = "varying"):
-    """``lax.pcast`` where it exists; identity where the VMA type system
-    (and therefore the cast) doesn't."""
-    if hasattr(lax, "pcast"):
-        return jax.tree.map(lambda a: lax.pcast(a, tuple(axis_names), to=to), x)
-    return x
+    """``lax.pcast`` over every leaf of ``x``."""
+    return jax.tree.map(lambda a: lax.pcast(a, tuple(axis_names), to=to), x)
 
 
 def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` / ``pltpu.TPUCompilerParams`` — renamed
-    between releases; same fields (``dimension_semantics`` et al.)."""
+    """``pltpu.CompilerParams`` (``dimension_semantics`` et al.)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from typing import Any
 
 import jax
 import numpy as np
@@ -133,6 +134,16 @@ def create_mesh(
         devices = jax.devices()
     shape = spec.resolve(len(devices))
     return Mesh(order_devices_for_mesh(devices, shape), MESH_AXES)
+
+
+def occupied_devices(tree: Any) -> int:
+    """How many devices the arrays of ``tree`` sit on. Not
+    ``jax.device_count()``: work placed on one chip of a four-chip host
+    occupies one, and a rate divided by four would be a quarter of the
+    truth."""
+    return len({
+        d for leaf in jax.tree.leaves(tree) for d in leaf.sharding.device_set
+    })
 
 
 def data_axes(mesh: Mesh) -> tuple[str, ...]:

@@ -77,6 +77,7 @@ from deeplearning_mpi_tpu.resilience.cluster import (
     replay_journal,
     scrub_rendezvous_env,
     tail_jsonl,
+    workers_would_share_tpu,
 )
 
 __all__ = ["FleetFailure", "FleetResult", "FleetSupervisor", "worker_main"]
@@ -133,6 +134,8 @@ def worker_main(argv: list[str] | None = None) -> int:
     from deeplearning_mpi_tpu.serving.scheduler import RequestState
     from deeplearning_mpi_tpu.telemetry import MetricsRegistry
 
+    from deeplearning_mpi_tpu.runtime import bootstrap
+
     rdir = Path(args.dir)
     spec = json.loads(Path(args.spec).read_text())
     # Topology keys ride next to (not inside) the engine kwargs dict.
@@ -141,11 +144,9 @@ def worker_main(argv: list[str] | None = None) -> int:
     if tp > 1 and os.environ.get("JAX_PLATFORMS", "") == "cpu":
         # Hardware-free TP: fake CPU devices, forced BEFORE the first
         # backend use (model.init below initializes it).
-        from deeplearning_mpi_tpu.runtime.bootstrap import (
-            set_virtual_cpu_devices,
-        )
-
-        set_virtual_cpu_devices(tp)
+        bootstrap.set_virtual_cpu_devices(tp)
+    else:
+        bootstrap.select_platform()
     cfg = TransformerConfig(**spec["model"])
     model = TransformerLM(config=cfg, dtype=jnp.float32)
 
@@ -716,6 +717,17 @@ class FleetSupervisor(ClusterSupervisor):
         # A replica is a lone process — leftover rendezvous vars from a
         # surrounding pod run would make its jax runtime wait for peers.
         scrub_rendezvous_env(env)
+        fleet_max = max(
+            self.num_replicas,
+            self.autoscale.max_replicas if self.autoscale else 1,
+        )
+        if workers_would_share_tpu(env, fleet_max):
+            raise FleetFailure(
+                f"refusing to spawn replica {rep.idx}: up to {fleet_max} "
+                "replica processes on this host would each initialise the "
+                "TPU, and a chip belongs to one process at a time. Run the "
+                "replicas on CPU (JAX_PLATFORMS=cpu) or one replica per host"
+            )
         log_path = self.fleet_dir / f"replica{rep.idx}-a{rep.attempt}.log"
         rep.log = log_path.open("w")  # dmt-lint: disable=DMT004 — stdout capture stream, not a consumed JSON artifact
         rep.proc = subprocess.Popen(

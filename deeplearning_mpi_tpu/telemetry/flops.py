@@ -19,11 +19,13 @@ the kernels here (`ops/pallas/flash_attention.py` trimmed grids,
 half, so counting full S² would overstate MFU on exactly the paths this
 repo optimized.
 
-Peak FLOPs per device come from a small table of TPU generations (bf16
-peak, the training dtype) with a ``DMT_PEAK_FLOPS`` env override. On CPU
-there is no meaningful peak; a nominal constant keeps MFU *defined* (the
-report needs a non-null column and relative comparisons across runs on the
-same host are still valid) and the override makes it honest if anyone
+Peak FLOPs per device come from one table of TPU generations (bf16 peak,
+the training dtype), reached through the ``device_kind`` strings the runtime
+actually reports (:data:`TPU_KINDS`), with a ``DMT_PEAK_FLOPS`` env override.
+A TPU whose kind is not in the table is an error, not a guessed generation.
+On CPU there is no meaningful peak; a nominal constant keeps MFU *defined*
+(the report needs a non-null column and relative comparisons across runs on
+the same host are still valid) and the override makes it honest if anyone
 calibrates their machine.
 """
 
@@ -42,6 +44,22 @@ PEAK_FLOPS: dict[str, float] = {
     "v5e": 197e12,
     "v5p": 459e12,
     "v6e": 918e12,
+}
+
+#: ``device_kind`` (lower-cased) as the runtime reports it → generation key
+#: of the tables here. The strings are the ones jax itself switches on
+#: (``jax/_src/pallas/mosaic/tpu_info.py``): a v5e reports "TPU v5 lite",
+#: not "v5e".
+TPU_KINDS: dict[str, str] = {
+    "tpu v2": "v2",
+    "tpu v3": "v3",
+    "tpu v4": "v4",
+    "tpu v5 lite": "v5e",
+    "tpu v5e": "v5e",
+    "tpu v5": "v5p",
+    "tpu v5p": "v5p",
+    "tpu v6 lite": "v6e",
+    "tpu v6e": "v6e",
 }
 
 #: Nominal CPU "peak" — a few AVX cores' worth. Arbitrary but stable, so
@@ -66,24 +84,34 @@ LINK_BANDWIDTH: dict[str, float] = {
 CPU_NOMINAL_LINK_BANDWIDTH = 10e9
 
 
+def _tpu_generation(device: Any) -> str | None:
+    """Generation key for a TPU device, ``None`` for any other platform;
+    raises on a TPU kind the tables do not know."""
+    if getattr(device, "platform", "") != "tpu":
+        return None
+    kind = getattr(device, "device_kind", "")
+    try:
+        return TPU_KINDS[kind.lower()]
+    except KeyError:
+        raise ValueError(
+            f"unknown TPU device_kind {kind!r}: add it to "
+            "telemetry.flops.TPU_KINDS with its spec-sheet peaks (or set "
+            "DMT_PEAK_FLOPS / DMT_LINK_BANDWIDTH)"
+        ) from None
+
+
 def device_peak_flops(device: Any | None = None) -> float:
     """Peak FLOPs/s for ``device`` (default: first local device).
 
     Resolution order: ``DMT_PEAK_FLOPS`` env var (calibrated override) →
-    TPU generation table via ``device_kind`` → CPU nominal constant.
+    TPU generation table via ``device_kind`` (unknown kind raises) → CPU
+    nominal constant.
     """
     env = os.environ.get("DMT_PEAK_FLOPS")
     if env:
         return float(env)
-    if device is None:
-        device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for gen, peak in PEAK_FLOPS.items():
-        if gen in kind.replace(" ", ""):
-            return peak
-    if getattr(device, "platform", "") == "tpu":
-        return PEAK_FLOPS["v4"]  # unknown TPU: assume mid-generation
-    return CPU_NOMINAL_PEAK_FLOPS
+    gen = _tpu_generation(device if device is not None else jax.devices()[0])
+    return PEAK_FLOPS[gen] if gen else CPU_NOMINAL_PEAK_FLOPS
 
 
 def device_link_bandwidth(device: Any | None = None) -> float:
@@ -95,15 +123,8 @@ def device_link_bandwidth(device: Any | None = None) -> float:
     env = os.environ.get("DMT_LINK_BANDWIDTH")
     if env:
         return float(env)
-    if device is None:
-        device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for gen, bw in LINK_BANDWIDTH.items():
-        if gen in kind.replace(" ", ""):
-            return bw
-    if getattr(device, "platform", "") == "tpu":
-        return LINK_BANDWIDTH["v4"]
-    return CPU_NOMINAL_LINK_BANDWIDTH
+    gen = _tpu_generation(device if device is not None else jax.devices()[0])
+    return LINK_BANDWIDTH[gen] if gen else CPU_NOMINAL_LINK_BANDWIDTH
 
 
 def overlap_fraction(
@@ -151,17 +172,14 @@ def xla_cost_analysis(compiled: Any) -> dict[str, float]:
     and masked work, so comparing the two bounds the overhead).
 
     Accepts a ``jax.stages.Compiled`` (``compiler/aot.py`` passes one per
-    warmed program). jaxlib 0.4.x returns a list of one dict keyed
-    ``'flops'`` / ``'bytes accessed'``; newer jax returns the dict
-    directly — both are handled. Returns ``{}`` where the backend exposes
-    nothing (keys absent, never faked — same convention as ``hbm_usage``).
+    warmed program); the dict is keyed ``'flops'`` / ``'bytes accessed'``.
+    Returns ``{}`` where the backend exposes nothing (keys absent, never
+    faked — same convention as ``hbm_usage``).
     """
     try:
         ca = compiled.cost_analysis()
     except Exception:
         return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     if not isinstance(ca, dict):
         return {}
     out: dict[str, float] = {}

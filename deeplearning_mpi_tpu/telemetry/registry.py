@@ -18,7 +18,7 @@ Design constraints, in order:
    them; a sink must never raise into the training loop (JSONL write
    failures degrade to a dropped record, not a dead run).
 
-Instruments follow the Prometheus taxonomy because it is the vocabulary
+Instruments follow the Prometheus naming because it is the vocabulary
 every operator already knows: ``Counter`` (monotonic, ``inc``), ``Gauge``
 (set-to-current), ``Histogram`` (observations + percentile summary).
 """

@@ -44,6 +44,9 @@ METRICS: dict[str, tuple[str, frozenset[str]]] = {
     "compile_cache_quarantined_total": ("counter", frozenset()),
     "compile_seconds": ("histogram", frozenset()),
     "train_compile_seconds": ("gauge", frozenset()),
+    "train_batch_devices": ("gauge", frozenset()),
+    "train_state_devices": ("gauge", frozenset()),
+    "train_step_mosaic_calls": ("gauge", frozenset()),
     "xla_bytes_per_step": ("gauge", frozenset()),
     "xla_flops_per_step": ("gauge", frozenset()),
     # -- serving engine (PR 2/7/9, serving/) --------------------------------

@@ -468,18 +468,9 @@ class Checkpointer:
         # would fall back to the shardings recorded at save time (wrong
         # topology for --tp serving of a 1-device-trained checkpoint).
         restore_args = ocp.checkpoint_utils.construct_restore_args(item)
-        try:
-            args = ocp.args.PyTreeRestore(
-                item=item, restore_args=restore_args, partial_restore=True
-            )
-        except TypeError:
-            # orbax < 0.11 has no partial_restore; empty transforms with
-            # the default transforms_default_to_original is its spelling of
-            # "restore the item subtree from the saved values, ignore the
-            # rest" (the opt_state this method exists to skip).
-            args = ocp.args.PyTreeRestore(
-                item=item, restore_args=restore_args, transforms={}
-            )
+        args = ocp.args.PyTreeRestore(
+            item=item, restore_args=restore_args, partial_restore=True
+        )
         restored = self.manager.restore(epoch, args=args)
         return template.replace(**restored)
 

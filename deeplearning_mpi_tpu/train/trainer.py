@@ -38,6 +38,7 @@ from jax.sharding import Mesh
 from deeplearning_mpi_tpu.data.loader import prefetch
 from deeplearning_mpi_tpu.resilience.preemption import Preempted
 from deeplearning_mpi_tpu.runtime.compat import buffer_donation_supported
+from deeplearning_mpi_tpu.runtime.mesh import occupied_devices
 from deeplearning_mpi_tpu.models.moe import (
     AUX_COLLECTION,
     METRIC_COLLECTION,
@@ -698,7 +699,10 @@ class Trainer:
         gauge, ``compile_cache_{hit,miss}_total`` counters (via the
         ``CompileCache`` built here or passed in), and — when XLA's cost
         analysis yields them — ``xla_flops_per_step`` / ``xla_bytes_per_step``
-        gauges. When the caller gave no analytic ``flops_per_step``, the XLA
+        gauges, plus ``train_step_mosaic_calls`` (Pallas kernels the compiled
+        step really holds; 0 off-TPU or when flash fell back to dense) and
+        ``train_state_devices`` / ``train_batch_devices`` (devices the params
+        and the batch occupy). When the caller gave no analytic ``flops_per_step``, the XLA
         count backfills it so epoch MFU appears without manual accounting.
 
         Call AFTER :meth:`place_state` — placement may rebuild the step, and
@@ -724,6 +728,15 @@ class Trainer:
                 self.issued_flops_per_step = prog.flops
         if prog.bytes_accessed:
             self.metrics.gauge("xla_bytes_per_step").set(prog.bytes_accessed)
+        self.metrics.gauge("train_step_mosaic_calls").set(
+            aot.mosaic_call_count(prog.compiled)
+        )
+        # Devices the params and the batch really occupy: a run on four
+        # chips whose state sits on one is then visible in the record.
+        self.metrics.gauge("train_state_devices").set(
+            occupied_devices(self.state.params)
+        )
+        self.metrics.gauge("train_batch_devices").set(occupied_devices(batch))
         self.train_step = aot.WarmProgram(prog, self.train_step)
         self._log(
             f"warmup: train_step compiled in {prog.compile_seconds:.2f}s "

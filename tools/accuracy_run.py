@@ -80,10 +80,9 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument("--platform", default=None, choices=("cpu", "tpu"))
     args = parser.parse_args(argv)
 
-    if args.platform:
-        import jax
+    from deeplearning_mpi_tpu.runtime.bootstrap import select_platform
 
-        jax.config.update("jax_platforms", args.platform)
+    select_platform(args.platform)
 
     import jax
     import jax.numpy as jnp

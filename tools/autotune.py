@@ -193,57 +193,49 @@ def selftest() -> int:
         finally:
             autotune.set_default_db(None)
 
-        # 4. Warm-engine contract under one persistent compile cache: the
-        # second engine's warmup deserializes (cache hits) and its first
-        # request triggers zero compiles (the trace counter stays put).
-        prev_dir = jax.config.jax_compilation_cache_dir
-        prev_min = jax.config.jax_persistent_cache_min_compile_time_secs
-        try:
-            ccache.enable(Path(td) / "xla_cache")
-            cfg = TransformerConfig.tiny()
-            model = TransformerLM(config=cfg, dtype=jnp.float32)
-            params = model.init(
-                jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
-            )["params"]
-            eng_cfg = EngineConfig(
-                max_slots=2, block_size=8, num_blocks=16,
-                max_blocks_per_seq=4, prefill_chunk=8, max_queue=8,
-            )
+        # 4. Warm-engine contract under the persistent compile cache main()
+        # placed ($JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache —
+        # never a temp dir: the path is part of the cache key): a second
+        # engine's warmup deserializes (cache hits) and its first request
+        # triggers zero compiles (the trace counter stays put).
+        cfg = TransformerConfig.tiny()
+        model = TransformerLM(config=cfg, dtype=jnp.float32)
+        params = model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
+        )["params"]
+        eng_cfg = EngineConfig(
+            max_slots=2, block_size=8, num_blocks=16,
+            max_blocks_per_seq=4, prefill_chunk=8, max_queue=8,
+        )
 
-            def make_engine():
-                registry = MetricsRegistry()
-                engine = ServingEngine(
-                    cfg, params, eng_cfg,
-                    dtype=jnp.float32, registry=registry,
-                )
-                engine.warmup(cache=ccache.CompileCache(registry=registry))
-                return engine, registry
+        def make_engine():
+            registry = MetricsRegistry()
+            engine = ServingEngine(
+                cfg, params, eng_cfg,
+                dtype=jnp.float32, registry=registry,
+            )
+            engine.warmup(cache=ccache.CompileCache(registry=registry))
+            return engine, registry
 
-            make_engine()  # cold: populates the persistent cache
-            engine, registry = make_engine()  # warm: must hit
-            hits = registry.counter("compile_cache_hit_total").value
-            check(hits > 0, f"warm engine start: compile_cache_hit_total={hits}")
+        make_engine()  # populates the persistent cache if it was cold
+        engine, registry = make_engine()  # warm: must hit
+        hits = registry.counter("compile_cache_hit_total").value
+        check(hits > 0, f"warm engine start: compile_cache_hit_total={hits}")
 
-            before = registry.counter("serve_compile_total").value
-            req = engine.submit(np.arange(1, 9, dtype=np.int32), 4)
-            while not engine.scheduler.idle():
-                engine.step()
-            after = registry.counter("serve_compile_total").value
-            check(
-                req.state is RequestState.FINISHED,
-                f"first request finished ({len(req.generated)} tokens)",
-            )
-            check(
-                after == before,
-                f"zero compiles on first request "
-                f"(serve_compile_total {before} -> {after})",
-            )
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", prev_min
-            )
-            ccache._reset_backend_cache()  # un-pin the tmp dir
+        before = registry.counter("serve_compile_total").value
+        req = engine.submit(np.arange(1, 9, dtype=np.int32), 4)
+        while not engine.scheduler.idle():
+            engine.step()
+        after = registry.counter("serve_compile_total").value
+        check(
+            req.state is RequestState.FINISHED,
+            f"first request finished ({len(req.generated)} tokens)",
+        )
+        check(
+            after == before,
+            f"zero compiles on first request "
+            f"(serve_compile_total {before} -> {after})",
+        )
 
         # 5. Whole-step schedule tuning: two candidates, oracle-first loss
         # verification, persisted winner, never-raise consult semantics.
@@ -335,10 +327,9 @@ def selftest() -> int:
 
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
-    if args.platform:
-        import jax
+    from deeplearning_mpi_tpu.runtime.bootstrap import select_platform
 
-        jax.config.update("jax_platforms", args.platform)
+    select_platform(args.platform)
     if args.virtual_devices:
         # Must precede first backend use — bootstrap refuses otherwise.
         from deeplearning_mpi_tpu.runtime import bootstrap

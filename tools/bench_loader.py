@@ -58,10 +58,9 @@ def main() -> None:
     ap.add_argument("--workers", type=int, nargs="+", default=[0, 2, 4, 8])
     args = ap.parse_args()
 
-    import jax
+    from deeplearning_mpi_tpu.runtime.bootstrap import select_platform
 
-    if args.platform == "cpu":
-        jax.config.update("jax_platforms", "cpu")
+    select_platform(args.platform)
 
     import os
     import tempfile
