@@ -92,6 +92,7 @@ from deeplearning_mpi_tpu.serving.scheduler import (
     RequestState,
     Scheduler,
 )
+from deeplearning_mpi_tpu.telemetry.trace import annotate, span
 
 __all__ = ["EngineConfig", "KVBuffers", "PagedForward", "ServingEngine"]
 
@@ -246,21 +247,22 @@ class PagedForward:
         per-row scales — data and scales land in ONE jitted program, which
         is what makes the pool's scale/block epoch check a real invariant
         rather than a race window."""
-        if not self.quantized:
-            k_pool, v_pool = kv
+        with annotate("attn/kv_scatter"):
+            if not self.quantized:
+                k_pool, v_pool = kv
+                return (
+                    k_pool.at[i, bid, off].set(k.astype(k_pool.dtype)),
+                    v_pool.at[i, bid, off].set(v.astype(v_pool.dtype)),
+                )
+            k_pool, v_pool, k_scale, v_scale = kv
+            qk, sk = quantize_kv(k)
+            qv, sv = quantize_kv(v)
             return (
-                k_pool.at[i, bid, off].set(k.astype(k_pool.dtype)),
-                v_pool.at[i, bid, off].set(v.astype(v_pool.dtype)),
+                k_pool.at[i, bid, off].set(qk),
+                v_pool.at[i, bid, off].set(qv),
+                k_scale.at[i, bid, off].set(sk),
+                v_scale.at[i, bid, off].set(sv),
             )
-        k_pool, v_pool, k_scale, v_scale = kv
-        qk, sk = quantize_kv(k)
-        qv, sv = quantize_kv(v)
-        return (
-            k_pool.at[i, bid, off].set(qk),
-            v_pool.at[i, bid, off].set(qv),
-            k_scale.at[i, bid, off].set(sk),
-            v_scale.at[i, bid, off].set(sv),
-        )
 
     def _kv_gather(
         self, kv: tuple[jax.Array, ...], i: int, tables: jax.Array
@@ -268,14 +270,15 @@ class PagedForward:
         """Gather layer ``i``'s pages through ``tables``, returning K/V in
         the compute dtype — the int8 path dequantizes here, inside the
         jitted program, so downstream attention never sees storage."""
-        if not self.quantized:
-            k_pool, v_pool = kv
-            return k_pool[i][tables], v_pool[i][tables]
-        k_pool, v_pool, k_scale, v_scale = kv
-        return (
-            dequantize_kv(k_pool[i][tables], k_scale[i][tables], self.dtype),
-            dequantize_kv(v_pool[i][tables], v_scale[i][tables], self.dtype),
-        )
+        with annotate("attn/kv_gather"):
+            if not self.quantized:
+                k_pool, v_pool = kv
+                return k_pool[i][tables], v_pool[i][tables]
+            k_pool, v_pool, k_scale, v_scale = kv
+            return (
+                dequantize_kv(k_pool[i][tables], k_scale[i][tables], self.dtype),
+                dequantize_kv(v_pool[i][tables], v_scale[i][tables], self.dtype),
+            )
 
     # -- copy-on-write block copy (prefix cache) -----------------------------
     def copy_block(
@@ -291,6 +294,10 @@ class PagedForward:
         return tuple(buf.at[:, dst].set(buf[:, src]) for buf in kv)
 
     # -- building blocks (mirror TransformerLM numerics) ---------------------
+    # Each block opens its own scope (``embed``, ``attn/qkv``, ``attn/out``,
+    # ``mlp``, ``logits``; ``attn/kv_scatter``/``attn/kv_gather`` above and
+    # ``attn/core`` in the programs), so the three programs' HLO ``op_name``s
+    # say which part of a layer an operation belongs to.
     def _lin(self, x: jax.Array, kernel: jax.Array) -> jax.Array:
         # flax nn.Dense(use_bias=False, dtype=d): both operands cast to the
         # compute dtype, f32 params untouched in the tree.
@@ -303,37 +310,69 @@ class PagedForward:
         )
         return (normed * scale).astype(x.dtype)
 
+    def _embed(self, params: Any, tokens: jax.Array) -> jax.Array:
+        with annotate("embed"):
+            return params["embed"]["embedding"].astype(self.dtype)[tokens]
+
     def _logits(self, x: jax.Array, params: Any) -> jax.Array:
-        emb = params["embed"]["embedding"]
-        if self.config.tied_embeddings:
-            return (
-                x.astype(self.dtype) @ emb.astype(self.dtype).T
-            ).astype(jnp.float32)
-        return self._lin(x, params["lm_head"]["kernel"]).astype(jnp.float32)
+        with annotate("logits"):
+            if self.config.tied_embeddings:
+                emb = params["embed"]["embedding"]
+                return (
+                    x.astype(self.dtype) @ emb.astype(self.dtype).T
+                ).astype(jnp.float32)
+            return self._lin(x, params["lm_head"]["kernel"]).astype(
+                jnp.float32
+            )
 
     def _attn_proj(
-        self, lp: Any, h: jax.Array, pos: jax.Array
+        self, lp: Any, x: jax.Array, pos: jax.Array
     ) -> tuple[jax.Array, jax.Array, jax.Array]:
+        """Pre-attention norm, Q/K/V projections and RoPE of one layer."""
         cfg = self.config
-        rows, seq = h.shape[0], h.shape[1]
+        rows, seq = x.shape[0], x.shape[1]
         kv_heads = cfg.num_kv_heads or cfg.num_heads
-        q = self._lin(h, lp["attn"]["q_proj"]["kernel"]).reshape(
-            rows, seq, cfg.num_heads, cfg.head_dim
-        )
-        k = self._lin(h, lp["attn"]["k_proj"]["kernel"]).reshape(
-            rows, seq, kv_heads, cfg.head_dim
-        )
-        v = self._lin(h, lp["attn"]["v_proj"]["kernel"]).reshape(
-            rows, seq, kv_heads, cfg.head_dim
-        )
-        return apply_rope(q, pos), apply_rope(k, pos), v
+        with annotate("attn/qkv"):
+            h = self._rmsnorm(x, lp["attn_norm"]["scale"])
+            q = self._lin(h, lp["attn"]["q_proj"]["kernel"]).reshape(
+                rows, seq, cfg.num_heads, cfg.head_dim
+            )
+            k = self._lin(h, lp["attn"]["k_proj"]["kernel"]).reshape(
+                rows, seq, kv_heads, cfg.head_dim
+            )
+            v = self._lin(h, lp["attn"]["v_proj"]["kernel"]).reshape(
+                rows, seq, kv_heads, cfg.head_dim
+            )
+            return apply_rope(q, pos), apply_rope(k, pos), v
+
+    def _attn_out(self, lp: Any, x: jax.Array, ctx: jax.Array) -> jax.Array:
+        """Residual add of the attention output projection; ``ctx`` is
+        ``[rows, seq, H, D]``."""
+        with annotate("attn/out"):
+            return x + self._lin(
+                ctx.reshape(x.shape[0], x.shape[1], -1),
+                lp["attn"]["out_proj"]["kernel"],
+            )
 
     def _mlp(self, lp: Any, x: jax.Array) -> jax.Array:
-        h = self._rmsnorm(x, lp["mlp_norm"]["scale"])
-        hidden = jax.nn.silu(
-            self._lin(h, lp["mlp"]["gate_proj"]["kernel"])
-        ) * self._lin(h, lp["mlp"]["up_proj"]["kernel"])
-        return x + self._lin(hidden, lp["mlp"]["down_proj"]["kernel"])
+        with annotate("mlp"):
+            h = self._rmsnorm(x, lp["mlp_norm"]["scale"])
+            hidden = jax.nn.silu(
+                self._lin(h, lp["mlp"]["gate_proj"]["kernel"])
+            ) * self._lin(h, lp["mlp"]["up_proj"]["kernel"])
+            return x + self._lin(hidden, lp["mlp"]["down_proj"]["kernel"])
+
+    def decode_program(
+        self, *, use_kernel: bool | None, block: int | None = None
+    ) -> Callable[..., Any]:
+        """:meth:`decode_step` with its schedule bound, for ``jax.jit``. A
+        bare ``functools.partial`` has no ``__name__`` and the program would
+        show as ``jit__unknown`` in traces and HLO dumps."""
+        fn = functools.partial(
+            self.decode_step, use_kernel=use_kernel, block=block
+        )
+        fn.__name__ = "decode_step"
+        return fn
 
     # -- jitted decode step --------------------------------------------------
     def decode_step(
@@ -363,8 +402,7 @@ class PagedForward:
         MB = tables.shape[1]
         L = MB * BS
         kv_heads = cfg.num_kv_heads or cfg.num_heads
-        emb = params["embed"]["embedding"]
-        x = emb.astype(self.dtype)[tokens][:, None, :]  # [S, 1, d]
+        x = self._embed(params, tokens)[:, None, :]  # [S, 1, d]
         pos = jnp.maximum(lengths - 1, 0)[:, None]  # [S, 1] absolute
         p = pos[:, 0]
         # Inactive slots route their (garbage) writes to the scratch block.
@@ -380,8 +418,7 @@ class PagedForward:
         window = cfg.attention_window or None
         for i in range(cfg.num_layers):
             lp = params[f"layer_{i}"]
-            h = self._rmsnorm(x, lp["attn_norm"]["scale"])
-            q, k, v = self._attn_proj(lp, h, pos)
+            q, k, v = self._attn_proj(lp, x, pos)
             kv = self._kv_scatter(kv, i, bid, off, k[:, 0], v[:, 0])
             # Gather each slot's pages back into position order: the block
             # table IS the logical->physical map, so indexing the pool with
@@ -389,15 +426,13 @@ class PagedForward:
             k_seq, v_seq = self._kv_gather(kv, i, tables)
             k_seq = k_seq.reshape(S, L, kv_heads, cfg.head_dim)
             v_seq = v_seq.reshape(S, L, kv_heads, cfg.head_dim)
-            ctx = batched_decode_attention(
-                q, k_seq, v_seq, idx, window=window,
-                use_kernel=use_kernel,
-                **({"block": block} if block else {}),
-            )
-            x = x + self._lin(
-                ctx.reshape(S, 1, cfg.num_heads * cfg.head_dim),
-                lp["attn"]["out_proj"]["kernel"],
-            )
+            with annotate("attn/core"):
+                ctx = batched_decode_attention(
+                    q, k_seq, v_seq, idx, window=window,
+                    use_kernel=use_kernel,
+                    **({"block": block} if block else {}),
+                )
+            x = self._attn_out(lp, x, ctx)
             x = self._mlp(lp, x)
         x = self._rmsnorm(x, params["final_norm"]["scale"])
         logits = self._logits(x[:, 0], params)  # [S, V] f32
@@ -420,8 +455,7 @@ class PagedForward:
         L = MB * BS
         kv_heads = cfg.num_kv_heads or cfg.num_heads
         rep = cfg.num_heads // kv_heads
-        emb = params["embed"]["embedding"]
-        x = emb.astype(self.dtype)[tokens][None]  # [1, C, d]
+        x = self._embed(params, tokens)[None]  # [1, C, d]
         offs = jnp.arange(C, dtype=jnp.int32)
         pos = (start + offs)[None]  # [1, C] absolute
         p = jnp.minimum(start + offs, L - 1)
@@ -430,8 +464,7 @@ class PagedForward:
         window = cfg.attention_window or None
         for i in range(cfg.num_layers):
             lp = params[f"layer_{i}"]
-            h = self._rmsnorm(x, lp["attn_norm"]["scale"])
-            q, k, v = self._attn_proj(lp, h, pos)
+            q, k, v = self._attn_proj(lp, x, pos)
             kv = self._kv_scatter(kv, i, bid, off, k[0], v[0])
             k_seq, v_seq = self._kv_gather(kv, i, table)
             k_seq = k_seq.reshape(1, L, kv_heads, cfg.head_dim)
@@ -441,14 +474,12 @@ class PagedForward:
             # absolute coordinates via q_offset. Stale rows from a previous
             # owner of a recycled block sit at positions strictly after the
             # last valid query and are causally masked.
-            ctx = dense_attention(
-                q, repeat_kv(k_seq, rep), repeat_kv(v_seq, rep),
-                causal=True, window=window, q_offset=start,
-            )
-            x = x + self._lin(
-                ctx.reshape(1, C, cfg.num_heads * cfg.head_dim),
-                lp["attn"]["out_proj"]["kernel"],
-            )
+            with annotate("attn/core"):
+                ctx = dense_attention(
+                    q, repeat_kv(k_seq, rep), repeat_kv(v_seq, rep),
+                    causal=True, window=window, q_offset=start,
+                )
+            x = self._attn_out(lp, x, ctx)
             x = self._mlp(lp, x)
         x = self._rmsnorm(x, params["final_norm"]["scale"])
         # Only the last VALID row's logits matter (and only on the final
@@ -503,8 +534,7 @@ class PagedForward:
         kv_heads = cfg.num_kv_heads or cfg.num_heads
         rep = cfg.num_heads // kv_heads
         scale = cfg.head_dim**-0.5
-        emb = params["embed"]["embedding"]
-        x = emb.astype(self.dtype)[tokens]  # [S, W, d]
+        x = self._embed(params, tokens)  # [S, W, d]
         offs = jnp.arange(W, dtype=jnp.int32)[None]  # [1, W]
         pos = jnp.maximum(lengths - 1, 0)[:, None] + offs  # [S, W] absolute
         p = jnp.minimum(pos, L - 1)
@@ -526,34 +556,31 @@ class PagedForward:
             valid &= pos[:, None, :, None] - k_pos[None, None, None, :] < window
         for i in range(cfg.num_layers):
             lp = params[f"layer_{i}"]
-            h = self._rmsnorm(x, lp["attn_norm"]["scale"])
-            q, k, v = self._attn_proj(lp, h, pos)
+            q, k, v = self._attn_proj(lp, x, pos)
             kv = self._kv_scatter(kv, i, bid, off, k, v)
             k_seq, v_seq = self._kv_gather(kv, i, tables)
-            k_seq = repeat_kv(
-                k_seq.reshape(S, L, kv_heads, cfg.head_dim), rep
-            )
-            v_seq = repeat_kv(
-                v_seq.reshape(S, L, kv_heads, cfg.head_dim), rep
-            )
-            scores = jnp.einsum(
-                "bqhd,bkhd->bhqk", q, k_seq,
-                preferred_element_type=jnp.float32,
-            ) * scale
-            scores = jnp.where(valid, scores, NEG_INF)
-            weights = jnp.where(
-                jnp.any(valid, axis=-1)[..., None],
-                jax.nn.softmax(scores, axis=-1),
-                0.0,
-            )
-            ctx = jnp.einsum(
-                "bhqk,bkhd->bqhd", weights.astype(v_seq.dtype), v_seq,
-                preferred_element_type=jnp.float32,
-            ).astype(q.dtype)
-            x = x + self._lin(
-                ctx.reshape(S, W, cfg.num_heads * cfg.head_dim),
-                lp["attn"]["out_proj"]["kernel"],
-            )
+            with annotate("attn/core"):
+                k_seq = repeat_kv(
+                    k_seq.reshape(S, L, kv_heads, cfg.head_dim), rep
+                )
+                v_seq = repeat_kv(
+                    v_seq.reshape(S, L, kv_heads, cfg.head_dim), rep
+                )
+                scores = jnp.einsum(
+                    "bqhd,bkhd->bhqk", q, k_seq,
+                    preferred_element_type=jnp.float32,
+                ) * scale
+                scores = jnp.where(valid, scores, NEG_INF)
+                weights = jnp.where(
+                    jnp.any(valid, axis=-1)[..., None],
+                    jax.nn.softmax(scores, axis=-1),
+                    0.0,
+                )
+                ctx = jnp.einsum(
+                    "bhqk,bkhd->bqhd", weights.astype(v_seq.dtype), v_seq,
+                    preferred_element_type=jnp.float32,
+                ).astype(q.dtype)
+            x = self._attn_out(lp, x, ctx)
             x = self._mlp(lp, x)
         x = self._rmsnorm(x, params["final_norm"]["scale"])
         logits = self._logits(x, params)  # [S, W, V] f32
@@ -759,7 +786,7 @@ class ServingEngine:
         # pools and (when quantized) scale pools alike.
         self._kv_donate = (1,) if buffer_donation_supported() else ()
         self._decode_jit = jax.jit(
-            functools.partial(self._fwd.decode_step, use_kernel=engine.use_kernel),
+            self._fwd.decode_program(use_kernel=engine.use_kernel),
             donate_argnums=self._kv_donate,
         )
         self._prefill_jit = jax.jit(
@@ -877,9 +904,7 @@ class ServingEngine:
         fn = self._decode_variants.get(key)
         if fn is None:
             jitted = jax.jit(
-                functools.partial(
-                    self._fwd.decode_step, use_kernel=use_kernel, block=block
-                ),
+                self._fwd.decode_program(use_kernel=use_kernel, block=block),
                 donate_argnums=self._kv_donate,
             )
             base = self._timed_first_call(jitted)
@@ -1072,33 +1097,46 @@ class ServingEngine:
         the subset their role owns — a prefill engine never decodes, a
         decode engine never admits from a prompt queue — against this one
         implementation of each phase.
+
+        Under a profiler session the step is a tree of host spans
+        (``telemetry.trace.span``, names under ``serve/``): this parent and
+        one child per phase, opened where the work happens so the role
+        engines inherit them. The parent's ``t`` label is the engine clock
+        at entry — the bridge from the profiler's clock (nanoseconds since
+        the session began) to every ``Request.t_*`` stamp and every
+        ``SpanRecorder`` record (docs/OBSERVABILITY.md "Clock alignment").
         """
         now = self._clock()
         finished: list[Request] = []
-        self._phase_admit(now)
-        self._phase_cow()
-        self._phase_prefill(finished)
-        self._phase_chaos()
-        decoding = self._phase_grow()
-        self._phase_decode(decoding, finished)
-        self.steps += 1
-        self._set_gauges()
-        if self._tracer is not None:
-            # Feeds the flight ring: after a wedge, the ring's tail of
-            # engine_step events is the "last known good" timeline.
-            self._tracer.event(
-                "engine_step", step=self.steps,
-                role=self.role or "colocated", finished=len(finished),
-            )
+        with span("serve/step", step=self.steps, t=now):
+            self._phase_admit(now)
+            self._phase_cow()
+            self._phase_prefill(finished)
+            self._phase_chaos()
+            decoding = self._phase_grow()
+            self._phase_decode(decoding, finished)
+            self.steps += 1
+            self._set_gauges()
+            if self._tracer is not None:
+                # Feeds the flight ring: after a wedge, the ring's tail of
+                # engine_step events is the "last known good" timeline.
+                self._tracer.event(
+                    "engine_step", step=self.steps,
+                    role=self.role or "colocated", finished=len(finished),
+                )
         return finished
 
     # -- step phases ---------------------------------------------------------
     def _phase_admit(self, now: float) -> list[Request]:
         """Shed expired queued requests, then admit into free slots."""
-        for _ in self.scheduler.shed_expired(now):
-            self._inc("serve_requests_shed")
-        admitted = self.scheduler.admit(now)
-        self._inc("serve_requests_admitted", len(admitted))
+        with span("serve/admit") as sp:
+            for _ in self.scheduler.shed_expired(now):
+                self._inc("serve_requests_shed")
+            admitted = self.scheduler.admit(now)
+            self._inc("serve_requests_admitted", len(admitted))
+            sp.set_metadata(
+                admitted=len(admitted), queued=self.scheduler.queue_depth()
+            )
         return admitted
 
     def _phase_cow(self) -> None:
@@ -1113,19 +1151,23 @@ class ServingEngine:
         """
         if self.prefix_cache is None:
             return
-        for src, dst, req in self.scheduler.take_pending_cow():
-            if req.state is RequestState.PREFILL:
-                self._kv = self._copy_fn(
-                    self._kv, jnp.int32(src), jnp.int32(dst)
-                )
-                if self._spec is not None:
-                    # The draft's pools ride the same block tables, so the
-                    # adopted prefix must exist there too — mirror the copy
-                    # (same src/dst ids, draft pools).
-                    self._spec.copy_block(src, dst)
-                self._record_writes([dst])
-                self.prefix_cache.note_cow()
-            self.pool.free([src])  # unpin the CoW source
+        pending = self.scheduler.take_pending_cow()
+        if not pending:
+            return
+        with span("serve/cow"):
+            for src, dst, req in pending:
+                if req.state is RequestState.PREFILL:
+                    self._kv = self._copy_fn(
+                        self._kv, jnp.int32(src), jnp.int32(dst)
+                    )
+                    if self._spec is not None:
+                        # The draft's pools ride the same block tables, so
+                        # the adopted prefix must exist there too — mirror
+                        # the copy (same src/dst ids, draft pools).
+                        self._spec.copy_block(src, dst)
+                    self._record_writes([dst])
+                    self.prefix_cache.note_cow()
+                self.pool.free([src])  # unpin the CoW source
 
     def _phase_prefill(self, finished: list[Request]) -> None:
         """One prefill chunk for every PREFILL slot."""
@@ -1151,18 +1193,19 @@ class ServingEngine:
         # so a pool that cannot serve it sheds the requester under its own
         # labeled reason ("spec_overflow") instead of the generic eviction.
         shed_reason = "spec_overflow" if self._spec is not None else "evicted"
-        for req in list(self.scheduler.running()):
-            if req.state is not RequestState.DECODE:
-                continue
-            while len(req.blocks) < self.pool.blocks_for(req.length):
-                if not self.scheduler.grow(req, shed_reason=shed_reason):
-                    self._inc("serve_requests_shed")
-                    break
-        # grow() may have evicted requests from the snapshot above.
-        return [
-            r for r in self.scheduler.running()
-            if r.state is RequestState.DECODE
-        ]
+        with span("serve/grow"):
+            for req in list(self.scheduler.running()):
+                if req.state is not RequestState.DECODE:
+                    continue
+                while len(req.blocks) < self.pool.blocks_for(req.length):
+                    if not self.scheduler.grow(req, shed_reason=shed_reason):
+                        self._inc("serve_requests_shed")
+                        break
+            # grow() may have evicted requests from the snapshot above.
+            return [
+                r for r in self.scheduler.running()
+                if r.state is RequestState.DECODE
+            ]
 
     def _phase_decode(
         self, decoding: list[Request], finished: list[Request]
@@ -1215,61 +1258,67 @@ class ServingEngine:
         self, decoding: list[Request], finished: list[Request]
     ) -> None:
         e = self.engine
-        tables = np.zeros((e.max_slots, e.max_blocks_per_seq), np.int32)
-        lengths = np.zeros((e.max_slots,), np.int32)
-        tokens = np.zeros((e.max_slots,), np.int32)
-        active = np.zeros((e.max_slots,), bool)
-        for req in decoding:
-            s = req.slot
-            tables[s, : len(req.blocks)] = req.blocks
-            lengths[s] = req.length
-            tokens[s] = req.generated[-1]
-            active[s] = True
-        tables = tables[
-            :, : self._gather_width(max(len(r.blocks) for r in decoding))
-        ]
-        fn = self._decode_fn
-        if e.use_kernel is None:
-            # Per-(batch, context)-bucket schedule: a tuned decode_bucket|...
-            # entry for THIS step's live bucket overrides the single
-            # gathered-shape flash_decode entry the default program consults
-            # at trace time. Miss = default program (never a recompile).
-            from deeplearning_mpi_tpu.compiler import autotune
+        with span("serve/decode_launch", rows=len(decoding)) as sp:
+            tables = np.zeros((e.max_slots, e.max_blocks_per_seq), np.int32)
+            lengths = np.zeros((e.max_slots,), np.int32)
+            tokens = np.zeros((e.max_slots,), np.int32)
+            active = np.zeros((e.max_slots,), bool)
+            for req in decoding:
+                s = req.slot
+                tables[s, : len(req.blocks)] = req.blocks
+                lengths[s] = req.length
+                tokens[s] = req.generated[-1]
+                active[s] = True
+            tables = tables[
+                :, : self._gather_width(max(len(r.blocks) for r in decoding))
+            ]
+            fn = self._decode_fn
+            if e.use_kernel is None:
+                # Per-(batch, context)-bucket schedule: a tuned decode_bucket|...
+                # entry for THIS step's live bucket overrides the single
+                # gathered-shape flash_decode entry the default program consults
+                # at trace time. Miss = default program (never a recompile).
+                from deeplearning_mpi_tpu.compiler import autotune
 
-            tuned = autotune.tuned_decode_bucket(
-                len(decoding), int(lengths.max()),
-                (
-                    e.max_slots, e.max_seq_len,
-                    self.config.num_kv_heads or self.config.num_heads,
-                    self.config.head_dim,
-                ),
-                self.dtype,
-                role=self.role,
-            )
-            if tuned is not None and not self._is_base_schedule(
-                tuned, tables.shape[1]
-            ):
-                fn = self._decode_variant(
-                    tuned["schedule"] == "kernel", tuned.get("block")
+                tuned = autotune.tuned_decode_bucket(
+                    len(decoding), int(lengths.max()),
+                    (
+                        e.max_slots, e.max_seq_len,
+                        self.config.num_kv_heads or self.config.num_heads,
+                        self.config.head_dim,
+                    ),
+                    self.dtype,
+                    role=self.role,
                 )
-        self._kv, next_tok = fn(
-            self.params, self._kv,
-            jnp.asarray(tables), jnp.asarray(lengths),
-            jnp.asarray(tokens), jnp.asarray(active),
-        )
-        BS = e.block_size
-        self._record_writes(
-            {req.blocks[(req.length - 1) // BS] for req in decoding}
-        )
-        self._inc("serve_decode_steps")
-        next_np = np.asarray(jax.device_get(next_tok))  # dmt-lint: disable=DMT003 — THE audited sync: one sampled-token fetch per decode step (EOS/retire decisions are host-side)
-        now = self._clock()
-        for req in decoding:
-            tok = int(next_np[req.slot])
-            req.generated.append(tok)
-            self._inc("serve_tokens_generated")
-            if self._done(req, tok):
-                self._finish(req, now, finished)
+                if tuned is not None and not self._is_base_schedule(
+                    tuned, tables.shape[1]
+                ):
+                    fn = self._decode_variant(
+                        tuned["schedule"] == "kernel", tuned.get("block")
+                    )
+            self._kv, next_tok = fn(
+                self.params, self._kv,
+                jnp.asarray(tables), jnp.asarray(lengths),
+                jnp.asarray(tokens), jnp.asarray(active),
+            )
+            BS = e.block_size
+            self._record_writes(
+                {req.blocks[(req.length - 1) // BS] for req in decoding}
+            )
+            self._inc("serve_decode_steps")
+            sp.set_metadata(width=tables.shape[1])
+        with span("serve/token_fetch"):
+            next_np = np.asarray(jax.device_get(next_tok))  # dmt-lint: disable=DMT003 — THE audited sync: one sampled-token fetch per decode step (EOS/retire decisions are host-side)
+        with span("serve/retire") as sp:
+            before = len(finished)
+            now = self._clock()
+            for req in decoding:
+                tok = int(next_np[req.slot])
+                req.generated.append(tok)
+                self._inc("serve_tokens_generated")
+                if self._done(req, tok):
+                    self._finish(req, now, finished)
+            sp.set_metadata(finished=len(finished) - before)
 
     def _spec_decode(
         self, decoding: list[Request], finished: list[Request]
@@ -1282,107 +1331,115 @@ class ServingEngine:
         blocks back to the free list."""
         e = self.engine
         K, BS = e.spec_k, e.block_size
-        tables = np.zeros((e.max_slots, e.max_blocks_per_seq), np.int32)
-        lengths = np.zeros((e.max_slots,), np.int32)
-        last = np.zeros((e.max_slots,), np.int32)
-        n_prop = np.zeros((e.max_slots,), np.int32)
-        active = np.zeros((e.max_slots,), bool)
-        for req in decoding:
-            s = req.slot
-            # Budget: the step emits up to n+1 tokens; never propose past
-            # the request's remaining generation budget (admission already
-            # bounds prompt + max_new to max_seq_len, so the position
-            # ceiling is subsumed).
-            n = min(K, req.max_new_tokens - len(req.generated) - 1)
-            if n > 0:
-                # Verify writes K/V at positions length-1 .. length-1+n:
-                # take the extra blocks all-or-nothing from the FREE list
-                # only. A speculative tail must never evict a peer (the
-                # mandatory-growth path above handles real pressure);
-                # on a dry pool the budget degrades to what the already-
-                # owned blocks cover.
-                need = self.pool.blocks_for(req.length + n) - len(req.blocks)
-                if need > 0:
-                    got = self.pool.alloc(need)
-                    if got is None and self.prefix_cache is not None:
-                        # Unreferenced cache branches are cheaper than a
-                        # degraded proposal budget — evict before giving up
-                        # (still never evicting a live peer).
-                        if self.prefix_cache.evict(need - self.pool.available):
-                            got = self.pool.alloc(need)
-                    if got is not None:
-                        req.blocks.extend(got)
-                    else:
-                        n = min(n, len(req.blocks) * BS - req.length)
-                        self._inc("spec_degraded_total")
-            tables[s, : len(req.blocks)] = req.blocks
-            lengths[s] = req.length
-            last[s] = req.generated[-1]
-            n_prop[s] = max(n, 0)
-            active[s] = True
-        tables = tables[
-            :, : self._gather_width(max(len(r.blocks) for r in decoding))
-        ]
-        props, draft_steps = self._spec.propose(
-            tables, lengths, last, n_prop, active
-        )
-        self._inc("spec_draft_steps", draft_steps)
-        W = K + 1
-        tokens = np.zeros((e.max_slots, W), np.int32)
-        tokens[:, 0] = last
-        tokens[:, 1:] = props
-        self._kv, greedy = self._verify_fn(
-            self.params, self._kv,
-            jnp.asarray(tables), jnp.asarray(lengths),
-            jnp.asarray(tokens), jnp.asarray(n_prop + 1),
-            jnp.asarray(active),
-        )
-        touched: set[int] = set()
-        for req in decoding:
-            n_fed = int(n_prop[req.slot]) + 1
-            lo = (req.length - 1) // BS
-            hi = min((req.length - 1 + n_fed - 1) // BS, len(req.blocks) - 1)
-            touched.update(req.blocks[lo : hi + 1])
-        self._record_writes(touched)
-        self._inc("serve_decode_steps")
-        self._inc("spec_verify_steps")
-        greedy_np = np.asarray(jax.device_get(greedy))  # [S, W]  # dmt-lint: disable=DMT003 — the audited verify fetch: exact-match acceptance runs on host
-        now = self._clock()
-        for req in decoding:
-            s = req.slot
-            n_p = int(n_prop[s])
-            g = greedy_np[s]
-            # Exact-greedy-match acceptance: the longest proposal prefix
-            # equal to the target's own greedy choices. greedy[i] is the
-            # target's token for position lengths[s]+i, i.e. exactly what
-            # a plain decode step would emit after the first i proposals.
-            n = 0
-            while n < n_p and int(props[s, n]) == int(g[n]):
-                n += 1
-            emitted_props = 0
-            for i in range(n + 1):
-                tok = int(g[i])
-                req.generated.append(tok)
-                self._inc("serve_tokens_generated")
-                if i < n:
-                    emitted_props += 1
-                if self._done(req, tok):
-                    self._finish(req, now, finished)
-                    break
-            self._inc("spec_proposed_total", n_p)
-            self._inc("spec_accepted_total", emitted_props)
-            self._inc("spec_rollback_total", n_p - emitted_props)
-            if req.state is RequestState.DECODE:
-                # Roll back the rejected tail's surplus blocks: keep exactly
-                # the cover the next step's mandatory growth would demand,
-                # return the rest to the free list. K/V content needs no
-                # rollback — garbage past the accepted prefix sits at
-                # positions the next verify step overwrites before they
-                # become causally visible.
-                freed = self.scheduler.shrink(
-                    req, self.pool.blocks_for(req.length)
-                )
-                self._inc("spec_blocks_rolled_back_total", len(freed))
+        with span("serve/draft_launch", rows=len(decoding)):
+            tables = np.zeros((e.max_slots, e.max_blocks_per_seq), np.int32)
+            lengths = np.zeros((e.max_slots,), np.int32)
+            last = np.zeros((e.max_slots,), np.int32)
+            n_prop = np.zeros((e.max_slots,), np.int32)
+            active = np.zeros((e.max_slots,), bool)
+            for req in decoding:
+                s = req.slot
+                # Budget: the step emits up to n+1 tokens; never propose past
+                # the request's remaining generation budget (admission already
+                # bounds prompt + max_new to max_seq_len, so the position
+                # ceiling is subsumed).
+                n = min(K, req.max_new_tokens - len(req.generated) - 1)
+                if n > 0:
+                    # Verify writes K/V at positions length-1 .. length-1+n:
+                    # take the extra blocks all-or-nothing from the FREE list
+                    # only. A speculative tail must never evict a peer (the
+                    # mandatory-growth path above handles real pressure);
+                    # on a dry pool the budget degrades to what the already-
+                    # owned blocks cover.
+                    need = self.pool.blocks_for(req.length + n) - len(req.blocks)
+                    if need > 0:
+                        got = self.pool.alloc(need)
+                        if got is None and self.prefix_cache is not None:
+                            # Unreferenced cache branches are cheaper than a
+                            # degraded proposal budget — evict before giving up
+                            # (still never evicting a live peer).
+                            if self.prefix_cache.evict(need - self.pool.available):
+                                got = self.pool.alloc(need)
+                        if got is not None:
+                            req.blocks.extend(got)
+                        else:
+                            n = min(n, len(req.blocks) * BS - req.length)
+                            self._inc("spec_degraded_total")
+                tables[s, : len(req.blocks)] = req.blocks
+                lengths[s] = req.length
+                last[s] = req.generated[-1]
+                n_prop[s] = max(n, 0)
+                active[s] = True
+            tables = tables[
+                :, : self._gather_width(max(len(r.blocks) for r in decoding))
+            ]
+            props, draft_steps = self._spec.propose(
+                tables, lengths, last, n_prop, active
+            )
+            self._inc("spec_draft_steps", draft_steps)
+        with span(
+            "serve/verify_launch", rows=len(decoding), width=tables.shape[1]
+        ):
+            W = K + 1
+            tokens = np.zeros((e.max_slots, W), np.int32)
+            tokens[:, 0] = last
+            tokens[:, 1:] = props
+            self._kv, greedy = self._verify_fn(
+                self.params, self._kv,
+                jnp.asarray(tables), jnp.asarray(lengths),
+                jnp.asarray(tokens), jnp.asarray(n_prop + 1),
+                jnp.asarray(active),
+            )
+            touched: set[int] = set()
+            for req in decoding:
+                n_fed = int(n_prop[req.slot]) + 1
+                lo = (req.length - 1) // BS
+                hi = min((req.length - 1 + n_fed - 1) // BS, len(req.blocks) - 1)
+                touched.update(req.blocks[lo : hi + 1])
+            self._record_writes(touched)
+            self._inc("serve_decode_steps")
+            self._inc("spec_verify_steps")
+        with span("serve/verify_fetch"):
+            greedy_np = np.asarray(jax.device_get(greedy))  # [S, W]  # dmt-lint: disable=DMT003 — the audited verify fetch: exact-match acceptance runs on host
+        with span("serve/retire") as sp:
+            before = len(finished)
+            now = self._clock()
+            for req in decoding:
+                s = req.slot
+                n_p = int(n_prop[s])
+                g = greedy_np[s]
+                # Exact-greedy-match acceptance: the longest proposal prefix
+                # equal to the target's own greedy choices. greedy[i] is the
+                # target's token for position lengths[s]+i, i.e. exactly what
+                # a plain decode step would emit after the first i proposals.
+                n = 0
+                while n < n_p and int(props[s, n]) == int(g[n]):
+                    n += 1
+                emitted_props = 0
+                for i in range(n + 1):
+                    tok = int(g[i])
+                    req.generated.append(tok)
+                    self._inc("serve_tokens_generated")
+                    if i < n:
+                        emitted_props += 1
+                    if self._done(req, tok):
+                        self._finish(req, now, finished)
+                        break
+                self._inc("spec_proposed_total", n_p)
+                self._inc("spec_accepted_total", emitted_props)
+                self._inc("spec_rollback_total", n_p - emitted_props)
+                if req.state is RequestState.DECODE:
+                    # Roll back the rejected tail's surplus blocks: keep exactly
+                    # the cover the next step's mandatory growth would demand,
+                    # return the rest to the free list. K/V content needs no
+                    # rollback — garbage past the accepted prefix sits at
+                    # positions the next verify step overwrites before they
+                    # become causally visible.
+                    freed = self.scheduler.shrink(
+                        req, self.pool.blocks_for(req.length)
+                    )
+                    self._inc("spec_blocks_rolled_back_total", len(freed))
+            sp.set_metadata(finished=len(finished) - before)
 
     def run_until_idle(self, *, max_steps: int = 100_000) -> list[Request]:
         """Step until queue and slots drain; returns everything finished.
@@ -1466,61 +1523,68 @@ class ServingEngine:
         e = self.engine
         start = req.prefilled
         n_valid = min(e.prefill_chunk, req.prompt_len - start)
-        chunk = np.zeros((e.prefill_chunk,), np.int32)
-        chunk[:n_valid] = req.prompt[start : start + n_valid]
-        table = np.zeros((e.max_blocks_per_seq,), np.int32)
-        table[: len(req.blocks)] = req.blocks
-        self._kv, last_logits = self._prefill_fn(
-            self.params, self._kv,
-            jnp.asarray(table), jnp.asarray(chunk),
-            jnp.int32(start), jnp.int32(n_valid),
-        )
-        self._record_writes(
-            req.blocks[start // e.block_size :
-                       (start + n_valid - 1) // e.block_size + 1]
-        )
-        if self._spec is not None:
-            # The draft ingests the prompt alongside the target (same
-            # chunk, same table, its own pools) so its propose loop has a
-            # complete prefix from the first decode iteration.
-            self._spec.prefill_chunk(table, chunk, start, n_valid)
-        self._inc("serve_prefill_chunks")
-        if self._tracer is not None:
-            self._tracer.event(
-                "prefill_chunk",
-                trace=req.trace or f"rid{req.rid}",
-                start=start, n=n_valid,
-                role=self.role or "colocated",
+        with span(
+            "serve/prefill_launch", rid=req.rid, start=start, n=n_valid
+        ):
+            chunk = np.zeros((e.prefill_chunk,), np.int32)
+            chunk[:n_valid] = req.prompt[start : start + n_valid]
+            table = np.zeros((e.max_blocks_per_seq,), np.int32)
+            table[: len(req.blocks)] = req.blocks
+            self._kv, last_logits = self._prefill_fn(
+                self.params, self._kv,
+                jnp.asarray(table), jnp.asarray(chunk),
+                jnp.int32(start), jnp.int32(n_valid),
             )
-        req.prefilled += n_valid
+            self._record_writes(
+                req.blocks[start // e.block_size :
+                           (start + n_valid - 1) // e.block_size + 1]
+            )
+            if self._spec is not None:
+                # The draft ingests the prompt alongside the target (same
+                # chunk, same table, its own pools) so its propose loop has a
+                # complete prefix from the first decode iteration.
+                self._spec.prefill_chunk(table, chunk, start, n_valid)
+            self._inc("serve_prefill_chunks")
+            if self._tracer is not None:
+                self._tracer.event(
+                    "prefill_chunk",
+                    trace=req.trace or f"rid{req.rid}",
+                    start=start, n=n_valid,
+                    role=self.role or "colocated",
+                )
+            req.prefilled += n_valid
         if req.prefilled < req.prompt_len:
             return
         # Prompt fully ingested: the first generated token comes straight
         # from the prefill's last-position logits (same seed-step split as
         # models.generate.first_token).
-        tok = int(jax.device_get(jnp.argmax(last_logits)))  # dmt-lint: disable=DMT003 — audited: the first token must reach the host to enter req.generated
-        req.state = RequestState.DECODE
-        req.generated.append(tok)
-        req.t_first_token = self._clock()
-        self._inc("serve_tokens_generated")
-        if self._metrics is not None and req.ttft is not None:
-            self._metrics.histogram("serve_ttft_s").observe(req.ttft)
-        if self.prefix_cache is not None:
-            # Index the FULL prompt blocks now: from this point the request
-            # only writes positions >= prompt_len, which never land in a
-            # full prefix block, so those pages are frozen. (The partial
-            # tail block is still being written by decode; it is indexed at
-            # _finish.) The device_get above is the proof the writes
-            # landed — insertion after it makes cached pages crash-safe.
-            n_full = req.prompt_len // e.block_size
-            if n_full:
-                self.prefix_cache.insert(
-                    req.prompt, req.blocks, n_full * e.block_size
-                )
-        if self._done(req, tok):
-            self._finish(req, req.t_first_token, finished)
-        else:
-            self._prefill_complete(req)
+        with span("serve/first_token_fetch", rid=req.rid):
+            tok = int(jax.device_get(jnp.argmax(last_logits)))  # dmt-lint: disable=DMT003 — audited: the first token must reach the host to enter req.generated
+        with span("serve/retire") as sp:
+            before = len(finished)
+            req.state = RequestState.DECODE
+            req.generated.append(tok)
+            req.t_first_token = self._clock()
+            self._inc("serve_tokens_generated")
+            if self._metrics is not None and req.ttft is not None:
+                self._metrics.histogram("serve_ttft_s").observe(req.ttft)
+            if self.prefix_cache is not None:
+                # Index the FULL prompt blocks now: from this point the request
+                # only writes positions >= prompt_len, which never land in a
+                # full prefix block, so those pages are frozen. (The partial
+                # tail block is still being written by decode; it is indexed at
+                # _finish.) The device_get above is the proof the writes
+                # landed — insertion after it makes cached pages crash-safe.
+                n_full = req.prompt_len // e.block_size
+                if n_full:
+                    self.prefix_cache.insert(
+                        req.prompt, req.blocks, n_full * e.block_size
+                    )
+            if self._done(req, tok):
+                self._finish(req, req.t_first_token, finished)
+            else:
+                self._prefill_complete(req)
+            sp.set_metadata(finished=len(finished) - before)
 
     def _prefill_complete(self, req: Request) -> None:
         """Hook: ``req`` just finished its prompt (first token emitted) and
@@ -1618,32 +1682,33 @@ class ServingEngine:
     def _set_gauges(self) -> None:
         if self._metrics is None:
             return
-        self._metrics.gauge(self._role_name("serve_queue_depth")).set(
-            self.scheduler.queue_depth()
-        )
-        self._metrics.gauge(self._role_name("serve_slots_active")).set(
-            self.scheduler.slots_active()
-        )
-        self._metrics.gauge(self._role_name("serve_kv_blocks_in_use")).set(
-            self.pool.in_use
-        )
-        from deeplearning_mpi_tpu.telemetry.registry import labeled
+        with span("serve/gauges"):
+            self._metrics.gauge(self._role_name("serve_queue_depth")).set(
+                self.scheduler.queue_depth()
+            )
+            self._metrics.gauge(self._role_name("serve_slots_active")).set(
+                self.scheduler.slots_active()
+            )
+            self._metrics.gauge(self._role_name("serve_kv_blocks_in_use")).set(
+                self.pool.in_use
+            )
+            from deeplearning_mpi_tpu.telemetry.registry import labeled
 
-        nbytes = self._kvh.nbytes
-        self._metrics.gauge(self._role_name("serve_kv_bytes")).set(nbytes)
-        self._metrics.gauge(
-            labeled("serve_kv_bytes", dtype=self._kv_dtype_name)
-        ).set(nbytes)
-        if self.prefix_cache is not None:
-            self._metrics.gauge("serve_prefix_nodes").set(
-                self.prefix_cache.num_nodes
-            )
-            self._metrics.gauge("serve_prefix_blocks").set(
-                self.prefix_cache.num_blocks_cached
-            )
-        if self.scheduler.tenants:
-            inflight = self.scheduler.tenant_tokens_in_flight()
-            for tenant in self.scheduler.tenants:
-                self._metrics.gauge(
-                    labeled("serve_tenant_tokens_in_flight", tenant=tenant)
-                ).set(inflight.get(tenant, 0))
+            nbytes = self._kvh.nbytes
+            self._metrics.gauge(self._role_name("serve_kv_bytes")).set(nbytes)
+            self._metrics.gauge(
+                labeled("serve_kv_bytes", dtype=self._kv_dtype_name)
+            ).set(nbytes)
+            if self.prefix_cache is not None:
+                self._metrics.gauge("serve_prefix_nodes").set(
+                    self.prefix_cache.num_nodes
+                )
+                self._metrics.gauge("serve_prefix_blocks").set(
+                    self.prefix_cache.num_blocks_cached
+                )
+            if self.scheduler.tenants:
+                inflight = self.scheduler.tenant_tokens_in_flight()
+                for tenant in self.scheduler.tenants:
+                    self._metrics.gauge(
+                        labeled("serve_tenant_tokens_in_flight", tenant=tenant)
+                    ).set(inflight.get(tenant, 0))

@@ -45,7 +45,6 @@ rewrites the draft pools through the same tables.
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Callable
 
 import jax
@@ -119,7 +118,7 @@ class SpeculativeDecoder:
         # tuning does not transfer, and draft steps are small enough that
         # kernel dispatch has nothing to win on CPU-class drafts.
         self._decode_jit = jax.jit(
-            functools.partial(self._fwd.decode_step, use_kernel=False),
+            self._fwd.decode_program(use_kernel=False),
             donate_argnums=donate,
         )
         self._prefill_jit = jax.jit(

@@ -30,7 +30,7 @@ from deeplearning_mpi_tpu.telemetry.registry import (
     labeled,
 )
 from deeplearning_mpi_tpu.telemetry.spans import Span, SpanRecorder
-from deeplearning_mpi_tpu.telemetry.trace import annotate, annotate_fn
+from deeplearning_mpi_tpu.telemetry.trace import annotate
 
 __all__ = [
     "InMemorySink",
@@ -41,6 +41,5 @@ __all__ = [
     "SpanRecorder",
     "TensorBoardSink",
     "annotate",
-    "annotate_fn",
     "labeled",
 ]

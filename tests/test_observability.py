@@ -560,6 +560,27 @@ class TestTraceAnnotations:
             trace.set_enabled(old)
         assert float(out) == 2.0
 
+    def test_span_is_the_bare_annotation_and_one_shared_noop_when_disabled(self):
+        """``span`` is for host code that runs every step: the
+        ``TraceAnnotation`` itself with its labels, no ``named_scope``; with
+        annotation disabled, one shared object that takes the same calls."""
+        from deeplearning_mpi_tpu.telemetry import trace
+
+        old = trace.set_enabled(True)
+        try:
+            on = trace.span("serve/x", rows=3)
+            assert type(on) is jax.profiler.TraceAnnotation
+            with on as sp:
+                sp.set_metadata(width=8)
+            trace.set_enabled(False)
+            off = trace.span("serve/x", rows=3)
+            assert not isinstance(off, jax.profiler.TraceAnnotation)
+            with off as sp:
+                sp.set_metadata(width=8)
+            assert sp is off and trace.span("serve/y") is off
+        finally:
+            trace.set_enabled(old)
+
 
 class TestTrainerTelemetry:
     def test_trainer_emits_canonical_records_through_registry(self, mesh):

@@ -1420,3 +1420,238 @@ class TestPrefixCacheDisagg:
         assert engine.prefix_cache.num_blocks_cached == 0
         assert engine.pool.in_use == 0
         engine.pool.check()
+
+
+# ---- the step from inside: host spans on the profiler's clock (PR 27) -----
+_PHASE_LABELS = {
+    "serve/step": {"step", "t"},
+    "serve/admit": {"admitted", "queued"},
+    "serve/prefill_launch": {"rid", "start", "n"},
+    "serve/first_token_fetch": {"rid"},
+    "serve/grow": set(),
+    "serve/decode_launch": {"rows", "width"},
+    "serve/token_fetch": set(),
+    "serve/retire": {"finished"},
+    "serve/gauges": set(),
+}
+_SCOPES = (
+    "embed", "attn/qkv", "attn/kv_scatter", "attn/kv_gather", "attn/core",
+    "attn/out", "mlp", "logits",
+)
+
+
+def _host_spans(trace_dir):
+    """(name, start s, duration s, labels) of every ``serve/`` and ``test/``
+    host event in the newest trace under ``trace_dir``, by start."""
+    from jax.profiler import ProfileData
+
+    path = max(trace_dir.rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime)
+    spans = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("serve/", "test/")):
+                    labels = {k: v for k, v in e.stats}
+                    spans.append((e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9, labels))
+    return sorted(spans, key=lambda s: s[1])
+
+
+def _inside(child, parent):
+    return parent[1] <= child[1] and child[1] + child[2] <= parent[1] + parent[2]
+
+
+def _span_prompts():
+    rng = np.random.default_rng(27)
+    return [rng.integers(1, 255, size=n).astype(np.int32) for n in (3, 7, 2)]
+
+
+def _serve_spans_run(engine, prompts):
+    """r0 decodes alone, then r1 (two chunks) and r2 arrive one step apart:
+    steps with a chunk and a decode batch, a first token and a decode batch,
+    and decode alone."""
+    import time as _time
+
+    reqs = [engine.submit(prompts[0], MAX_NEW + 3)]
+    engine.step()
+    reqs.append(engine.submit(prompts[1], MAX_NEW))
+    engine.step()
+    reqs.append(engine.submit(prompts[2], MAX_NEW))
+    steps = 2
+    while not engine.scheduler.idle():
+        engine.step()
+        _time.sleep(0.0005)
+        steps += 1
+        assert steps < 200, "engine did not drain"
+    return reqs
+
+
+@pytest.fixture(scope="module")
+def step_trace(tiny_lm, tmp_path_factory):
+    """A warmed tiny engine on the real clock, stepped under a profiler
+    session with Python call tracing off (what the benchmark runs)."""
+    import time as _time
+
+    cfg, _, params = tiny_lm
+    registry = MetricsRegistry()
+    engine = ServingEngine(
+        cfg, params, ENGINE_CFG, dtype=jnp.float32, clock=_time.monotonic,
+        registry=registry,
+    )
+    engine.warmup()
+    engine.submit(np.arange(1, 6, dtype=np.int32), 2)
+    engine.run_until_idle()  # the small programs around the engine's own
+    compiles = registry.snapshot()["serve_compile_total"]
+    trace_dir = tmp_path_factory.mktemp("serve_spans")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        t_mark = _time.monotonic()
+        with jax.profiler.TraceAnnotation("test/mark"):
+            pass
+        reqs = _serve_spans_run(engine, _span_prompts())
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(trace_dir)
+    steps = [s for s in spans if s[0] == "serve/step"]
+    children = [
+        [c for c in spans if c[0] not in ("serve/step", "test/mark") and _inside(c, st)]
+        for st in steps
+    ]
+    return {
+        "spans": spans, "steps": steps, "children": children, "reqs": reqs,
+        "t_mark": t_mark, "compiles_before": compiles,
+        "compiles_after": registry.snapshot()["serve_compile_total"],
+    }
+
+
+class TestStepSpans:
+    def test_every_phase_is_a_child_of_its_step(self, step_trace):
+        """No ``serve/`` span outside a ``serve/step``, children of one step
+        do not overlap, and a step is tiled by them (what is left is the
+        step's own self time)."""
+        spans, steps, children = (step_trace[k] for k in ("spans", "steps", "children"))
+        assert len(steps) >= 6
+        n_children = sum(len(c) for c in children)
+        assert n_children == len([s for s in spans if s[0].startswith("serve/")]) - len(steps)
+        for st, kids in zip(steps, children):
+            assert kids[0][0] == "serve/admit" and kids[-1][0] == "serve/gauges"
+            for a, b in zip(kids, kids[1:]):
+                assert a[1] + a[2] <= b[1], (a, b)
+            assert sum(k[2] for k in kids) <= st[2]
+
+    def test_a_step_with_a_chunk_and_a_decode_batch_has_both_launches_and_both_fetches(self, step_trace):
+        names = [[k[0] for k in kids] for kids in step_trace["children"]]
+        assert names[0] == [  # r0's only chunk is its last: first token, then it decodes
+            "serve/admit", "serve/prefill_launch", "serve/first_token_fetch", "serve/retire",
+            "serve/grow", "serve/decode_launch", "serve/token_fetch", "serve/retire", "serve/gauges",
+        ]
+        assert names[1] == [  # r1's first of two chunks beside r0's decode
+            "serve/admit", "serve/prefill_launch", "serve/grow", "serve/decode_launch",
+            "serve/token_fetch", "serve/retire", "serve/gauges",
+        ]
+        assert names[-1] == [
+            "serve/admit", "serve/grow", "serve/decode_launch", "serve/token_fetch",
+            "serve/retire", "serve/gauges",
+        ]
+        assert "serve/cow" not in {n for step in names for n in step}  # no prefix cache, no copy
+
+    def test_spans_carry_the_labels_of_the_table(self, step_trace):
+        steps, children, reqs = (step_trace[k] for k in ("steps", "children", "reqs"))
+        for st, kids in zip(steps, children):
+            for name, _, _, labels in [st, *kids]:
+                assert set(labels) == _PHASE_LABELS[name], (name, labels)
+        assert [st[3]["step"] for st in steps] == list(range(steps[0][3]["step"], steps[0][3]["step"] + len(steps)))
+        chunks = [k[3] for kids in children for k in kids if k[0] == "serve/prefill_launch"]
+        by_rid = {r.rid: r for r in reqs}
+        assert [(c["rid"], c["start"], c["n"]) for c in chunks] == [
+            (reqs[0].rid, 0, 3), (reqs[1].rid, 0, 4), (reqs[1].rid, 4, 3), (reqs[2].rid, 0, 2),
+        ]
+        assert all(by_rid[c["rid"]].prompt_len >= c["start"] + c["n"] for c in chunks)
+        admits = [kids[0][3] for kids in children]
+        assert sum(a["admitted"] for a in admits) == 3 and all(a["queued"] == 0 for a in admits)
+        launches = [k[3] for kids in children for k in kids if k[0] == "serve/decode_launch"]
+        assert max(l["rows"] for l in launches) == 3 and {l["width"] for l in launches} <= {1, 2, 4, 8}
+        retired = sum(k[3]["finished"] for kids in children for k in kids if k[0] == "serve/retire")
+        assert retired == 3
+
+    def test_the_t_label_ties_the_engine_clock_to_the_trace_clock(self, step_trace):
+        """``t`` minus the span's own start is the engine clock's reading at
+        the profiler session's zero; an annotation stamped by hand in the
+        same session gives the same offset to within 1 ms."""
+        mark = next(s for s in step_trace["spans"] if s[0] == "test/mark")
+        reference = step_trace["t_mark"] - mark[1]
+        offsets = [st[3]["t"] - st[1] for st in step_trace["steps"]]
+        assert max(abs(o - reference) for o in offsets) < 1e-3
+
+    def test_t_admitted_maps_into_the_step_that_admitted_it(self, step_trace):
+        """``Request.t_admitted`` is the step's ``now`` (its ``t`` label), read
+        just before the spans open: mapped onto the trace's clock it lies
+        within a millisecond before the ``serve/admit`` span that admitted the
+        request, never after it, and in no other step."""
+        steps, children, reqs = (step_trace[k] for k in ("steps", "children", "reqs"))
+        offset = steps[0][3]["t"] - steps[0][1]
+        admitting = [(st, kids[0]) for st, kids in zip(steps, children) if kids[0][3]["admitted"]]
+        assert len(admitting) == len(reqs)
+        for req, (st, admit) in zip(reqs, admitting):
+            at = req.t_admitted - offset
+            assert admit[1] - 1e-3 <= at <= admit[1] + admit[2]
+            assert req.t_admitted == st[3]["t"]
+            later = [s for s in steps if s[1] > st[1]]
+            assert not later or at < later[0][1]
+
+    def test_no_compile_after_warmup_under_the_profiler(self, step_trace):
+        assert step_trace["compiles_after"] == step_trace["compiles_before"]
+
+    def test_tokens_identical_with_annotations_stripped(self, step_trace, tiny_lm):
+        """``trace.set_enabled(False)`` strips the host spans AND the scopes
+        inside the programs (they are built with it off): same tokens, and
+        ``serve_compile_total`` still flat after ``warmup()``."""
+        from deeplearning_mpi_tpu.telemetry import trace
+
+        cfg, model, params = tiny_lm
+        old = trace.set_enabled(False)
+        try:
+            registry = MetricsRegistry()
+            engine = ServingEngine(cfg, params, ENGINE_CFG, dtype=jnp.float32, registry=registry)
+            engine.warmup()
+            warmed = registry.snapshot()["serve_compile_total"]
+            reqs = _serve_spans_run(engine, _span_prompts())
+        finally:
+            trace.set_enabled(old)
+        assert registry.snapshot()["serve_compile_total"] == warmed
+        assert [r.generated for r in reqs] == [r.generated for r in step_trace["reqs"]]
+        for req, prompt in zip(reqs, _span_prompts()):
+            assert req.generated == _offline_greedy(model, params, prompt, req.max_new_tokens)
+
+    @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk", "verify_step"])
+    def test_programs_are_named_and_scoped(self, tiny_lm, program):
+        """The lowered module is ``jit_<program>`` (nothing ``_unknown``) and
+        every operation's location carries one of the layer scopes."""
+        engine = _spec_engine(tiny_lm)
+        e = engine.engine
+        slots = jnp.zeros((e.max_slots,), jnp.int32)
+        tables = jnp.zeros((e.max_slots, e.max_blocks_per_seq), jnp.int32)
+        live = jnp.zeros((e.max_slots,), bool)
+        jitted, args = {
+            "decode_step": (engine._decode_jit, (tables, slots, slots, live)),
+            "prefill_chunk": (engine._prefill_jit, (
+                jnp.zeros((e.max_blocks_per_seq,), jnp.int32), jnp.zeros((e.prefill_chunk,), jnp.int32),
+                jnp.int32(0), jnp.int32(1),
+            )),
+            "verify_step": (engine._verify_jit, (
+                tables, slots, jnp.zeros((e.max_slots, e.spec_k + 1), jnp.int32), slots, live,
+            )),
+        }[program]
+        text = jitted.lower(engine.params, engine._kv, *args).as_text(debug_info=True)
+        assert f"module @jit_{program} " in text and "_unknown" not in text
+        for scope in _SCOPES:
+            assert f"jit({program})/{scope}/" in text, scope
+        if program == "decode_step":  # the draft's and a tuned variant's, too
+            draft = engine._spec._decode_jit.lower(
+                engine._spec.params, engine._spec._kv, tables, slots, slots, live
+            ).as_text()
+            assert "module @jit_decode_step " in draft
+            assert engine._fwd.decode_program(use_kernel=True, block=64).__name__ == "decode_step"
