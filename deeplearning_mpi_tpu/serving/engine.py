@@ -212,6 +212,9 @@ class PagedForward:
     gather goes through :meth:`_kv_scatter` / :meth:`_kv_gather`, so the
     int8 path quantizes rows on the way into the pool and dequantizes
     inside the gather, leaving the attention math itself dtype-blind.
+    Both index the whole ``[layers, blocks, ...]`` pool with the layer in
+    the index: no program holds a value of one layer's pool shape
+    (``tests/test_serving.py::test_page_gather_reads_the_pool_in_place``).
     """
 
     def __init__(
@@ -269,15 +272,21 @@ class PagedForward:
     ) -> tuple[jax.Array, jax.Array]:
         """Gather layer ``i``'s pages through ``tables``, returning K/V in
         the compute dtype — the int8 path dequantizes here, inside the
-        jitted program, so downstream attention never sees storage."""
+        jitted program, so downstream attention never sees storage.
+
+        The pool is read in place: ``pool[i, tables]`` is ONE gather over
+        the whole ``[layers, blocks, ...]`` buffer. ``pool[i][tables]``
+        first slices out layer ``i``, and XLA materialises the slice — a
+        copy of a layer's whole pool per K and V per layer in every
+        program, 13 ms of a 37 ms decode step on the v5e (PERF.md, PR 28)."""
         with annotate("attn/kv_gather"):
             if not self.quantized:
                 k_pool, v_pool = kv
-                return k_pool[i][tables], v_pool[i][tables]
+                return k_pool[i, tables], v_pool[i, tables]
             k_pool, v_pool, k_scale, v_scale = kv
             return (
-                dequantize_kv(k_pool[i][tables], k_scale[i][tables], self.dtype),
-                dequantize_kv(v_pool[i][tables], v_scale[i][tables], self.dtype),
+                dequantize_kv(k_pool[i, tables], k_scale[i, tables], self.dtype),
+                dequantize_kv(v_pool[i, tables], v_scale[i, tables], self.dtype),
             )
 
     # -- copy-on-write block copy (prefix cache) -----------------------------

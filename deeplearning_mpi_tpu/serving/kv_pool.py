@@ -364,8 +364,10 @@ def init_kv_buffers(
     ``ops/quant.quantize_kv``).
 
     One array per K/V (not per layer) so the jitted engine step threads a
-    handful of buffers instead of ``2 * num_layers`` — the layer axis is
-    indexed statically inside the step's Python layer loop.
+    handful of buffers instead of ``2 * num_layers`` — the step's Python
+    layer loop puts the static layer number into the scatter's and the
+    gather's own index (``pool[i, blocks]``, never ``pool[i][blocks]``,
+    whose slice is a copy of the layer's whole pool).
     """
     import jax.numpy as jnp
 

@@ -1626,16 +1626,15 @@ class TestStepSpans:
         for req, prompt in zip(reqs, _span_prompts()):
             assert req.generated == _offline_greedy(model, params, prompt, req.max_new_tokens)
 
-    @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk", "verify_step"])
-    def test_programs_are_named_and_scoped(self, tiny_lm, program):
-        """The lowered module is ``jit_<program>`` (nothing ``_unknown``) and
-        every operation's location carries one of the layer scopes."""
-        engine = _spec_engine(tiny_lm)
+    @staticmethod
+    def _program(engine, program):
+        """One of the engine's three jitted programs and arguments (after
+        ``params`` and ``kv``) of the shapes it is compiled for."""
         e = engine.engine
         slots = jnp.zeros((e.max_slots,), jnp.int32)
         tables = jnp.zeros((e.max_slots, e.max_blocks_per_seq), jnp.int32)
         live = jnp.zeros((e.max_slots,), bool)
-        jitted, args = {
+        return {
             "decode_step": (engine._decode_jit, (tables, slots, slots, live)),
             "prefill_chunk": (engine._prefill_jit, (
                 jnp.zeros((e.max_blocks_per_seq,), jnp.int32), jnp.zeros((e.prefill_chunk,), jnp.int32),
@@ -1645,13 +1644,52 @@ class TestStepSpans:
                 tables, slots, jnp.zeros((e.max_slots, e.spec_k + 1), jnp.int32), slots, live,
             )),
         }[program]
+
+    @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk", "verify_step"])
+    def test_programs_are_named_and_scoped(self, tiny_lm, program):
+        """The lowered module is ``jit_<program>`` (nothing ``_unknown``) and
+        every operation's location carries one of the layer scopes."""
+        engine = _spec_engine(tiny_lm)
+        jitted, args = self._program(engine, program)
         text = jitted.lower(engine.params, engine._kv, *args).as_text(debug_info=True)
         assert f"module @jit_{program} " in text and "_unknown" not in text
         for scope in _SCOPES:
             assert f"jit({program})/{scope}/" in text, scope
         if program == "decode_step":  # the draft's and a tuned variant's, too
             draft = engine._spec._decode_jit.lower(
-                engine._spec.params, engine._spec._kv, tables, slots, slots, live
+                engine._spec.params, engine._spec._kv, *args
             ).as_text()
             assert "module @jit_decode_step " in draft
             assert engine._fwd.decode_program(use_kernel=True, block=64).__name__ == "decode_step"
+
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["float", "int8"])
+    @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk", "verify_step"])
+    def test_page_gather_reads_the_pool_in_place(self, tiny_lm, program, kv_dtype):
+        """No value of a per-layer pool's shape exists in any program, and
+        every gather of KV pages (or their scales) takes a whole pool as its
+        operand: ``pool[i][tables]`` would copy layer ``i``'s whole pool
+        once per K and V per layer before gathering from the copy."""
+        engine = _spec_engine(tiny_lm, base_cfg=dataclasses.replace(ENGINE_CFG, kv_dtype=kv_dtype))
+        jitted, args = self._program(engine, program)
+        pools = {buf.shape for buf in engine._kv}
+        assert len(pools) == (2 if kv_dtype else 1)
+        layer_slices = {shape[1:] for shape in pools}
+        pages = {shape[2:] for shape in pools}  # [block_size, Hkv(, D)]
+
+        def equations(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from equations(sub)
+
+        closed = jax.make_jaxpr(jitted)(engine.params, engine._kv, *args)
+        kv_gathers = 0
+        for eqn in equations(closed.jaxpr):
+            for out in eqn.outvars:
+                assert getattr(out.aval, "shape", None) not in layer_slices, eqn
+            if eqn.primitive.name == "gather":
+                operand = eqn.invars[0].aval.shape
+                if any(operand[-len(page):] == page for page in pages):
+                    assert operand in pools, eqn
+                    kv_gathers += 1
+        assert kv_gathers == len(engine._kv) * engine.config.num_layers
