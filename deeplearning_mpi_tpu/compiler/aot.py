@@ -16,9 +16,10 @@ Three layers:
   ``telemetry/flops.xla_cost_analysis`` — the measured complement to the
   analytic estimators.
 - :class:`WarmProgram` — the callable swapped into hot paths: the compiled
-  executable on the fast path, falling back to the original jitted callable
-  if an argument signature ever drifts (AOT executables reject unseen
-  avals with a TypeError instead of retracing).
+  executable on the fast path (one per static shape where a function is
+  warmed at several), falling back to the original jitted callable if an
+  argument signature ever drifts (AOT executables reject unseen avals with
+  a TypeError instead of retracing).
 - :class:`WarmupRegistry` — named programs registered with their example
   arguments, compiled in one ``warm_all()`` sweep; how the trainer step and
   both serving programs (decode step, chunked prefill) precompile before
@@ -155,19 +156,36 @@ class WarmProgram:
     compiled for (AOT never retraces); the fallback keeps a signature drift
     — a config change, an unexpected dtype — a silent recompile instead of
     a crash. ``fallback_calls`` counts how often the net was needed (zero
-    in a correctly-warmed engine)."""
+    in a correctly-warmed engine).
 
-    def __init__(self, program: CompiledProgram, fallback: Callable[..., Any]):
+    A function warmed at several static shapes hands in a dict of programs
+    keyed by the shape of its ``shape_arg``-th argument: the call picks its
+    executable by that shape, so no listed shape pays a raised and caught
+    exception on its way to the program."""
+
+    def __init__(
+        self,
+        program: CompiledProgram | dict[tuple[int, ...], CompiledProgram],
+        fallback: Callable[..., Any],
+        *,
+        shape_arg: int | None = None,
+    ):
         self.program = program
         self.fallback = fallback
+        self.shape_arg = shape_arg
         self.fallback_calls = 0
 
     def __call__(self, *args: Any) -> Any:
-        try:
-            return self.program.compiled(*args)
-        except TypeError:
-            self.fallback_calls += 1
-            return self.fallback(*args)
+        program = self.program
+        if self.shape_arg is not None:
+            program = program.get(args[self.shape_arg].shape)
+        if program is not None:
+            try:
+                return program.compiled(*args)
+            except TypeError:
+                pass
+        self.fallback_calls += 1
+        return self.fallback(*args)
 
 
 class WarmupRegistry:

@@ -224,8 +224,15 @@ class CompileCache:
 
     def snapshot(self) -> frozenset[str]:
         """Entry names right now — diff two snapshots around a compile to
-        tell a persistent-cache hit (no new file) from a miss (new file)."""
-        return frozenset(e.name for e in self.entries())
+        tell a persistent-cache hit (no new file) from a miss (new file).
+        Names alone, no stat per entry: two of these bracket every warmed
+        program, and :meth:`entries` costs 0.16 s over 230 entries on the
+        chip's host."""
+        if not self.enabled:
+            return frozenset()
+        return frozenset(
+            n for n in os.listdir(self.path) if n.endswith(CACHE_SUFFIX)
+        )
 
     # -- hit/miss accounting -------------------------------------------------
     def observe_compile(

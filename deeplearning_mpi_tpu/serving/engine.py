@@ -4,14 +4,15 @@ The offline path (``models/generate``) compiles one program per batch whose
 cache is sized ``prompt + max_new`` and whose rows march in lockstep. A
 serving engine inverts every one of those assumptions: requests arrive and
 finish independently, so the engine compiles a small fixed set of programs
-once — a batched decode step over ``max_slots`` rows, a per-slot prefill
-chunk, and (with speculative decoding on) a batched multi-token verify
-step — and a host-side loop swaps finished sequences out of slots between
-steps. Every jitted shape is static (slot count, gathered KV length, chunk
-width, speculation width), so admission, completion, and eviction never
-trigger recompilation; the only thing that changes step to step is the
-*contents* of the slot-indexed arrays (block tables, fill levels, last
-tokens, active mask).
+once — a batched decode step over up to ``max_slots`` rows, a per-slot
+prefill chunk, and (with speculative decoding on) a batched multi-token
+verify step — and a host-side loop swaps finished sequences out of slots
+between steps. Every jitted shape is static and comes from a short list
+warmed before traffic (the block table's rows x width bucket, chunk width,
+speculation width: ``_table_shapes``), so admission, completion, and
+eviction never trigger recompilation; what changes step to step is which
+warmed shape the live sequences pick and the *contents* of the arrays (block
+tables, fill levels, last tokens, active mask).
 
 Layer map (see ``docs/SERVING.md`` for the full walkthrough):
 
@@ -95,6 +96,35 @@ from deeplearning_mpi_tpu.serving.scheduler import (
 from deeplearning_mpi_tpu.telemetry.trace import annotate, span
 
 __all__ = ["EngineConfig", "KVBuffers", "PagedForward", "ServingEngine"]
+
+
+def _table_shapes(
+    max_slots: int, max_blocks: int
+) -> tuple[tuple[int, ...], tuple[tuple[int, int], ...]]:
+    """The static shapes a block table may take, from the engine's two
+    ceilings alone: the ladder of widths (the prefill chunk's; the verify
+    step's and the draft's at ``max_slots`` rows) and the decode step's
+    (rows, width) pairs. Together they are the programs ``warmup()`` pays
+    for, so both lists are short.
+
+    Widths: the powers of two from an eighth of the full table up, then the
+    full table; a rung below an eighth would save under a sixteenth of the
+    full gather. Decode pairs: a quarter, a half and all of ``max_slots``
+    rows at the two widest rungs, and ``max_slots`` rows at every rung. The
+    gather costs rows x width, so a narrow table is cheap already and a rung
+    saves the most where the rows are many: the narrow rungs are not
+    multiplied by the row buckets."""
+    widths = []
+    w = 1
+    while w < max_blocks:
+        if 8 * w >= max_blocks:
+            widths.append(w)
+        w *= 2
+    widths.append(max_blocks)
+    rows = {-(-max_slots // 4), -(-max_slots // 2), max_slots}
+    pairs = {(r, w) for r in rows for w in widths[-2:]}
+    pairs |= {(max_slots, w) for w in widths}
+    return tuple(widths), tuple(sorted(pairs))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -396,19 +426,26 @@ class PagedForward:
         use_kernel: bool | None = False,
         block: int | None = None,
     ) -> tuple[tuple[jax.Array, ...], jax.Array]:
+        """One token for each of the table's ``S`` rows. Static per
+        PROGRAM: ``S`` and ``MB``, read off ``tables`` (any row count up to
+        ``max_slots``, any width up to ``max_blocks_per_seq``; a row is a
+        packed position, not a slot). Static per ENGINE: ``block_size``,
+        the model, the pools. Rows with ``active`` false are padding: they
+        write to the scratch block and their token is garbage."""
         # Host side effect at TRACE time only: one tick per compilation of
         # this program. A warmed engine calls the AOT executable directly
         # (never retraces), so "zero compiles on the first request" is an
         # assertable counter delta, not a timing heuristic.
         self._tick()
-        cfg, e = self.config, self.engine
-        S, BS = e.max_slots, e.block_size
-        # Static gather width from the TABLE shape, not the engine ceiling:
-        # the host slices the block tables to this step's live bucket
-        # (ServingEngine._gather_width), so a batch of shallow sequences
-        # streams O(bucket) KV per layer instead of always paying the full
-        # max_blocks_per_seq-wide gather. One compile per distinct width.
-        MB = tables.shape[1]
+        cfg, BS = self.config, self.engine.block_size
+        # Both static sizes come from the TABLE, not the engine's ceilings:
+        # the host packs the rows that decode into this step's row bucket
+        # and cuts the table to its width bucket
+        # (ServingEngine._decode_shape), so the page gather and the
+        # attention over it cost O(rows x width) of what is live instead of
+        # max_slots x max_blocks_per_seq. One compile per distinct (S, MB);
+        # only block_size is the engine's.
+        S, MB = tables.shape
         L = MB * BS
         kv_heads = cfg.num_kv_heads or cfg.num_heads
         x = self._embed(params, tokens)[:, None, :]  # [S, 1, d]
@@ -457,10 +494,18 @@ class PagedForward:
         start: jax.Array,   # scalar int32: absolute position of tokens[0]
         n_valid: jax.Array,  # scalar int32: real rows in the chunk
     ) -> tuple[tuple[jax.Array, ...], jax.Array]:
+        """One chunk of one prompt through the slot's block table. Static
+        per PROGRAM: the table's width ``MB`` — the host cuts it to the
+        bucket covering the positions the chunk can see
+        (``start + n_valid``, ServingEngine._prefill_one), so keys past
+        the chunk's reach are never gathered, repeated or scored. Static
+        per ENGINE: ``prefill_chunk`` and ``block_size``. Rows past
+        ``n_valid`` clamp to the table's last position and write to the
+        scratch block."""
         # Trace-time compile tick — see decode_step.
         self._tick()
         cfg, e = self.config, self.engine
-        MB, BS, C = e.max_blocks_per_seq, e.block_size, e.prefill_chunk
+        MB, BS, C = table.shape[0], e.block_size, e.prefill_chunk
         L = MB * BS
         kv_heads = cfg.num_kv_heads or cfg.num_heads
         rep = cfg.num_heads // kv_heads
@@ -727,6 +772,12 @@ class ServingEngine:
             ))
         self._kvh = kv_buffers
         self._kv_dtype_name = (storage or jnp.dtype(dtype)).name
+        # The static shapes a step's block table can take (docs/SERVING.md
+        # "The fixed-shape step"): derived from max_slots and
+        # max_blocks_per_seq alone, and the very lists warmup() walks.
+        self._widths, self._decode_shapes = _table_shapes(
+            engine.max_slots, engine.max_blocks_per_seq
+        )
         self._next_rid = 0
         self.steps = 0
         self._metrics = registry
@@ -741,6 +792,7 @@ class ServingEngine:
                 "serve_tokens_generated", "serve_prefill_chunks",
                 "serve_decode_steps", "serve_requeued_total",
                 "serve_tokens_discarded_total",
+                "serve_gather_blocks", "serve_live_blocks",
             ):
                 registry.counter(name)
             # A role-labeled engine (one half of a disaggregated pair)
@@ -882,17 +934,19 @@ class ServingEngine:
 
         return call
 
-    def _is_base_schedule(self, tuned: dict[str, Any], width: int) -> bool:
+    def _is_base_schedule(
+        self, tuned: dict[str, Any], rows: int, width: int
+    ) -> bool:
         """True when a tuned bucket entry names the very schedule the base
         decode program (``use_kernel=None``) already resolved at trace time
-        for this gather width — swapping to a variant would lazily compile
+        for this table shape — swapping to a variant would lazily compile
         a byte-identical duplicate, so the caller stays on the warmed base
         program instead."""
         from deeplearning_mpi_tpu.compiler import autotune
 
         base = autotune.tuned_decode_schedule(
             (
-                self.engine.max_slots, width * self.engine.block_size,
+                rows, width * self.engine.block_size,
                 self.config.num_kv_heads or self.config.num_heads,
                 self.config.head_dim,
             ),
@@ -932,14 +986,16 @@ class ServingEngine:
     def warmup(self, *, cache: Any = None) -> dict[str, Any]:
         """AOT-compile the serving programs before traffic.
 
-        Lowers and compiles the batched decode step, the chunked-prefill
-        program, and — when speculative decoding is configured — the verify
-        step plus the draft model's decode/prefill programs, all at their
-        exact serving shapes (every jitted shape is static by design — see
-        the module docstring — so warmup's avals are the only avals the
-        engine will ever call with), then swaps the compiled executables
+        Lowers and compiles the batched decode step and the chunked-prefill
+        program at every table shape the bucket functions can emit
+        (:func:`_table_shapes`), and — when speculative decoding is
+        configured — the verify step plus the draft model's decode/prefill
+        programs at the full table (every jitted shape is static by design
+        — see the module docstring — so warmup's avals are the only avals
+        the engine will ever call with), then swaps the compiled executables
         into the hot path wrapped in
-        :class:`~deeplearning_mpi_tpu.compiler.aot.WarmProgram`. A compiled
+        :class:`~deeplearning_mpi_tpu.compiler.aot.WarmProgram`, which picks
+        the executable by the table's shape. A compiled
         executable never retraces, so a warmed engine performs ZERO
         compiles on its first request — asserted by the
         ``serve_compile_total`` trace counter in ``tests/test_compiler.py``
@@ -959,28 +1015,42 @@ class ServingEngine:
 
         e = self.engine
         reg = aot.WarmupRegistry(registry=self._metrics, cache=cache)
-        slots_i32 = jnp.zeros((e.max_slots,), jnp.int32)
-        reg.register(
-            "serve_decode_step", self._decode_jit,
-            self.params, self._kv,
-            jnp.zeros((e.max_slots, e.max_blocks_per_seq), jnp.int32),
-            slots_i32, slots_i32, jnp.zeros((e.max_slots,), bool),
-        )
-        reg.register(
-            "serve_prefill_chunk", self._prefill_jit,
-            self.params, self._kv,
-            jnp.zeros((e.max_blocks_per_seq,), jnp.int32),
-            jnp.zeros((e.prefill_chunk,), jnp.int32),
-            jnp.int32(0), jnp.int32(1),
-        )
+
+        def zeros(*shape: int, dtype: Any = jnp.int32) -> jax.Array:
+            return jnp.zeros(shape, dtype)
+
+        # One program for every table shape the bucket functions can emit
+        # (_table_shapes): the count of these is warm-up's cost. The full
+        # shape keeps the bare name.
+        full = (e.max_slots, e.max_blocks_per_seq)
+        decode_names = {
+            shape: "serve_decode_step" + (
+                "" if shape == full else "@{}x{}".format(*shape)
+            )
+            for shape in self._decode_shapes
+        }
+        for (rows, wb), name in decode_names.items():
+            reg.register(
+                name, self._decode_jit,
+                self.params, self._kv, zeros(rows, wb),
+                zeros(rows), zeros(rows), zeros(rows, dtype=bool),
+            )
+        prefill_names = {
+            (wb,): "serve_prefill_chunk" + ("" if wb == full[1] else f"@{wb}")
+            for wb in self._widths
+        }
+        for (wb,), name in prefill_names.items():
+            reg.register(
+                name, self._prefill_jit,
+                self.params, self._kv, zeros(wb), zeros(e.prefill_chunk),
+                jnp.int32(0), jnp.int32(1),
+            )
         if self._spec is not None:
             reg.register(
                 "serve_verify_step", self._verify_jit,
-                self.params, self._kv,
-                jnp.zeros((e.max_slots, e.max_blocks_per_seq), jnp.int32),
-                slots_i32,
-                jnp.zeros((e.max_slots, e.spec_k + 1), jnp.int32),
-                slots_i32, jnp.zeros((e.max_slots,), bool),
+                self.params, self._kv, zeros(*full), zeros(e.max_slots),
+                zeros(e.max_slots, e.spec_k + 1),
+                zeros(e.max_slots), zeros(e.max_slots, dtype=bool),
             )
             self._spec.register_warmup(reg)
         if self.prefix_cache is not None:
@@ -995,11 +1065,15 @@ class ServingEngine:
                 self._metrics.histogram("serve_compile_seconds").observe(
                     prog.lower_seconds + prog.compile_seconds
                 )
+        # The table is argument 2 of both programs: its shape picks the
+        # executable, so a listed shape never falls through to the jit.
         self._decode_fn = aot.WarmProgram(
-            programs["serve_decode_step"], self._decode_jit
+            {s: programs[n] for s, n in decode_names.items()},
+            self._decode_jit, shape_arg=2,
         )
         self._prefill_fn = aot.WarmProgram(
-            programs["serve_prefill_chunk"], self._prefill_jit
+            {s: programs[n] for s, n in prefill_names.items()},
+            self._prefill_jit, shape_arg=2,
         )
         if self._spec is not None:
             self._verify_fn = aot.WarmProgram(
@@ -1010,23 +1084,20 @@ class ServingEngine:
             self._copy_fn = aot.WarmProgram(
                 programs["serve_kv_copy_block"], self._copy_jit
             )
-        # Pre-trace every narrower gather-width bucket through the jit
-        # fallbacks (WarmProgram covers only the full-width avals): an
-        # all-inactive batch routes its writes to the scratch block and
-        # rebinds the donated pools, so these calls compile + execute
-        # harmlessly and width dispatch never compiles mid-traffic.
-        idle = jnp.zeros((e.max_slots,), jnp.int32)
-        off = jnp.zeros((e.max_slots,), bool)
-        for wb in self._gather_widths()[:-1]:
-            t = jnp.zeros((e.max_slots, wb), jnp.int32)
-            self._kv, _ = self._decode_jit(
-                self.params, self._kv, t, idle, idle, off
-            )
-            if self._spec is not None:
+        # The verify step and the draft's decode keep max_slots rows and
+        # one executable each, at the full table: their narrower widths are
+        # pre-traced through the jit fallbacks. An all-inactive batch routes
+        # its writes to the scratch block and rebinds the donated pools, so
+        # these calls compile + execute harmlessly and no width transition
+        # compiles mid-traffic.
+        if self._spec is not None:
+            idle = zeros(e.max_slots)
+            off = zeros(e.max_slots, dtype=bool)
+            for wb in self._widths[:-1]:
+                t = zeros(e.max_slots, wb)
                 self._kv, _ = self._verify_jit(
                     self.params, self._kv, t, idle,
-                    jnp.zeros((e.max_slots, e.spec_k + 1), jnp.int32),
-                    idle, off,
+                    zeros(e.max_slots, e.spec_k + 1), idle, off,
                 )
                 self._spec.pretrace_width(t, idle, off)
         self._warmed = True
@@ -1235,52 +1306,53 @@ class ServingEngine:
             else:
                 self._plain_decode(decoding, finished)
 
-    def _gather_width(self, blocks_held: int) -> int:
-        """Static block-table width for this step's jitted program: the
-        power-of-two bucket (capped at the full table) covering the widest
-        live row. The decode/verify programs' page gather streams O(width)
-        KV per layer — at serving batch sizes that traffic rivals the
-        matmuls — so shallow fills must not pay the full
-        ``max_blocks_per_seq``-wide gather. This is the same (batch,
-        context)-bucket observation the ``decode_bucket|...`` tuning key
-        space encodes, applied to the gather itself; :meth:`warmup`
-        pre-traces every width so a warmed engine never compiles on a
-        bucket transition."""
-        from deeplearning_mpi_tpu.compiler.autotune import pow2_bucket
+    def _gather_width(self, blocks: int) -> int:
+        """Static block-table width covering ``blocks``: the narrowest rung
+        of ``_widths`` (:func:`_table_shapes`) that holds them. The
+        programs' page gather streams O(width) KV per layer — at serving
+        batch sizes that traffic rivals the matmuls — so a shallow fill must
+        not pay the full ``max_blocks_per_seq``-wide gather. :meth:`warmup`
+        compiles every width, so a warmed engine never compiles on a bucket
+        transition."""
+        return next(w for w in self._widths if w >= blocks)
 
-        return pow2_bucket(
-            max(blocks_held, 1), cap=self.engine.max_blocks_per_seq
+    def _decode_shape(self, rows: int, blocks_held: int) -> tuple[int, int]:
+        """Static (rows, width) of this step's decode table: of the pairs
+        in ``_decode_shapes`` (:func:`_table_shapes`) that hold the ``rows``
+        that decode and the widest live row's ``blocks_held``, the one of
+        least rows x width (the gather and the attention over it are linear
+        in that product), with fewer rows on a tie. Both sizes are static
+        per PROGRAM, so the step costs what is live."""
+        return min(
+            (s for s in self._decode_shapes
+             if s[0] >= rows and s[1] >= blocks_held),
+            key=lambda s: (s[0] * s[1], s),
         )
-
-    def _gather_widths(self) -> list[int]:
-        """Every width :meth:`_gather_width` can emit, ascending."""
-        mb = self.engine.max_blocks_per_seq
-        out = []
-        w = 1
-        while w < mb:
-            out.append(w)
-            w *= 2
-        out.append(mb)
-        return out
 
     def _plain_decode(
         self, decoding: list[Request], finished: list[Request]
     ) -> None:
         e = self.engine
-        with span("serve/decode_launch", rows=len(decoding)) as sp:
-            tables = np.zeros((e.max_slots, e.max_blocks_per_seq), np.int32)
-            lengths = np.zeros((e.max_slots,), np.int32)
-            tokens = np.zeros((e.max_slots,), np.int32)
-            active = np.zeros((e.max_slots,), bool)
-            for req in decoding:
-                s = req.slot
-                tables[s, : len(req.blocks)] = req.blocks
-                lengths[s] = req.length
-                tokens[s] = req.generated[-1]
-                active[s] = True
-            tables = tables[
-                :, : self._gather_width(max(len(r.blocks) for r in decoding))
-            ]
+        # Only the rows that decode go to the device, packed in the order
+        # of ``decoding`` into the warmed table that holds them in the
+        # least rows x width; row i of every array — and of the tokens that
+        # come back — is decoding[i], whatever its slot. Pad rows are
+        # inactive.
+        held = [len(r.blocks) for r in decoding]
+        rows, width = self._decode_shape(len(decoding), max(held))
+        with span(
+            "serve/decode_launch",
+            rows=len(decoding), table_rows=rows, width=width,
+        ):
+            tables = np.zeros((rows, width), np.int32)
+            lengths = np.zeros((rows,), np.int32)
+            tokens = np.zeros((rows,), np.int32)
+            active = np.zeros((rows,), bool)
+            for i, req in enumerate(decoding):
+                tables[i, : held[i]] = req.blocks
+                lengths[i] = req.length
+                tokens[i] = req.generated[-1]
+                active[i] = True
             fn = self._decode_fn
             if e.use_kernel is None:
                 # Per-(batch, context)-bucket schedule: a tuned decode_bucket|...
@@ -1300,7 +1372,7 @@ class ServingEngine:
                     role=self.role,
                 )
                 if tuned is not None and not self._is_base_schedule(
-                    tuned, tables.shape[1]
+                    tuned, rows, width
                 ):
                     fn = self._decode_variant(
                         tuned["schedule"] == "kernel", tuned.get("block")
@@ -1315,14 +1387,15 @@ class ServingEngine:
                 {req.blocks[(req.length - 1) // BS] for req in decoding}
             )
             self._inc("serve_decode_steps")
-            sp.set_metadata(width=tables.shape[1])
+            self._inc("serve_gather_blocks", rows * width)
+            self._inc("serve_live_blocks", sum(held))
         with span("serve/token_fetch"):
             next_np = np.asarray(jax.device_get(next_tok))  # dmt-lint: disable=DMT003 — THE audited sync: one sampled-token fetch per decode step (EOS/retire decisions are host-side)
         with span("serve/retire") as sp:
             before = len(finished)
             now = self._clock()
-            for req in decoding:
-                tok = int(next_np[req.slot])
+            for i, req in enumerate(decoding):
+                tok = int(next_np[i])
                 req.generated.append(tok)
                 self._inc("serve_tokens_generated")
                 if self._done(req, tok):
@@ -1408,6 +1481,11 @@ class ServingEngine:
             self._record_writes(touched)
             self._inc("serve_decode_steps")
             self._inc("spec_verify_steps")
+            # the target's verify gather; the draft's own are not counted
+            self._inc("serve_gather_blocks", e.max_slots * tables.shape[1])
+            self._inc(
+                "serve_live_blocks", sum(len(r.blocks) for r in decoding)
+            )
         with span("serve/verify_fetch"):
             greedy_np = np.asarray(jax.device_get(greedy))  # [S, W]  # dmt-lint: disable=DMT003 — the audited verify fetch: exact-match acceptance runs on host
         with span("serve/retire") as sp:
@@ -1532,8 +1610,14 @@ class ServingEngine:
         e = self.engine
         start = req.prefilled
         n_valid = min(e.prefill_chunk, req.prompt_len - start)
+        # The target's table is cut to the bucket covering the positions
+        # this chunk can SEE — not the blocks the request holds, which
+        # cover the whole prompt from admission on.
+        reach = self.pool.blocks_for(start + n_valid)
+        width = self._gather_width(reach)
         with span(
-            "serve/prefill_launch", rid=req.rid, start=start, n=n_valid
+            "serve/prefill_launch",
+            rid=req.rid, start=start, n=n_valid, width=width,
         ):
             chunk = np.zeros((e.prefill_chunk,), np.int32)
             chunk[:n_valid] = req.prompt[start : start + n_valid]
@@ -1541,9 +1625,11 @@ class ServingEngine:
             table[: len(req.blocks)] = req.blocks
             self._kv, last_logits = self._prefill_fn(
                 self.params, self._kv,
-                jnp.asarray(table), jnp.asarray(chunk),
+                jnp.asarray(table[:width]), jnp.asarray(chunk),
                 jnp.int32(start), jnp.int32(n_valid),
             )
+            self._inc("serve_gather_blocks", width)
+            self._inc("serve_live_blocks", reach)
             self._record_writes(
                 req.blocks[start // e.block_size :
                            (start + n_valid - 1) // e.block_size + 1]

@@ -54,6 +54,11 @@ METRICS: dict[str, tuple[str, frozenset[str]]] = {
     "serve_compile_total": ("counter", frozenset()),
     "serve_decode_held_steps": ("counter", frozenset()),
     "serve_decode_steps": ("counter", frozenset()),
+    # blocks the programs' tables gather (rows x width a decode or verify step,
+    # width a chunk) against blocks the live rows hold / the chunk can see: their
+    # ratio is the fill of the gather (docs/SERVING.md "The fixed-shape step")
+    "serve_gather_blocks": ("counter", frozenset()),
+    "serve_live_blocks": ("counter", frozenset()),
     "serve_handoff_depth": ("gauge", frozenset()),
     "serve_handoff_stalls_total": ("counter", frozenset()),
     "serve_handoffs_total": ("counter", frozenset()),

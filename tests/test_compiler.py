@@ -577,22 +577,24 @@ class TestWarmedEngine:
         registry = MetricsRegistry()
         engine = self._engine(registry)
         engine.warmup()
-        # Warmup traced the two AOT programs once each (the trace-time tick
-        # in _decode_step/_prefill_chunk) plus one decode variant per
-        # narrower gather-width bucket (here widths [1, 2] below MB=4).
+        # Warmup compiled every table shape the bucket functions can emit,
+        # once each (the trace-time tick in decode_step/prefill_chunk): the
+        # decode program at its (rows, width) pairs, the prefill chunk at
+        # every width — here rows 1 and 2 at widths 2 and 4 and rows 2 at
+        # width 1, prefill at (1, 2, 4).
         compiles = registry.counter("serve_compile_total").value
-        assert compiles == 2 + (len(engine._gather_widths()) - 1)
+        assert compiles == len(engine._decode_shapes) + len(engine._widths)
         req = engine.submit(np.arange(1, 9, dtype=np.int32), 4)
         while not engine.scheduler.idle():
             engine.step()
         assert req.state is RequestState.FINISHED
         # The actual contract: the first request compiled NOTHING.
         assert registry.counter("serve_compile_total").value == compiles
-        # Prefill stayed on the AOT executable. Decode rows holding fewer
-        # than max_blocks_per_seq blocks dispatch through the pre-traced
-        # narrow-width jit — counted as fallback calls, but the zero
-        # compile-delta above proves those widths were already warm.
+        # Every table shape has its own AOT executable, picked by the
+        # table's shape: the chunk (8 tokens, one block) and the decode
+        # rows never fell through to the jit behind them.
         assert engine._prefill_fn.fallback_calls == 0
+        assert engine._decode_fn.fallback_calls == 0
 
     def test_tuned_einsum_buckets_stay_on_base_program(self):
         """A decode_bucket| entry whose winner IS the base program's

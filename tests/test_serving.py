@@ -1426,14 +1426,16 @@ class TestPrefixCacheDisagg:
 _PHASE_LABELS = {
     "serve/step": {"step", "t"},
     "serve/admit": {"admitted", "queued"},
-    "serve/prefill_launch": {"rid", "start", "n"},
+    "serve/prefill_launch": {"rid", "start", "n", "width"},
     "serve/first_token_fetch": {"rid"},
     "serve/grow": set(),
-    "serve/decode_launch": {"rows", "width"},
+    "serve/decode_launch": {"rows", "table_rows", "width"},
     "serve/token_fetch": set(),
     "serve/retire": {"finished"},
     "serve/gauges": set(),
 }
+_PROGRAMS = ["decode_step", "prefill_chunk", "verify_step"]
+_PACKED = ["decode_step@packed", "prefill_chunk@packed"]  # at the smallest table shape
 _SCOPES = (
     "embed", "attn/qkv", "attn/kv_scatter", "attn/kv_gather", "attn/core",
     "attn/out", "mlp", "logits",
@@ -1574,6 +1576,9 @@ class TestStepSpans:
         assert sum(a["admitted"] for a in admits) == 3 and all(a["queued"] == 0 for a in admits)
         launches = [k[3] for kids in children for k in kids if k[0] == "serve/decode_launch"]
         assert max(l["rows"] for l in launches) == 3 and {l["width"] for l in launches} <= {1, 2, 4, 8}
+        assert all(l["rows"] <= l["table_rows"] <= 3 for l in launches)
+        assert {l["table_rows"] for l in launches} == {1, 3}  # r0 alone; r1 and r2 reach decode in one step
+        assert [c["width"] for c in chunks] == [1, 1, 2, 1]  # the chunk's reach, not the blocks held
         retired = sum(k[3]["finished"] for kids in children for k in kids if k[0] == "serve/retire")
         assert retired == 3
 
@@ -1629,28 +1634,32 @@ class TestStepSpans:
     @staticmethod
     def _program(engine, program):
         """One of the engine's three jitted programs and arguments (after
-        ``params`` and ``kv``) of the shapes it is compiled for."""
+        ``params`` and ``kv``) of the shapes it is compiled for;
+        ``<program>@packed`` at the smallest table the bucket functions emit."""
         e = engine.engine
-        slots = jnp.zeros((e.max_slots,), jnp.int32)
-        tables = jnp.zeros((e.max_slots, e.max_blocks_per_seq), jnp.int32)
-        live = jnp.zeros((e.max_slots,), bool)
+        program, _, packed = program.partition("@")
+        rows, width = engine._decode_shapes[0] if packed else (e.max_slots, e.max_blocks_per_seq)
+        slots = jnp.zeros((rows,), jnp.int32)
+        tables = jnp.zeros((rows, width), jnp.int32)
+        live = jnp.zeros((rows,), bool)
         return {
             "decode_step": (engine._decode_jit, (tables, slots, slots, live)),
             "prefill_chunk": (engine._prefill_jit, (
-                jnp.zeros((e.max_blocks_per_seq,), jnp.int32), jnp.zeros((e.prefill_chunk,), jnp.int32),
-                jnp.int32(0), jnp.int32(1),
+                jnp.zeros((engine._widths[0] if packed else width,), jnp.int32),
+                jnp.zeros((e.prefill_chunk,), jnp.int32), jnp.int32(0), jnp.int32(1),
             )),
             "verify_step": (engine._verify_jit, (
                 tables, slots, jnp.zeros((e.max_slots, e.spec_k + 1), jnp.int32), slots, live,
             )),
         }[program]
 
-    @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk", "verify_step"])
+    @pytest.mark.parametrize("program", [*_PROGRAMS, *_PACKED])
     def test_programs_are_named_and_scoped(self, tiny_lm, program):
         """The lowered module is ``jit_<program>`` (nothing ``_unknown``) and
         every operation's location carries one of the layer scopes."""
         engine = _spec_engine(tiny_lm)
         jitted, args = self._program(engine, program)
+        program = program.partition("@")[0]
         text = jitted.lower(engine.params, engine._kv, *args).as_text(debug_info=True)
         assert f"module @jit_{program} " in text and "_unknown" not in text
         for scope in _SCOPES:
@@ -1663,7 +1672,7 @@ class TestStepSpans:
             assert engine._fwd.decode_program(use_kernel=True, block=64).__name__ == "decode_step"
 
     @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["float", "int8"])
-    @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk", "verify_step"])
+    @pytest.mark.parametrize("program", [*_PROGRAMS, *_PACKED])
     def test_page_gather_reads_the_pool_in_place(self, tiny_lm, program, kv_dtype):
         """No value of a per-layer pool's shape exists in any program, and
         every gather of KV pages (or their scales) takes a whole pool as its
@@ -1693,3 +1702,258 @@ class TestStepSpans:
                     assert operand in pools, eqn
                     kv_gathers += 1
         assert kv_gathers == len(engine._kv) * engine.config.num_layers
+
+
+# ---- the block table at the live rows x the live width (PR 31) -------------
+# max_slots 4 and 8 blocks a sequence: row buckets (1, 2, 4), widths
+# (1, 2, 4, 8), decode shapes 1, 2, 4 x (4, 8).
+LIVE_CFG = EngineConfig(
+    max_slots=4, block_size=4, num_blocks=64, max_blocks_per_seq=8,
+    prefill_chunk=4, max_queue=16,
+)
+
+
+def _own(kv):
+    """A copy of the pools that a donating program may consume."""
+    return tuple(jnp.array(buf) for buf in kv)
+
+
+def _pages(kv):
+    """Every pool past the scratch block, which pad rows write to."""
+    return [np.asarray(buf)[:, 1:] for buf in kv]
+
+
+def _prefilled(engine, prompts, tables):
+    """Pools holding ``prompts`` written whole through ``tables``, by the
+    full-width prefill program."""
+    e = engine.engine
+    kv = _own(engine._kv)
+    for prompt, table in zip(prompts, tables):
+        for start in range(0, len(prompt), e.prefill_chunk):
+            n = min(e.prefill_chunk, len(prompt) - start)
+            chunk = np.zeros((e.prefill_chunk,), np.int32)
+            chunk[:n] = prompt[start:start + n]
+            kv, _ = engine._prefill_jit(
+                engine.params, kv, jnp.asarray(table), jnp.asarray(chunk), jnp.int32(start), jnp.int32(n),
+            )
+    return kv
+
+
+def _full_shape(engine):
+    """The same engine held to the one table shape of its ceilings."""
+    e = engine.engine
+    engine._widths = (e.max_blocks_per_seq,)
+    engine._decode_shapes = ((e.max_slots, e.max_blocks_per_seq),)
+    return engine
+
+
+def _recorded(engine):
+    """Record the table shapes the engine hands its two programs."""
+    seen = {"decode": set(), "prefill": set()}
+    decode, prefill = engine._decode_fn, engine._prefill_fn
+
+    def decode_fn(params, kv, tables, *rest):
+        seen["decode"].add(tuple(tables.shape))
+        return decode(params, kv, tables, *rest)
+
+    def prefill_fn(params, kv, table, *rest):
+        seen["prefill"].add(table.shape[0])
+        return prefill(params, kv, table, *rest)
+
+    engine._decode_fn, engine._prefill_fn = decode_fn, prefill_fn
+    return seen
+
+
+def _live_shapes_run(engine):
+    """Batches that grow and shrink through every decode shape and prefill
+    width of ``LIVE_CFG``: waves of 4, 2, 1, 2, 3, 1, 3 requests of short,
+    middling and long contexts, then a staggered mix. Returns the requests
+    in submission order."""
+    rng = np.random.default_rng(31)
+    reqs = []
+
+    def wave(n, prompt_len, max_new):
+        for _ in range(n):
+            reqs.append(engine.submit(rng.integers(1, 255, size=prompt_len).astype(np.int32), max_new))
+        engine.run_until_idle()
+
+    wave(4, 3, 3)    # 4 rows x 1 block, then 4 x 2
+    wave(2, 3, 3)    # 4 x 1 (less to gather than 2 x 4), then 2 x 4
+    wave(1, 6, 8)    # 1 x 4
+    wave(2, 10, 4)   # 2 x 4
+    wave(3, 10, 4)   # 4 x 4
+    wave(1, 18, 4)   # 1 x 8, and chunks that reach 1, 2, 3, 4 and 5 blocks
+    wave(2, 18, 3)   # 2 x 8
+    wave(3, 18, 3)   # 4 x 8
+    for prompt_len, max_new in ((5, 9), (17, 4), (2, 12), (9, 6), (13, 3), (1, 7)):
+        reqs.append(engine.submit(rng.integers(1, 255, size=prompt_len).astype(np.int32), max_new))
+        engine.step()
+    engine.run_until_idle()
+    return reqs
+
+
+@pytest.fixture(scope="module")
+def live_shapes_run(tiny_lm):
+    """The run above through a warmed engine, and through one held to the
+    full table shape."""
+    cfg, _, params = tiny_lm
+    registry = MetricsRegistry()
+    engine = ServingEngine(cfg, params, LIVE_CFG, dtype=jnp.float32, registry=registry)
+    engine.warmup()
+    warmed = registry.snapshot()["serve_compile_total"]
+    warm = (engine._decode_fn, engine._prefill_fn)
+    seen = _recorded(engine)
+    reqs = _live_shapes_run(engine)
+    full = _full_shape(ServingEngine(cfg, params, LIVE_CFG, dtype=jnp.float32))
+    seen_full = _recorded(full)
+    return {
+        "engine": engine, "reqs": reqs, "seen": seen, "warmed": warmed, "warm": warm, "snapshot": registry.snapshot(),
+        "full_reqs": _live_shapes_run(full), "seen_full": seen_full,
+    }
+
+
+class TestLiveShapes:
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["float", "int8"])
+    @pytest.mark.parametrize("rows", [1, 2, 4], ids=["smallest", "middle", "max_slots"])
+    def test_packed_decode_step_matches_the_full_table(self, tiny_lm, rows, kv_dtype):
+        """``decode_step`` on a table packed to (rows, width) returns the
+        token of every live row, and writes the pages, of the call at
+        (``max_slots``, ``max_blocks_per_seq``) with the same sequences in
+        slots apart."""
+        cfg, _, params = tiny_lm
+        engine = ServingEngine(cfg, params, dataclasses.replace(LIVE_CFG, kv_dtype=kv_dtype), dtype=jnp.float32)
+        e = engine.engine
+        rng = np.random.default_rng(rows)
+        slots = [3, 0][:min(rows, 2)]  # live rows in slots apart and out of order (the smallest bucket holds one)
+        prompts = [rng.integers(1, 255, size=n).astype(np.int32) for n in (9, 6)][:len(slots)]
+        held = [[7, 2, 11], [5, 9]][:len(slots)]  # blocks out of order, none the scratch block
+        tables = np.zeros((e.max_slots, e.max_blocks_per_seq), np.int32)
+        lengths = np.zeros((e.max_slots,), np.int32)
+        tokens = np.zeros((e.max_slots,), np.int32)
+        active = np.zeros((e.max_slots,), bool)
+        for slot, prompt, blocks in zip(slots, prompts, held):
+            tables[slot, :len(blocks)] = blocks
+            lengths[slot] = len(prompt) + 1
+            tokens[slot] = 17 + slot
+            active[slot] = True
+        kv = _prefilled(engine, prompts, [tables[s] for s in slots])
+        width = next(w for r, w in engine._decode_shapes if r == rows and w >= 3)
+        packed = [np.zeros((rows, *a.shape[1:]), a.dtype) for a in (tables[:, :width], lengths, tokens, active)]
+        for i, slot in enumerate(slots):
+            for dst, src in zip(packed, (tables[:, :width], lengths, tokens, active)):
+                dst[i] = src[slot]
+        kv_full, tok_full = engine._decode_jit(engine.params, _own(kv), *map(jnp.asarray, (tables, lengths, tokens, active)))
+        kv_packed, tok_packed = engine._decode_jit(engine.params, _own(kv), *map(jnp.asarray, packed))
+        assert tok_packed.shape == (rows,)
+        assert [int(tok_packed[i]) for i in range(len(slots))] == [int(tok_full[s]) for s in slots]
+        for a, b, before in zip(_pages(kv_packed), _pages(kv_full), _pages(kv)):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+            assert (a != before).any()  # the step did write
+
+    @pytest.mark.parametrize("chunk", [0, 1, 2], ids=["first", "middle", "ragged_last"])
+    def test_prefill_chunk_on_a_table_cut_to_its_reach_matches_the_full_width(self, tiny_lm, chunk):
+        """A chunk sees positions below ``start + n_valid``: on the table cut
+        to the bucket covering them it returns the last row's logits, and
+        writes the pages, of the call at ``max_blocks_per_seq``."""
+        cfg, _, params = tiny_lm
+        engine = ServingEngine(cfg, params, LIVE_CFG, dtype=jnp.float32)
+        e = engine.engine
+        prompt = np.random.default_rng(5).integers(1, 255, size=11).astype(np.int32)
+        table = np.zeros((e.max_blocks_per_seq,), np.int32)
+        table[:3] = [6, 2, 9]  # the request holds its whole prompt's blocks from admission on
+        start = chunk * e.prefill_chunk
+        kv = _prefilled(engine, [prompt[:start]], [table])
+        n = min(e.prefill_chunk, len(prompt) - start)
+        tokens = np.zeros((e.prefill_chunk,), np.int32)
+        tokens[:n] = prompt[start:start + n]
+        width = engine._gather_width(engine.pool.blocks_for(start + n))
+        assert width == (1, 2, 4)[chunk] < e.max_blocks_per_seq
+        rest = (jnp.asarray(tokens), jnp.int32(start), jnp.int32(n))
+        kv_full, logits_full = engine._prefill_jit(engine.params, _own(kv), jnp.asarray(table), *rest)
+        kv_cut, logits_cut = engine._prefill_jit(engine.params, _own(kv), jnp.asarray(table[:width]), *rest)
+        np.testing.assert_allclose(np.asarray(logits_cut), np.asarray(logits_full), rtol=1e-5, atol=1e-5)
+        for a, b, before in zip(_pages(kv_cut), _pages(kv_full), _pages(kv)):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+            assert (a != before).any()
+
+    def test_streams_are_those_of_the_full_shape_engine(self, live_shapes_run):
+        run = live_shapes_run
+        assert run["seen_full"] == {"decode": {(4, 8)}, "prefill": {8}}
+        assert len(run["reqs"]) == len(run["full_reqs"]) == 24
+        for req, full in zip(run["reqs"], run["full_reqs"]):
+            assert req.state is full.state is RequestState.FINISHED
+            assert req.generated == full.generated, req.rid
+
+    def test_the_run_reaches_every_shape_and_compiles_none(self, live_shapes_run):
+        """``warmup()`` traced one program per shape the bucket functions can
+        emit; the run reached every one of them and traced nothing."""
+        run = live_shapes_run
+        engine = run["engine"]
+        assert run["seen"]["decode"] == set(engine._decode_shapes)
+        assert run["seen"]["prefill"] == set(engine._widths)
+        assert run["warmed"] == len(engine._decode_shapes) + len(engine._widths)
+        # each through its own executable, none through the jit behind them
+        assert [fn.fallback_calls for fn in run["warm"]] == [0, 0]
+        assert run["snapshot"]["serve_compile_total"] == run["warmed"]
+
+    def test_the_bucket_functions_emit_only_warmed_shapes(self, tiny_lm):
+        """Every (rows that decode, blocks held) maps to the pair of
+        ``_decode_shapes`` that holds it in the least rows x width, every
+        reach to a width of ``_widths``, and each listed shape is emitted by
+        something: the lists ``warmup()`` walks are the sets the step can
+        ask for."""
+        cfg, _, params = tiny_lm
+        for ecfg in (LIVE_CFG, ENGINE_CFG, EngineConfig(max_slots=32, block_size=16, num_blocks=256, max_blocks_per_seq=224)):
+            engine = ServingEngine(cfg, params, ecfg, dtype=jnp.float32)
+            emitted = set()
+            for rows in range(1, ecfg.max_slots + 1):
+                for held in range(1, ecfg.max_blocks_per_seq + 1):
+                    shape = engine._decode_shape(rows, held)
+                    assert shape[0] >= rows and shape[1] >= held
+                    assert all(
+                        shape[0] * shape[1] <= r * w
+                        for r, w in engine._decode_shapes if r >= rows and w >= held
+                    )
+                    emitted.add(shape)
+            assert emitted == set(engine._decode_shapes)
+            widths = {engine._gather_width(b) for b in range(1, ecfg.max_blocks_per_seq + 1)}
+            assert widths == set(engine._widths)
+            assert (ecfg.max_slots, ecfg.max_blocks_per_seq) in emitted
+            assert len(emitted) + len(widths) <= 12  # the program budget (the one-axis ladder of PR 28 warmed 10)
+        assert engine._widths == (32, 64, 128, 224)  # the benchmark's serving cell
+        assert engine._decode_shapes == (
+            (8, 128), (8, 224), (16, 128), (16, 224), (32, 32), (32, 64), (32, 128), (32, 224),
+        )
+        # a full batch of short rows keeps the narrow table it had before the rows were packed
+        assert engine._decode_shape(32, 20) == engine._decode_shape(12, 20) == (32, 32)
+        assert engine._decode_shape(12, 50) == (16, 128) and engine._decode_shape(5, 20) == (8, 128)
+
+    def test_gather_counters_bound_the_live_blocks(self, live_shapes_run, tiny_lm):
+        snap = live_shapes_run["snapshot"]
+        assert snap["serve_gather_blocks"] >= snap["serve_live_blocks"] > 0
+        # One row of a short prompt, by the buckets: the chunk reaches 1
+        # block at width 1; two decode steps hold 1 then 2 blocks in the
+        # narrowest table warmed for one row, 1 x 4.
+        cfg, _, params = tiny_lm
+        registry = MetricsRegistry()
+        engine = ServingEngine(cfg, params, LIVE_CFG, dtype=jnp.float32, registry=registry)
+        engine.submit(np.arange(1, 4, dtype=np.int32), 3)
+        engine.run_until_idle()
+        one = registry.snapshot()
+        assert engine._gather_width(1) == 1 and engine._decode_shape(1, 2) == (1, 4)
+        assert (one["serve_gather_blocks"], one["serve_live_blocks"]) == (1 + 4 + 4, 1 + 1 + 2)
+
+    def test_the_verify_step_counts_its_gather_too(self, tiny_lm):
+        """A speculating engine gathers ``max_slots`` rows at the widest
+        live row's width in each verify step: one short prompt through
+        ``ENGINE_CFG`` (3 slots) is a chunk at width 1 that reaches 1 block,
+        then verify steps at 3 x 1 and 3 x 2 that hold 1 and 2."""
+        registry = MetricsRegistry()
+        engine = _spec_engine(tiny_lm, registry=registry)
+        req = engine.submit(np.arange(1, 4, dtype=np.int32), 5)
+        engine.run_until_idle()
+        snap = registry.snapshot()
+        steps = snap["spec_verify_steps"]
+        assert req.state is RequestState.FINISHED and steps >= 1
+        assert snap["serve_gather_blocks"] > snap["serve_live_blocks"] > 1
+        assert 1 + 3 * steps <= snap["serve_gather_blocks"] <= 1 + 3 * 2 * steps
