@@ -437,34 +437,9 @@ def bench_spec_decode(
     # The engine arms: the paged engine, batch concurrent requests — once
     # with the self-draft proposing (spec), once with speculation off
     # (plain: the k=0 candidate the spec-k tuner always keeps in the
-    # field). Identical pool geometry so both consult the same tuned
-    # decode-bucket entries.
+    # field). Identical pool geometry in both.
     block_size = 32
     blocks_per_seq = (context + spec_k) // block_size + 2
-    # Feed the per-(batch, context)-bucket decode schedule through the
-    # tuning DB. With --tuning_db the installed DB is consulted as-is;
-    # without one, tune THIS pool shape's live context buckets inline
-    # (repeats=1 — enough to pick a schedule and stamp provenance), so
-    # the engine's per-step consults hit either way and
-    # details.tuning_provenance records which entries drove the run.
-    db = autotune.default_db()
-    if db is None:
-        db = autotune.set_default_db(autotune.TuningDB())
-    max_seq_len = blocks_per_seq * block_size
-    pool_shape = (
-        batch, max_seq_len,
-        cfg.num_kv_heads or cfg.num_heads, cfg.head_dim,
-    )
-    autotune.tune_decode_buckets(
-        pool_shape, dt, db=db,
-        batch_buckets=(batch,),
-        context_buckets=tuple(sorted({
-            autotune.pow2_bucket(c, cap=max_seq_len)
-            for c in (context // 2, context, context + spec_k + 1)
-        })),
-        blocks=(max_seq_len,),
-        repeats=1,
-    )
     base_cfg = dict(
         max_slots=batch,
         block_size=block_size,
@@ -474,9 +449,6 @@ def bench_spec_decode(
         # prompt; a wider fixed-shape chunk would pad-and-waste.
         prefill_chunk=min(64, prompt_len),
         max_queue=2 * batch,
-        # A DB is always installed by this point, so defer the
-        # kernel-vs-einsum choice to its per-bucket winners every step.
-        use_kernel=None,
         decode_buckets=(batch // 2, batch) if batch >= 2 else (),
     )
 

@@ -277,7 +277,7 @@ def _kernel_cases(size: Size, interpret: bool):
 
     def decode_ref(window=None):
         return lambda q, k, v, idx: batched_decode_attention(
-            q, k, v, idx, window=window, use_kernel=False)
+            q, k, v, idx, window=window)
 
     for batch in size.decode_batches:
         yield (
@@ -306,15 +306,6 @@ def _kernel_cases(size: Size, interpret: bool):
 
     yield ("flash_decode_int8", decode_int8, decode_int8_ref,
            decode_args(mid, 5), 1, 3e-2)
-    # The dispatcher the engine and generate.py call: with the kernel asked
-    # for, the kernel must be what runs (it falls to the walk without a word
-    # when the block does not tile).
-    yield (
-        "decode_dispatch",
-        lambda q, k, v, idx: batched_decode_attention(
-            q, k, v, idx, use_kernel=True),
-        decode_ref(), decode_args(mid, 6), 1, 3e-2,
-    )
 
 
 def _ring_flash_case(size: Size):

@@ -247,7 +247,6 @@ def _check_retrace(src: SourceFile) -> list[Finding]:
 _HOT_SCOPES: dict[str, set[str]] = {
     "deeplearning_mpi_tpu/serving/engine.py": {
         "step", "_plain_decode", "_spec_decode", "_prefill_one",
-        "_decode_variant",
     },
     "deeplearning_mpi_tpu/serving/disagg.py": {"step"},
     "deeplearning_mpi_tpu/serving/speculative.py": {"propose", "rollback"},

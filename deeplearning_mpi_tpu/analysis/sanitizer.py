@@ -20,9 +20,7 @@ Three tripwires, each the runtime half of a static rule:
   copy-on-write step and is mutating pages another sharer still reads).
 - **Retrace tripwire** (``sanitize_retrace_trips_total``): after a serving
   engine's :meth:`warmup` completes, the zero-compile contract is armed —
-  any ``serve_compile_total`` tick raises unless it happens under the
-  :func:`allow_compiles` context (tuned per-bucket decode variants are
-  documented lazy compiles, not contract violations).
+  any ``serve_compile_total`` tick raises.
 - **Donation canary** (``sanitize_donation_canary_trips_total``):
   :func:`donation_canary` hashes a state leaf before checkpoint save and
   re-verifies it after the save barrier — the PR 3 aliasing bug (async
@@ -39,7 +37,6 @@ counter names so ``tools/metrics_report.py`` can render them.
 
 from __future__ import annotations
 
-import contextlib
 import hashlib
 import os
 from typing import Any, Iterable
@@ -47,7 +44,6 @@ from typing import Any, Iterable
 __all__ = [
     "KVPoolSanitizer",
     "SanitizerError",
-    "allow_compiles",
     "attach_registry",
     "check_compile_tick",
     "donation_canary",
@@ -72,7 +68,6 @@ class SanitizerError(RuntimeError):
 
 _trips: dict[str, int] = {}
 _registry: Any = None
-_allow_compiles_depth = 0
 
 
 def enabled() -> bool:
@@ -122,23 +117,10 @@ def reset_trips() -> None:
 
 # -- retrace tripwire --------------------------------------------------------
 
-@contextlib.contextmanager
-def allow_compiles():
-    """Scope in which post-warmup compiles are sanctioned (tuned per-bucket
-    decode variants are DB-dependent lazy overlays, documented as outside
-    the zero-compile contract)."""
-    global _allow_compiles_depth
-    _allow_compiles_depth += 1
-    try:
-        yield
-    finally:
-        _allow_compiles_depth -= 1
-
-
 def check_compile_tick(*, post_warmup: bool, what: str = "serving program") -> None:
     """Called where ``serve_compile_total`` ticks. A tick after warmup is a
     retrace — the zero-compile contract every serving drill asserts."""
-    if not post_warmup or not enabled() or _allow_compiles_depth > 0:
+    if not post_warmup or not enabled():
         return
     trip(
         RETRACE_TRIPS,

@@ -85,14 +85,10 @@ def build_parser() -> argparse.ArgumentParser:
                      "KV pool — no KV bytes move) to a decode-only engine, "
                      "so decode batches never stall behind long prefills; "
                      "with --replicas > 1 every replica runs disaggregated")
-    eng.add_argument("--use_kernel", action="store_true",
-                     help="dispatch decode attention to the Pallas "
-                     "flash_decode kernel (per-row fill levels)")
     eng.add_argument("--tuning_db", default=None,
                      help="autotuner tuning DB (tools/autotune.py output): "
-                     "decode schedule and kernel block sizes come from its "
-                     "winners; without --use_kernel the kernel-vs-einsum "
-                     "choice itself defers to the DB")
+                     "feeds --spec_k -1; the decode program does not "
+                     "consult it")
     eng.add_argument("--warmup", action="store_true",
                      help="AOT-compile the decode and prefill programs "
                      "before accepting traffic (compiler/aot.py): first-"
@@ -351,9 +347,12 @@ def replay(engine, entries, *, poll_s: float = 0.0005):
     return reqs, time.monotonic() - t0
 
 
-def _report(reqs, wall_s, registry, out=sys.stderr):
+def _report(reqs, wall_s, registry):
     from deeplearning_mpi_tpu.serving import RequestState
 
+    # Looked up at call time: a default bound at import keeps whatever
+    # stream stood in for stderr then (a test's capture, closed since).
+    out = sys.stderr
     done = [r for r in reqs if r.state is RequestState.FINISHED]
     shed = [r for r in reqs if r.state is RequestState.SHED]
     tokens = sum(len(r.generated) for r in done)
@@ -787,10 +786,6 @@ def main(argv: list[str] | None = None) -> int:
         from deeplearning_mpi_tpu.compiler.autotune import set_default_db
 
         set_default_db(args.tuning_db)
-    # --use_kernel forces the Pallas path; with only a tuning DB the
-    # schedule choice itself (kernel vs einsum) defers to the DB's winner
-    # (use_kernel=None); otherwise the einsum default stands.
-    use_kernel = True if args.use_kernel else (None if args.tuning_db else False)
 
     spec_k = args.spec_k
     if spec_k and args.draft_layers < 1:
@@ -850,7 +845,6 @@ def main(argv: list[str] | None = None) -> int:
             max_blocks_per_seq=args.max_blocks_per_seq,
             prefill_chunk=args.prefill_chunk,
             max_queue=args.max_queue,
-            use_kernel=use_kernel,
             spec_k=spec_k,
             decode_buckets=decode_buckets,
             max_hold_steps=args.max_hold_steps,

@@ -12,8 +12,7 @@ oracle, time with median-of-repeats, persist the winner.
 DB entries are keyed by ``(kernel, shape, dtype, backend)`` — a tuning
 measured on one backend never leaks to another. Call sites
 (``ops/pallas/flash_attention.py``, ``ops/pallas/flash_decode.py``,
-``ops/attention.py`` and through it ``serving/engine.py``) consult
-:func:`default_db` lazily and fall back to the module defaults on any
+``ops/attention.py:decode_attention``) consult :func:`default_db` lazily and fall back to the module defaults on any
 miss, parse error, or absent DB — tuning is an overlay, never a
 requirement.
 
@@ -44,21 +43,17 @@ __all__ = [
     "SPEC_K_CANDIDATES",
     "STEP_REMAT_CANDIDATES",
     "TuningDB",
-    "decode_bucket_key",
     "default_db",
     "expected_tokens_per_step",
-    "pow2_bucket",
     "set_default_db",
     "spec_k_key",
     "step_candidates",
     "step_tuning_key",
-    "tune_decode_buckets",
     "tune_flash_attention",
     "tune_flash_decode",
     "tune_spec_k",
     "tune_step_schedule",
     "tuned_attention_blocks",
-    "tuned_decode_bucket",
     "tuned_decode_schedule",
     "tuned_spec_k",
     "tuned_step_schedule",
@@ -314,122 +309,16 @@ def tuned_attention_blocks(
 
 
 def tuned_decode_schedule(
-    shape: tuple[int, ...], dtype: Any, *, role: str | None = None
+    shape: tuple[int, ...], dtype: Any
 ) -> dict[str, Any] | None:
     """``{"schedule": "kernel"|"einsum", "block": int|None}`` for a
-    ``[B, L, Hkv, D]`` decode buffer, or None when untuned.
-
-    ``role`` selects a disaggregated engine's own key space (a
-    ``|role=decode`` suffix): a prefill-only and a decode-only engine see
-    different live shapes and should keep independent winners. A role
-    lookup falls back to the shared (role-less) entry, so an untuned role
-    inherits the colocated tuning instead of losing it.
-    """
-    if role:
-        try:
-            db = default_db()
-            if db is not None:
-                key = tuning_key(
-                    "flash_decode", shape, dtype, jax.default_backend()
-                ) + f"|role={role}"
-                params = db.lookup_key(key)
-                if params and params.get("schedule") in ("kernel", "einsum"):
-                    return params
-        except Exception:
-            pass
+    ``[B, L, Hkv, D]`` contiguous decode buffer
+    (``ops.attention.decode_attention(use_kernel=None)``), or None when
+    untuned."""
     params = _consult("flash_decode", shape, dtype)
     if not params or params.get("schedule") not in ("kernel", "einsum"):
         return None
     return params
-
-
-# -- decode (batch, context) buckets ------------------------------------------
-
-def pow2_bucket(n: int, cap: int | None = None) -> int:
-    """Round ``n`` up to the next power of two, clamped to ``cap``. The
-    canonical bucketing for live decode (batch, context) values: a serving
-    step's exact batch/fill pair almost never recurs, but its bucket does,
-    so per-bucket entries get consulted instead of missing forever."""
-    n = max(int(n), 1)
-    b = 1
-    while b < n:
-        b *= 2
-    if cap is not None:
-        b = min(b, int(cap))
-    return b
-
-
-def _pow2_buckets(limit: int) -> tuple[int, ...]:
-    """Every value :func:`pow2_bucket` can emit under ``cap=limit`` — the
-    default enumeration the bucket tuner sweeps."""
-    out = []
-    b = 1
-    while b < limit:
-        out.append(b)
-        b *= 2
-    out.append(int(limit))
-    return tuple(out)
-
-
-def decode_bucket_key(
-    batch_bucket: int,
-    context_bucket: int,
-    shape: tuple[int, ...],
-    dtype: Any,
-    backend: str | None = None,
-    role: str | None = None,
-) -> str:
-    """Key for one decode (batch, context) bucket over a ``[S, L, Hkv, D]``
-    gathered-pool shape:
-    ``decode_bucket|b<batch>xc<context>|<dims>|<dtype>|<backend>`` —
-    suffixed ``|role=<role>`` for a disaggregated engine's own key space.
-
-    The plain ``flash_decode`` entry keys on the buffer shape alone, which
-    collapses every live condition a serving step can be in to ONE
-    schedule; the bucket key space splits it by how many slots are live
-    and how deep they are — the two variables the kernel-vs-einsum
-    crossover actually moves with. A prefill-only engine and a decode-only
-    engine split further by role: their live (batch, context) mixes never
-    overlap, so a shared winner is the wrong winner for at least one.
-    """
-    backend = backend or jax.default_backend()
-    dims = "x".join(str(int(s)) for s in shape)
-    key = (
-        f"decode_bucket|b{int(batch_bucket)}xc{int(context_bucket)}|"
-        f"{dims}|{jnp.dtype(dtype).name}|{backend}"
-    )
-    return key + (f"|role={role}" if role else "")
-
-
-def tuned_decode_bucket(
-    batch: int,
-    context: int,
-    shape: tuple[int, ...],
-    dtype: Any,
-    *,
-    role: str | None = None,
-) -> dict[str, Any] | None:
-    """The tuned decode schedule for LIVE (batch, context) values — both
-    bucketed here, batch capped at the slot count and context at the
-    gathered length — or None when untuned. Never raises (call-site
-    consult: the serving hot loop hits this every step). With ``role``
-    set, the role-specific entry wins and the shared entry is the
-    fallback — same inheritance rule as :func:`tuned_decode_schedule`."""
-    try:
-        db = default_db()
-        if db is None:
-            return None
-        bb = pow2_bucket(batch, cap=int(shape[0]))
-        cb = pow2_bucket(context, cap=int(shape[1]))
-        for r in ((role, None) if role else (None,)):
-            params = db.lookup_key(
-                decode_bucket_key(bb, cb, tuple(shape), dtype, role=r)
-            )
-            if params and params.get("schedule") in ("kernel", "einsum"):
-                return params
-        return None
-    except Exception:
-        return None
 
 
 # -- speculative proposal depth -----------------------------------------------
@@ -619,9 +508,9 @@ def tune_flash_decode(
     """Search the decode schedule (einsum vs Pallas kernel) and the
     kernel's KV block for one ``[B, L, Hkv, D]`` buffer shape.
 
-    The einsum schedule (``batched_decode_attention``'s default — the
-    measured-roofline read-everything path) is always a candidate AND the
-    numerics oracle; kernel candidates must match it to compete. Returns
+    The einsum schedule (``batched_decode_attention``, the
+    read-everything path) is always a candidate AND the numerics oracle;
+    kernel candidates must match it to compete. Returns
     the winning ``{"schedule", "block"}`` (recorded into ``db``).
     """
     from deeplearning_mpi_tpu.ops.attention import batched_decode_attention
@@ -646,7 +535,7 @@ def tune_flash_decode(
 
     einsum_fn = jax.jit(
         lambda q, k_buf, v_buf, index: batched_decode_attention(
-            q, k_buf, v_buf, index, use_kernel=False
+            q, k_buf, v_buf, index
         )
     )
     oracle = einsum_fn(q, k_buf, v_buf, index)
@@ -688,123 +577,6 @@ def tune_flash_decode(
             best_seconds=best["seconds"], candidates=results,
         )
     return params
-
-
-def tune_decode_buckets(
-    shape: tuple[int, int, int, int],
-    dtype: Any = jnp.float32,
-    *,
-    heads: int | None = None,
-    db: TuningDB | None = None,
-    batch_buckets: tuple[int, ...] | None = None,
-    context_buckets: tuple[int, ...] | None = None,
-    blocks: tuple[int, ...] | None = None,
-    repeats: int = 3,
-    interpret: bool | None = None,
-) -> dict[str, dict[str, Any]]:
-    """Search the decode schedule PER (batch, context) bucket for one
-    ``[S, L, Hkv, D]`` gathered-pool shape.
-
-    :func:`tune_flash_decode` answers "what schedule for this buffer?"
-    once; a serving engine's buffer shape never changes, but its live
-    conditions do — 2 slots at depth 100 and 32 slots at depth 4000 want
-    different schedules. For every (batch bucket, context bucket) pair
-    this synthesizes the matching live condition on the SAME full-shape
-    buffers (the first ``bb`` rows filled to a spread just under ``cb``,
-    the rest inactive with index −1, exactly how the engine marks empty
-    slots), then runs the einsum-oracle-first schedule search and records
-    the winner under its :func:`decode_bucket_key`. Returns
-    ``{key: params}`` for every bucket tuned.
-    """
-    from deeplearning_mpi_tpu.ops.attention import batched_decode_attention
-    from deeplearning_mpi_tpu.ops.pallas.flash_decode import (
-        decode_block_fits,
-        flash_decode,
-    )
-
-    batch, length, kv_heads, head_dim = shape
-    heads = heads or kv_heads
-    batch_buckets = tuple(batch_buckets or _pow2_buckets(batch))
-    context_buckets = tuple(context_buckets or _pow2_buckets(length))
-    kq, kk, kv = jax.random.split(jax.random.key(2), 3)
-    q = jax.random.normal(kq, (batch, 1, heads, head_dim), dtype)
-    k_buf = jax.random.normal(kk, shape, dtype)
-    v_buf = jax.random.normal(kv, shape, dtype)
-
-    einsum_fn = jax.jit(
-        lambda q, k_buf, v_buf, index: batched_decode_attention(
-            q, k_buf, v_buf, index, use_kernel=False
-        )
-    )
-
-    tuned: dict[str, dict[str, Any]] = {}
-    for bb in batch_buckets:
-        bb = min(int(bb), batch)
-        for cb in context_buckets:
-            cb = min(int(cb), length)
-            # Live rows spread over [cb/2, cb) — the engine's continuous-
-            # batching regime for this bucket; idle rows are index -1.
-            index = jnp.asarray(
-                [
-                    cb - 1 - (i * (cb // 2)) // max(bb - 1, 1)
-                    if i < bb else -1
-                    for i in range(batch)
-                ],
-                jnp.int32,
-            )
-            oracle = einsum_fn(q, k_buf, v_buf, index)
-            results = [{
-                "schedule": "einsum", "block": None,
-                "seconds": measure(einsum_fn, q, k_buf, v_buf, index,
-                                   repeats=repeats),
-            }]
-            best = results[0]
-            seen: set[int] = set()
-            for want in sorted(
-                set(blocks or DECODE_BLOCK_CANDIDATES), reverse=True
-            ):
-                fitted = decode_block_fits(want, length)
-                if fitted is None or fitted in seen:
-                    continue
-                seen.add(fitted)
-                fn = jax.jit(
-                    lambda q, k_buf, v_buf, index, b=fitted: jnp.where(
-                        (index >= 0)[:, None, None, None],
-                        flash_decode(
-                            q, k_buf, v_buf, jnp.maximum(index, 0),
-                            block=b, interpret=interpret,
-                        ),
-                        0.0,
-                    )
-                )
-                rejected = _rejection(
-                    fn, (q, k_buf, v_buf, index), oracle, dtype
-                )
-                if rejected:
-                    results.append(
-                        {"schedule": "kernel", "block": fitted, **rejected}
-                    )
-                    continue
-                secs = measure(fn, q, k_buf, v_buf, index, repeats=repeats)
-                entry = {"schedule": "kernel", "block": fitted,
-                         "seconds": secs}
-                results.append(entry)
-                if secs < best["seconds"]:
-                    best = entry
-            params = {"schedule": best["schedule"], "block": best["block"]}
-            key = decode_bucket_key(bb, cb, shape, dtype)
-            if db is not None:
-                db.record_key(
-                    key, params,
-                    best_seconds=best["seconds"], candidates=results,
-                    kernel="decode_bucket",
-                    shape=[int(s) for s in shape],
-                    batch_bucket=bb, context_bucket=cb,
-                    dtype=jnp.dtype(dtype).name,
-                    backend=jax.default_backend(),
-                )
-            tuned[key] = params
-    return tuned
 
 
 # -- speculative depth search -------------------------------------------------

@@ -294,8 +294,6 @@ def batched_decode_attention(
     index: jax.Array,
     *,
     window: int | None = None,
-    use_kernel: bool | None = None,
-    block: int = 1024,
 ) -> jax.Array:
     """One decode step where every row sits at its OWN fill level.
 
@@ -305,26 +303,17 @@ def batched_decode_attention(
     different sequence, so ``index`` here is ``[B]`` int32 (row ``b`` attends
     cache positions ``0..index[b]``; negative = inactive row, output zeros).
     Shapes otherwise match: ``q`` ``[B, 1, H, D]``, grouped buffers
-    ``[B, L, Hkv, D]``, grouped heads consumed natively.
+    ``[B, L, Hkv, D]``, grouped heads consumed natively (no ``repeat_kv``).
 
-    Two schedules, chosen STATICALLY like decode_attention's:
-
-    - default: ONE masked grouped einsum over the whole buffer — the
-      dense-roofline schedule (PERF_ANALYSIS §9) with the scalar prefix
-      mask swapped for a per-row one. The serving engine's buffers are the
-      gathered pages of ``serving.kv_pool`` (``max_blocks_per_seq * block``
-      rows), sized by the engine's admission limit, so the read-everything
-      trade is the measured-fastest one at those lengths.
-    - ``use_kernel=True``: the fused Pallas kernel
-      (:func:`~deeplearning_mpi_tpu.ops.pallas.flash_decode.flash_decode`),
-      which takes the ``[B]`` index vector natively — per-row clamped DMAs
-      keep HBM traffic O(own index) per row on long buffers. Falls back to
-      the einsum when the buffer does not tile.
-    - ``use_kernel=None``: consult the autotuner's tuning DB for this
-      buffer's (shape, dtype, backend) — a recorded winner picks the
-      schedule (and the kernel's block); untuned shapes keep the einsum.
-      This is how ``serving/engine.py`` defers its dispatch decision to
-      measurements (``EngineConfig(use_kernel=None)``).
+    ONE masked grouped einsum over the whole buffer, the serving engine's
+    only decode core: its buffers are the gathered pages of
+    ``serving.kv_pool`` (the step's live rows x live width,
+    ``ServingEngine._decode_shape``), so there is no unfilled tail worth a
+    walk. On the v5e the decode program it sits in reads 72.2% of its bytes
+    bound at 8 rows x 128 blocks (``serve_decode_roofline``; ledger, PR 31).
+    What would beat it is attention that reads the pool through the block
+    table without the gather (ROADMAP S1) — a new kernel, not a schedule of
+    this function.
 
     Not differentiable; decode is inference-only.
     """
@@ -341,25 +330,6 @@ def batched_decode_attention(
         raise ValueError(
             f"index must be [{batch}] (one fill level per row), got {index.shape}"
         )
-    if use_kernel is None:
-        use_kernel, tuned_block = _tuned_decode_schedule(
-            k_buf.shape, k_buf.dtype
-        )
-        if tuned_block:
-            block = tuned_block
-    if use_kernel:
-        from deeplearning_mpi_tpu.ops.pallas.flash_decode import (
-            flash_decode,
-            kernel_decode_block,
-        )
-
-        fitted = kernel_decode_block(block, k_buf.shape, k_buf.dtype)
-        if fitted is not None:
-            out = flash_decode(
-                q, k_buf, v_buf, jnp.maximum(index, 0), block=fitted,
-                window=window,
-            )
-            return jnp.where(index[:, None, None, None] >= 0, out, 0.0)
     group = heads // kv_heads
     scale = head_dim**-0.5
     qg = q[:, 0].reshape(batch, kv_heads, group, head_dim)

@@ -45,6 +45,14 @@ partials first and reduces once. Same value to ~2 ulp, different
 association — bitwise tests use untied configs, tied is covered at
 ``allclose``.
 
+Second deviation: **the EMA** (``ema_decay > 0``). Params, moments and
+losses stay bit-equal, clipped or not, but ``d*ema + (1-d)*params`` of the
+ZeRO-sharded leaves does not: XLA:CPU contracts it to one fma inside the
+GSPMD step's fusion and rounds twice after this schedule's explicit
+``all_gather`` (replicated leaves agree; swapping the operands moves the
+difference to them). 7.5e-9 at the first step, 8.9e-8 after five — covered
+at ``allclose``; no reordering here can pin another fusion's contraction.
+
 Memory: Adam's ``mu``+``nu`` drop from 2x params replicated to 2x params/dp
 per device. Params themselves stay replicated (ZeRO-3 parameter sharding is
 a different trade and not implemented here).
@@ -367,9 +375,9 @@ def make_overlapped_train_step(
 
     Drop-in for ``train.trainer.make_train_step`` on pure-DP meshes with
     ZeRO-1 placement: same ``(state, batch) -> (state, metrics)`` signature,
-    same NaN-skip / EMA / metric semantics, bit-identical state evolution to
-    the GSPMD path on CPU (untied params; see module docstring for the tied-
-    embedding and clipped-gradient caveats). Raises
+    same NaN-skip / EMA / metric semantics, bit-identical params, optimizer
+    state and losses to the GSPMD path on CPU (untied params; see module
+    docstring for the tied-embedding and EMA caveats). Raises
     :class:`OverlapUnsupported` at build time for configurations the
     schedule cannot express — callers fall back to GSPMD.
 

@@ -32,10 +32,7 @@ CPU: the e2e test pins handoff streams bit-identical to the colocated
 engine's.
 
 Each role keeps its own compiled programs, its own warmup, and its own
-autotuning key space (``compiler.autotune``'s ``|role=...`` suffix): a
-prefill-heavy program mix and a decode-heavy one want different tuned
-winners, and a shared key would let one role's measurements overwrite the
-other's.
+``role=...`` gauges in the shared registry.
 
 Chaos: the ``handoff_stall`` fault kind (``--chaos handoff_stall@step:N``)
 wedges the handoff queue — completed prefills pile up while the decode

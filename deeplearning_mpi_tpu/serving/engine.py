@@ -29,10 +29,8 @@ Layer map (see ``docs/SERVING.md`` for the full walkthrough):
   its own dimensions. The decode step scatters each slot's new K/V through
   its block table (inactive slots write to the scratch block), gathers each
   slot's pages back into a ``[S, L, Hkv, D]`` view, and runs
-  :func:`~deeplearning_mpi_tpu.ops.attention.batched_decode_attention` —
-  kernel-dispatchable to ``ops.pallas.flash_decode``, with the
-  kernel-vs-einsum choice resolvable per (batch, context) bucket through
-  the autotuner DB (``compiler.autotune.tuned_decode_bucket``). Prefill is
+  :func:`~deeplearning_mpi_tpu.ops.attention.batched_decode_attention`,
+  the per-row masked einsum. Prefill is
   chunked: each PREFILL slot advances one ``prefill_chunk``-wide causal
   forward per engine step, so a long prompt cannot stall decode for every
   other slot. The verify step is a width-``spec_k + 1`` extension of the
@@ -62,7 +60,6 @@ must never change your completion.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from typing import Any, Callable, Iterable, Optional
 
@@ -145,14 +142,6 @@ class EngineConfig:
     prefill_chunk: int = 16
     #: bounded request queue (admission control)
     max_queue: int = 64
-    #: dispatch batched decode attention to the Pallas flash_decode kernel
-    #: (which consumes the per-row index vector natively); False = the
-    #: dense einsum schedule; None = consult the autotuner's tuning DB —
-    #: first the per-(batch, context)-bucket ``decode_bucket|...`` entries
-    #: for this step's live bucket, then the single gathered-buffer
-    #: ``flash_decode`` entry (``compiler/autotune.py``); untuned shapes
-    #: keep the einsum
-    use_kernel: bool | None = False
     #: draft proposals verified per sequence per engine step (0 = plain
     #: decode). With ``spec_k > 0`` the engine needs a draft model
     #: (``ServingEngine(draft_config=..., draft_params=...)``) and every
@@ -335,7 +324,7 @@ class PagedForward:
     # -- building blocks (mirror TransformerLM numerics) ---------------------
     # Each block opens its own scope (``embed``, ``attn/qkv``, ``attn/out``,
     # ``mlp``, ``logits``; ``attn/kv_scatter``/``attn/kv_gather`` above and
-    # ``attn/core`` in the programs), so the three programs' HLO ``op_name``s
+    # ``attn/core`` in ``_layers``), so the three programs' HLO ``op_name``s
     # say which part of a layer an operation belongs to.
     def _lin(self, x: jax.Array, kernel: jax.Array) -> jax.Array:
         # flax nn.Dense(use_bias=False, dtype=d): both operands cast to the
@@ -401,17 +390,43 @@ class PagedForward:
             ) * self._lin(h, lp["mlp"]["up_proj"]["kernel"])
             return x + self._lin(hidden, lp["mlp"]["down_proj"]["kernel"])
 
-    def decode_program(
-        self, *, use_kernel: bool | None, block: int | None = None
-    ) -> Callable[..., Any]:
-        """:meth:`decode_step` with its schedule bound, for ``jax.jit``. A
-        bare ``functools.partial`` has no ``__name__`` and the program would
-        show as ``jit__unknown`` in traces and HLO dumps."""
-        fn = functools.partial(
-            self.decode_step, use_kernel=use_kernel, block=block
-        )
-        fn.__name__ = "decode_step"
-        return fn
+    def _layers(
+        self,
+        params: Any,
+        kv: tuple[jax.Array, ...],
+        x: jax.Array,       # [rows, seq, d] embedded tokens
+        pos: jax.Array,     # [rows, seq] absolute positions (RoPE)
+        bid: jax.Array,     # block id each new K/V row is written to
+        off: jax.Array,     # offset in that block; same shape as ``bid``
+        tables: jax.Array,  # [rows, MB] block ids, or [MB] for one row
+        attend: Callable[[jax.Array, jax.Array, jax.Array], jax.Array],
+    ) -> tuple[tuple[jax.Array, ...], jax.Array]:
+        """THE layer loop of all three programs: per layer, project, scatter
+        the new K/V rows through ``(bid, off)``, gather the table's pages
+        back into position order (the block table IS the logical->physical
+        map, so indexing the pool with it yields a contiguous
+        ``[rows, L, Hkv, D]`` view of every sequence, this step's rows
+        included), ``attend(q, k_seq, v_seq) -> [rows, seq, H, D]`` under
+        the ``attn/core`` scope, output projection, MLP; then the final
+        norm. A program is its index math for ``(pos, bid, off)``, its
+        ``attend`` and its head; a new layer kind is written here once."""
+        cfg = self.config
+        head = (cfg.num_kv_heads or cfg.num_heads, cfg.head_dim)
+        new = bid.shape + head  # this step's K/V rows, one per (bid, off)
+        seq = (x.shape[0], tables.shape[-1] * self.engine.block_size) + head
+        for i in range(cfg.num_layers):
+            lp = params[f"layer_{i}"]
+            q, k, v = self._attn_proj(lp, x, pos)
+            kv = self._kv_scatter(
+                kv, i, bid, off, k.reshape(new), v.reshape(new)
+            )
+            k_seq, v_seq = self._kv_gather(kv, i, tables)
+            k_seq, v_seq = k_seq.reshape(seq), v_seq.reshape(seq)
+            with annotate("attn/core"):
+                ctx = attend(q, k_seq, v_seq)
+            x = self._attn_out(lp, x, ctx)
+            x = self._mlp(lp, x)
+        return kv, self._rmsnorm(x, params["final_norm"]["scale"])
 
     # -- jitted decode step --------------------------------------------------
     def decode_step(
@@ -422,9 +437,6 @@ class PagedForward:
         lengths: jax.Array,  # [S] int32 known tokens (prompt + generated)
         tokens: jax.Array,   # [S] int32 token fed this step (position len-1)
         active: jax.Array,   # [S] bool
-        *,
-        use_kernel: bool | None = False,
-        block: int | None = None,
     ) -> tuple[tuple[jax.Array, ...], jax.Array]:
         """One token for each of the table's ``S`` rows. Static per
         PROGRAM: ``S`` and ``MB``, read off ``tables`` (any row count up to
@@ -437,17 +449,14 @@ class PagedForward:
         # (never retraces), so "zero compiles on the first request" is an
         # assertable counter delta, not a timing heuristic.
         self._tick()
-        cfg, BS = self.config, self.engine.block_size
+        BS = self.engine.block_size
         # Both static sizes come from the TABLE, not the engine's ceilings:
         # the host packs the rows that decode into this step's row bucket
         # and cuts the table to its width bucket
         # (ServingEngine._decode_shape), so the page gather and the
-        # attention over it cost O(rows x width) of what is live instead of
-        # max_slots x max_blocks_per_seq. One compile per distinct (S, MB);
-        # only block_size is the engine's.
+        # attention over it cost O(rows x width) of what is live. One
+        # compile per distinct (S, MB).
         S, MB = tables.shape
-        L = MB * BS
-        kv_heads = cfg.num_kv_heads or cfg.num_heads
         x = self._embed(params, tokens)[:, None, :]  # [S, 1, d]
         pos = jnp.maximum(lengths - 1, 0)[:, None]  # [S, 1] absolute
         p = pos[:, 0]
@@ -457,30 +466,15 @@ class PagedForward:
             tables[jnp.arange(S), jnp.minimum(p // BS, MB - 1)],
             SCRATCH_BLOCK,
         )
-        off = p % BS
         # Row b attends its own filled prefix 0..lengths[b]-1; negative
         # marks the row inactive (zero output).
         idx = jnp.where(active, lengths - 1, -1)
-        window = cfg.attention_window or None
-        for i in range(cfg.num_layers):
-            lp = params[f"layer_{i}"]
-            q, k, v = self._attn_proj(lp, x, pos)
-            kv = self._kv_scatter(kv, i, bid, off, k[:, 0], v[:, 0])
-            # Gather each slot's pages back into position order: the block
-            # table IS the logical->physical map, so indexing the pool with
-            # it yields a contiguous [S, L] view of every sequence.
-            k_seq, v_seq = self._kv_gather(kv, i, tables)
-            k_seq = k_seq.reshape(S, L, kv_heads, cfg.head_dim)
-            v_seq = v_seq.reshape(S, L, kv_heads, cfg.head_dim)
-            with annotate("attn/core"):
-                ctx = batched_decode_attention(
-                    q, k_seq, v_seq, idx, window=window,
-                    use_kernel=use_kernel,
-                    **({"block": block} if block else {}),
-                )
-            x = self._attn_out(lp, x, ctx)
-            x = self._mlp(lp, x)
-        x = self._rmsnorm(x, params["final_norm"]["scale"])
+        window = self.config.attention_window or None
+
+        def attend(q: jax.Array, k_seq: jax.Array, v_seq: jax.Array) -> jax.Array:
+            return batched_decode_attention(q, k_seq, v_seq, idx, window=window)
+
+        kv, x = self._layers(params, kv, x, pos, bid, p % BS, tables, attend)
         logits = self._logits(x[:, 0], params)  # [S, V] f32
         return kv, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
@@ -505,37 +499,27 @@ class PagedForward:
         # Trace-time compile tick — see decode_step.
         self._tick()
         cfg, e = self.config, self.engine
-        MB, BS, C = table.shape[0], e.block_size, e.prefill_chunk
-        L = MB * BS
-        kv_heads = cfg.num_kv_heads or cfg.num_heads
-        rep = cfg.num_heads // kv_heads
+        BS, C = e.block_size, e.prefill_chunk
+        rep = cfg.num_heads // (cfg.num_kv_heads or cfg.num_heads)
         x = self._embed(params, tokens)[None]  # [1, C, d]
         offs = jnp.arange(C, dtype=jnp.int32)
         pos = (start + offs)[None]  # [1, C] absolute
-        p = jnp.minimum(start + offs, L - 1)
+        p = jnp.minimum(start + offs, table.shape[0] * BS - 1)
         bid = jnp.where(offs < n_valid, table[p // BS], SCRATCH_BLOCK)
-        off = p % BS
         window = cfg.attention_window or None
-        for i in range(cfg.num_layers):
-            lp = params[f"layer_{i}"]
-            q, k, v = self._attn_proj(lp, x, pos)
-            kv = self._kv_scatter(kv, i, bid, off, k[0], v[0])
-            k_seq, v_seq = self._kv_gather(kv, i, table)
-            k_seq = k_seq.reshape(1, L, kv_heads, cfg.head_dim)
-            v_seq = v_seq.reshape(1, L, kv_heads, cfg.head_dim)
+
+        def attend(q: jax.Array, k_seq: jax.Array, v_seq: jax.Array) -> jax.Array:
             # The chunk's queries see every earlier chunk's pages PLUS this
-            # chunk's own rows (just scattered above); causal masking in
-            # absolute coordinates via q_offset. Stale rows from a previous
-            # owner of a recycled block sit at positions strictly after the
-            # last valid query and are causally masked.
-            with annotate("attn/core"):
-                ctx = dense_attention(
-                    q, repeat_kv(k_seq, rep), repeat_kv(v_seq, rep),
-                    causal=True, window=window, q_offset=start,
-                )
-            x = self._attn_out(lp, x, ctx)
-            x = self._mlp(lp, x)
-        x = self._rmsnorm(x, params["final_norm"]["scale"])
+            # chunk's own rows (just scattered); causal masking in absolute
+            # coordinates via q_offset. Stale rows from a previous owner of
+            # a recycled block sit at positions strictly after the last
+            # valid query and are causally masked.
+            return dense_attention(
+                q, repeat_kv(k_seq, rep), repeat_kv(v_seq, rep),
+                causal=True, window=window, q_offset=start,
+            )
+
+        kv, x = self._layers(params, kv, x, pos, bid, p % BS, table, attend)
         # Only the last VALID row's logits matter (and only on the final
         # chunk — the host ignores them otherwise). Padded rows compute
         # garbage that is never read and whose K/V went to scratch.
@@ -578,18 +562,14 @@ class PagedForward:
         the parity tests pin.
         """
         self._tick()
-        cfg, e = self.config, self.engine
-        S, BS = e.max_slots, e.block_size
+        cfg, BS = self.config, self.engine.block_size
         # Width-bucketed gather, same as decode_step: MB is the host-sliced
         # table width covering this verify batch's deepest row.
-        MB = tables.shape[1]
-        W = tokens.shape[1]
-        L = MB * BS
-        kv_heads = cfg.num_kv_heads or cfg.num_heads
-        rep = cfg.num_heads // kv_heads
+        L = tables.shape[1] * BS
+        rep = cfg.num_heads // (cfg.num_kv_heads or cfg.num_heads)
         scale = cfg.head_dim**-0.5
         x = self._embed(params, tokens)  # [S, W, d]
-        offs = jnp.arange(W, dtype=jnp.int32)[None]  # [1, W]
+        offs = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None]  # [1, W]
         pos = jnp.maximum(lengths - 1, 0)[:, None] + offs  # [S, W] absolute
         p = jnp.minimum(pos, L - 1)
         row_valid = active[:, None] & (offs < n_live[:, None])  # [S, W]
@@ -598,45 +578,36 @@ class PagedForward:
             jnp.take_along_axis(tables, p // BS, axis=1),
             SCRATCH_BLOCK,
         )
-        off = p % BS
         k_pos = jnp.arange(L, dtype=jnp.int32)
         # [S, 1, W, L] causal mask in absolute coordinates, per-row offsets.
         valid = (
             (k_pos[None, None, None, :] <= pos[:, None, :, None])
             & row_valid[:, None, :, None]
         )
-        window = cfg.attention_window or None
-        if window is not None:
-            valid &= pos[:, None, :, None] - k_pos[None, None, None, :] < window
-        for i in range(cfg.num_layers):
-            lp = params[f"layer_{i}"]
-            q, k, v = self._attn_proj(lp, x, pos)
-            kv = self._kv_scatter(kv, i, bid, off, k, v)
-            k_seq, v_seq = self._kv_gather(kv, i, tables)
-            with annotate("attn/core"):
-                k_seq = repeat_kv(
-                    k_seq.reshape(S, L, kv_heads, cfg.head_dim), rep
-                )
-                v_seq = repeat_kv(
-                    v_seq.reshape(S, L, kv_heads, cfg.head_dim), rep
-                )
-                scores = jnp.einsum(
-                    "bqhd,bkhd->bhqk", q, k_seq,
-                    preferred_element_type=jnp.float32,
-                ) * scale
-                scores = jnp.where(valid, scores, NEG_INF)
-                weights = jnp.where(
-                    jnp.any(valid, axis=-1)[..., None],
-                    jax.nn.softmax(scores, axis=-1),
-                    0.0,
-                )
-                ctx = jnp.einsum(
-                    "bhqk,bkhd->bqhd", weights.astype(v_seq.dtype), v_seq,
-                    preferred_element_type=jnp.float32,
-                ).astype(q.dtype)
-            x = self._attn_out(lp, x, ctx)
-            x = self._mlp(lp, x)
-        x = self._rmsnorm(x, params["final_norm"]["scale"])
+        if cfg.attention_window:
+            valid &= (
+                pos[:, None, :, None] - k_pos[None, None, None, :]
+                < cfg.attention_window
+            )
+
+        def attend(q: jax.Array, k_seq: jax.Array, v_seq: jax.Array) -> jax.Array:
+            k_seq, v_seq = repeat_kv(k_seq, rep), repeat_kv(v_seq, rep)
+            scores = jnp.einsum(
+                "bqhd,bkhd->bhqk", q, k_seq,
+                preferred_element_type=jnp.float32,
+            ) * scale
+            scores = jnp.where(valid, scores, NEG_INF)
+            weights = jnp.where(
+                jnp.any(valid, axis=-1)[..., None],
+                jax.nn.softmax(scores, axis=-1),
+                0.0,
+            )
+            return jnp.einsum(
+                "bhqk,bkhd->bqhd", weights.astype(v_seq.dtype), v_seq,
+                preferred_element_type=jnp.float32,
+            ).astype(q.dtype)
+
+        kv, x = self._layers(params, kv, x, pos, bid, p % BS, tables, attend)
         logits = self._logits(x, params)  # [S, W, V] f32
         return kv, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
@@ -661,9 +632,9 @@ class ServingEngine:
     sequences off by transferring block-table ownership, with the pages
     already in place. Omitted (the default), the engine owns both privately
     — the colocated topology, byte-identical to the pre-disaggregation
-    behavior. ``role`` labels this engine's autotuning key space
-    (``compiler.autotune`` ``|role=...`` suffix) so each role keeps its own
-    tuned winners.
+    behavior. ``role`` labels this engine's occupancy gauges
+    (``serve_queue_depth{role=...}``, :meth:`_role_name`) and its trace
+    events: the two engines of a disaggregated pair share one registry.
     """
 
     def __init__(
@@ -847,8 +818,7 @@ class ServingEngine:
         # pools and (when quantized) scale pools alike.
         self._kv_donate = (1,) if buffer_donation_supported() else ()
         self._decode_jit = jax.jit(
-            self._fwd.decode_program(use_kernel=engine.use_kernel),
-            donate_argnums=self._kv_donate,
+            self._fwd.decode_step, donate_argnums=self._kv_donate
         )
         self._prefill_jit = jax.jit(
             self._fwd.prefill_chunk, donate_argnums=self._kv_donate
@@ -867,10 +837,6 @@ class ServingEngine:
         # into serve_compile_seconds.
         self._decode_fn = self._timed_first_call(self._decode_jit)
         self._prefill_fn = self._timed_first_call(self._prefill_jit)
-        #: tuned per-bucket decode variants, keyed (use_kernel, block) —
-        #: bounded by the number of distinct tuned schedules, each a
-        #: one-time compile at the same static shapes as the default.
-        self._decode_variants: dict[tuple[bool, int | None], Callable[..., Any]] = {}
         # Armed by warmup(): once True, any serve_compile_total tick is a
         # zero-retrace contract violation the sanitizer (DMT_SANITIZE=1)
         # turns into a SanitizerError instead of a silent latency spike.
@@ -934,55 +900,6 @@ class ServingEngine:
 
         return call
 
-    def _is_base_schedule(
-        self, tuned: dict[str, Any], rows: int, width: int
-    ) -> bool:
-        """True when a tuned bucket entry names the very schedule the base
-        decode program (``use_kernel=None``) already resolved at trace time
-        for this table shape — swapping to a variant would lazily compile
-        a byte-identical duplicate, so the caller stays on the warmed base
-        program instead."""
-        from deeplearning_mpi_tpu.compiler import autotune
-
-        base = autotune.tuned_decode_schedule(
-            (
-                rows, width * self.engine.block_size,
-                self.config.num_kv_heads or self.config.num_heads,
-                self.config.head_dim,
-            ),
-            self.dtype,
-            role=self.role,
-        ) or {"schedule": "einsum", "block": None}
-        return (tuned["schedule"], tuned.get("block")) == (
-            base["schedule"], base.get("block")
-        )
-
-    def _decode_variant(
-        self, use_kernel: bool, block: int | None
-    ) -> Callable[..., Any]:
-        """The decode program for one tuned (schedule, block) bucket entry,
-        compiled on first use and cached — bucket dispatch swaps between a
-        handful of executables, never retraces an existing one."""
-        key = (bool(use_kernel), block)
-        fn = self._decode_variants.get(key)
-        if fn is None:
-            jitted = jax.jit(
-                self._fwd.decode_program(use_kernel=use_kernel, block=block),
-                donate_argnums=self._kv_donate,
-            )
-            base = self._timed_first_call(jitted)
-            if _sanitizer.enabled():
-                # Variant compiles are documented lazy overlays, outside
-                # the zero-compile contract — sanction their trace ticks
-                # so the retrace tripwire stays armed for everything else.
-                def fn(*args: Any, _base: Callable[..., Any] = base) -> Any:
-                    with _sanitizer.allow_compiles():
-                        return _base(*args)
-            else:
-                fn = base
-            self._decode_variants[key] = fn
-        return fn
-
     def warmup(self, *, cache: Any = None) -> dict[str, Any]:
         """AOT-compile the serving programs before traffic.
 
@@ -999,10 +916,7 @@ class ServingEngine:
         executable never retraces, so a warmed engine performs ZERO
         compiles on its first request — asserted by the
         ``serve_compile_total`` trace counter in ``tests/test_compiler.py``
-        and the ``tools/autotune.py --selftest`` acceptance check. (Tuned
-        per-bucket decode variants compile lazily on their first dispatch —
-        they are DB-dependent overlays, not part of the zero-compile
-        contract.)
+        and the ``tools/autotune.py --selftest`` acceptance check.
 
         ``cache`` is an optional
         :class:`~deeplearning_mpi_tpu.compiler.cache.CompileCache`; under a
@@ -1332,7 +1246,6 @@ class ServingEngine:
     def _plain_decode(
         self, decoding: list[Request], finished: list[Request]
     ) -> None:
-        e = self.engine
         # Only the rows that decode go to the device, packed in the order
         # of ``decoding`` into the warmed table that holds them in the
         # least rows x width; row i of every array — and of the tokens that
@@ -1353,36 +1266,12 @@ class ServingEngine:
                 lengths[i] = req.length
                 tokens[i] = req.generated[-1]
                 active[i] = True
-            fn = self._decode_fn
-            if e.use_kernel is None:
-                # Per-(batch, context)-bucket schedule: a tuned decode_bucket|...
-                # entry for THIS step's live bucket overrides the single
-                # gathered-shape flash_decode entry the default program consults
-                # at trace time. Miss = default program (never a recompile).
-                from deeplearning_mpi_tpu.compiler import autotune
-
-                tuned = autotune.tuned_decode_bucket(
-                    len(decoding), int(lengths.max()),
-                    (
-                        e.max_slots, e.max_seq_len,
-                        self.config.num_kv_heads or self.config.num_heads,
-                        self.config.head_dim,
-                    ),
-                    self.dtype,
-                    role=self.role,
-                )
-                if tuned is not None and not self._is_base_schedule(
-                    tuned, rows, width
-                ):
-                    fn = self._decode_variant(
-                        tuned["schedule"] == "kernel", tuned.get("block")
-                    )
-            self._kv, next_tok = fn(
+            self._kv, next_tok = self._decode_fn(
                 self.params, self._kv,
                 jnp.asarray(tables), jnp.asarray(lengths),
                 jnp.asarray(tokens), jnp.asarray(active),
             )
-            BS = e.block_size
+            BS = self.engine.block_size
             self._record_writes(
                 {req.blocks[(req.length - 1) // BS] for req in decoding}
             )

@@ -113,13 +113,8 @@ class SpeculativeDecoder:
                 kv_dtype if kv_dtype is not None else dtype,
             ))
         self._kvh = kv_buffers
-        # The draft always decodes through the einsum schedule: its
-        # gathered KV shape differs from the target's, so target bucket
-        # tuning does not transfer, and draft steps are small enough that
-        # kernel dispatch has nothing to win on CPU-class drafts.
         self._decode_jit = jax.jit(
-            self._fwd.decode_program(use_kernel=False),
-            donate_argnums=donate,
+            self._fwd.decode_step, donate_argnums=donate
         )
         self._prefill_jit = jax.jit(
             self._fwd.prefill_chunk, donate_argnums=donate

@@ -193,8 +193,6 @@ class TestSanitizer:
 
     def test_compile_tick_trips_only_post_warmup(self, sanitize_on):
         sanitizer.check_compile_tick(post_warmup=False)  # warmup: fine
-        with sanitizer.allow_compiles():
-            sanitizer.check_compile_tick(post_warmup=True)  # sanctioned
         with pytest.raises(sanitizer.SanitizerError, match="AFTER warmup"):
             sanitizer.check_compile_tick(post_warmup=True)
         assert sanitizer.trip_counts()[sanitizer.RETRACE_TRIPS] == 1

@@ -176,29 +176,9 @@ class TestAutotune:
             autotune.set_default_db(None)
 
 
-# -- decode bucket tuner (`decode_bucket|...`) + spec-k (`spec_k|...`) --------
+# -- spec-k (`spec_k|...` key space) ------------------------------------------
 
-class TestDecodeBucketTuning:
-    SHAPE = (2, 64, 2, 16)
-
-    def test_pow2_bucket(self):
-        assert autotune.pow2_bucket(1) == 1
-        assert autotune.pow2_bucket(3) == 4
-        assert autotune.pow2_bucket(8) == 8
-        assert autotune.pow2_bucket(9) == 16
-        # Clamped to the buffer's real extent: a bucket can never name a
-        # condition the gathered pool cannot hold.
-        assert autotune.pow2_bucket(40, cap=32) == 32
-        assert autotune.pow2_bucket(0) == 1
-
-    def test_decode_bucket_key_canonical(self):
-        key = autotune.decode_bucket_key(2, 64, self.SHAPE, F32, backend="cpu")
-        assert key == "decode_bucket|b2xc64|2x64x2x16|float32|cpu"
-        # dtype objects and names collapse to one spelling.
-        assert key == autotune.decode_bucket_key(
-            2, 64, self.SHAPE, "float32", backend="cpu"
-        )
-
+class TestSpecKTuning:
     def test_expected_tokens_per_step(self):
         # a=0: only the bonus token ever lands. a=1: all k + bonus.
         assert autotune.expected_tokens_per_step(0.0, 4) == 1.0
@@ -208,52 +188,16 @@ class TestDecodeBucketTuning:
         # Out-of-range rates clamp instead of exploding.
         assert autotune.expected_tokens_per_step(2.0, 3) == 4.0
 
-    def test_tune_buckets_round_trip_and_live_consult(self, tmp_path):
-        db = autotune.TuningDB(tmp_path / "b.json")
-        tuned = autotune.tune_decode_buckets(
-            self.SHAPE, F32, db=db,
-            batch_buckets=(1, 2), context_buckets=(32, 64),
-            blocks=(16,), repeats=1,
-        )
-        assert len(tuned) == 4
-        for key, params in tuned.items():
-            assert key.startswith("decode_bucket|")
-            assert params["schedule"] in ("kernel", "einsum")
-        db.save()
-        autotune.set_default_db(autotune.TuningDB.load(db.path))
-        try:
-            # Live values bucket up: batch 2 -> b2, context 40 -> c64.
-            got = autotune.tuned_decode_bucket(2, 40, self.SHAPE, F32)
-            want = tuned[autotune.decode_bucket_key(2, 64, self.SHAPE, F32)]
-            assert got == want
-            # An untuned dtype misses cleanly.
-            assert (
-                autotune.tuned_decode_bucket(2, 40, self.SHAPE, jnp.bfloat16)
-                is None
-            )
-        finally:
-            autotune.set_default_db(None)
-        # No DB installed: consult degrades to None, never raises.
-        assert autotune.tuned_decode_bucket(2, 40, self.SHAPE, F32) is None
+    def test_spec_k_consult_never_raises(self):
+        from deeplearning_mpi_tpu.models import TransformerConfig
 
-    def test_bucket_consult_never_raises(self):
         class Broken:
             def lookup_key(self, *a, **k):
                 raise RuntimeError("boom")
 
         autotune._default_db = Broken()
         try:
-            assert (
-                autotune.tuned_decode_bucket(2, 40, self.SHAPE, F32) is None
-            )
-            assert (
-                autotune.tuned_spec_k(
-                    __import__(
-                        "deeplearning_mpi_tpu.models", fromlist=["models"]
-                    ).TransformerConfig.tiny(),
-                    1, F32,
-                ) is None
-            )
+            assert autotune.tuned_spec_k(TransformerConfig.tiny(), 1, F32) is None
         finally:
             autotune.set_default_db(None)
 
@@ -571,9 +515,18 @@ class TestWarmedEngine:
             dtype=F32, registry=registry,
         )
 
-    def test_zero_compiles_on_first_request(self):
+    @staticmethod
+    def _first_request(engine):
+        """One 8-token prompt served to its 4 tokens; returns them."""
         from deeplearning_mpi_tpu.serving import RequestState
 
+        req = engine.submit(np.arange(1, 9, dtype=np.int32), 4)
+        while not engine.scheduler.idle():
+            engine.step()
+        assert req.state is RequestState.FINISHED
+        return list(req.generated)
+
+    def test_zero_compiles_on_first_request(self):
         registry = MetricsRegistry()
         engine = self._engine(registry)
         engine.warmup()
@@ -584,10 +537,7 @@ class TestWarmedEngine:
         # width 1, prefill at (1, 2, 4).
         compiles = registry.counter("serve_compile_total").value
         assert compiles == len(engine._decode_shapes) + len(engine._widths)
-        req = engine.submit(np.arange(1, 9, dtype=np.int32), 4)
-        while not engine.scheduler.idle():
-            engine.step()
-        assert req.state is RequestState.FINISHED
+        self._first_request(engine)
         # The actual contract: the first request compiled NOTHING.
         assert registry.counter("serve_compile_total").value == compiles
         # Every table shape has its own AOT executable, picked by the
@@ -596,69 +546,48 @@ class TestWarmedEngine:
         assert engine._prefill_fn.fallback_calls == 0
         assert engine._decode_fn.fallback_calls == 0
 
-    def test_tuned_einsum_buckets_stay_on_base_program(self):
-        """A decode_bucket| entry whose winner IS the base program's
-        schedule (einsum, no block) must not spawn a duplicate lazy-compiled
-        variant — the warmed engine stays at zero compiles even with
-        per-bucket consults live (use_kernel=None)."""
-        from deeplearning_mpi_tpu.compiler import autotune
-        from deeplearning_mpi_tpu.models import (
-            TransformerConfig,
-            TransformerLM,
-        )
-        from deeplearning_mpi_tpu.serving import (
-            EngineConfig,
-            RequestState,
-            ServingEngine,
-        )
+    def test_a_tuning_db_does_not_reach_the_decode_program(self):
+        """With a default DB holding ``flash_decode`` and ``flash_attention``
+        winners for the very shapes the engine gathers and prefills, a warmed
+        engine compiles nothing and emits the DB-less engine's tokens: no
+        serving program consults the DB."""
 
-        cfg = TransformerConfig.tiny()
-        params = TransformerLM(config=cfg, dtype=F32).init(
-            jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
-        )["params"]
-        ecfg = EngineConfig(max_slots=2, block_size=8, num_blocks=16,
-                            max_blocks_per_seq=4, prefill_chunk=8,
-                            max_queue=8, use_kernel=None)
-        shape = (2, 32, cfg.num_kv_heads or cfg.num_heads, cfg.head_dim)
-        db = autotune.TuningDB()
-        for bb in (1, 2):
-            for cb in (8, 16, 32):
-                db.record_key(
-                    autotune.decode_bucket_key(bb, cb, shape, F32),
-                    {"schedule": "einsum", "block": None},
-                )
-        autotune.set_default_db(db)
-        try:
-            registry = MetricsRegistry()
-            engine = ServingEngine(
-                cfg, params, ecfg, dtype=F32, registry=registry,
-            )
+        def run(registry):
+            engine = self._engine(registry)
             engine.warmup()
             compiles = registry.counter("serve_compile_total").value
-            req = engine.submit(np.arange(1, 9, dtype=np.int32), 4)
-            while not engine.scheduler.idle():
-                engine.step()
-            assert req.state is RequestState.FINISHED
-            assert db.consulted, "bucket entries were never consulted"
-            assert engine._decode_variants == {}
+            tokens = self._first_request(engine)
             assert registry.counter("serve_compile_total").value == compiles
+            return engine, tokens
+
+        engine, want = run(MetricsRegistry())
+        cfg, e = engine.config, engine.engine
+        kv_heads = cfg.num_kv_heads or cfg.num_heads
+        db = autotune.TuningDB()
+        for rows, width in engine._decode_shapes:
+            db.record(
+                "flash_decode", (rows, width * e.block_size, kv_heads, cfg.head_dim),
+                F32, {"schedule": "kernel", "block": e.block_size},
+            )
+        for width in engine._widths:
+            db.record(
+                "flash_attention", (1, width * e.block_size, cfg.num_heads, cfg.head_dim),
+                F32, {"block_q": 8, "block_k": 8},
+            )
+        autotune.set_default_db(db)
+        try:
+            _, got = run(MetricsRegistry())
         finally:
             autotune.set_default_db(None)
+        assert got == want
+        assert not db.consulted
 
     def test_warmed_matches_unwarmed_tokens(self):
-        from deeplearning_mpi_tpu.serving import RequestState
-
-        prompt = np.arange(1, 9, dtype=np.int32)
-
         def run(warm):
             engine = self._engine(MetricsRegistry())
             if warm:
                 engine.warmup()
-            req = engine.submit(prompt, 4)
-            while not engine.scheduler.idle():
-                engine.step()
-            assert req.state is RequestState.FINISHED
-            return req.generated
+            return self._first_request(engine)
 
         assert run(warm=True) == run(warm=False)
 

@@ -3,10 +3,11 @@
 The acceptance bar for ``parallel.zero.make_overlapped_train_step``: over
 >= 5 optimizer steps on a dp=2 CPU mesh, the overlapped schedule must
 produce *bit-identical* optimizer state (and params, and per-step losses)
-to the GSPMD ZeRO-1 step. Bitwise claims use untied embeddings and the
-one-hot embedding gradient (``TransformerConfig.onehot_embed``) — the two
-documented association caveats (see parallel/zero.py's module docstring);
-tied embeddings are covered at allclose.
+to the GSPMD ZeRO-1 step, with and without the global-norm clip. Bitwise
+claims use untied embeddings and the one-hot embedding gradient
+(``TransformerConfig.onehot_embed``) — the two documented association
+caveats (see parallel/zero.py's module docstring); tied embeddings and the
+EMA of the ZeRO-sharded leaves are covered at allclose.
 """
 
 import jax
@@ -111,15 +112,29 @@ class TestBitEquality:
         _assert_tree_bit_equal(state_g.opt_state, state_o.opt_state, "opt_state")
         _assert_tree_bit_equal(state_g.params, state_o.params, "params")
         if ema:
-            _assert_tree_bit_equal(state_g.ema_params, state_o.ema_params, "ema")
+            # d*e + (1-d)*p over bit-equal params: XLA:CPU contracts it to
+            # one fma in the GSPMD step's fusion and not after the explicit
+            # all_gather, for the ZeRO-sharded leaves only — half an ulp of
+            # the product at step 0 (7.5e-9), 8.9e-8 after five (module
+            # docstring of parallel/zero.py).
+            for a, b in zip(
+                jax.tree.leaves(state_g.ema_params),
+                jax.tree.leaves(state_o.ema_params),
+            ):
+                np.testing.assert_allclose(
+                    np.asarray(a), np.asarray(b), rtol=1e-6, atol=2e-7
+                )
         assert int(state_o.step) == n_steps
 
     def test_bitwise_vs_gspmd_5_steps(self):
         self._compare()
 
-    def test_bitwise_with_clip_and_ema(self):
+    def test_bitwise_with_clip(self):
         # The pre-clip mirrors optax.clip_by_global_norm's exact form, so
         # even the clipped path lands bit-equal on this mesh.
+        self._compare(clip=1.0)
+
+    def test_clip_and_ema_bitwise_state_and_ema_allclose(self):
         self._compare(clip=1.0, ema=True)
 
     def test_tied_embeddings_allclose(self):

@@ -834,6 +834,12 @@ class TestEngineValidation:
                 EngineConfig(num_blocks=4, max_blocks_per_seq=8),
             )
 
+    def test_engine_config_rejects_use_kernel(self):
+        """The decode step has one schedule: the field is gone, not
+        deprecated, so a stale config fails loudly."""
+        with pytest.raises(TypeError, match="use_kernel"):
+            EngineConfig(use_kernel=False)
+
     def test_rejects_nonpositive_max_new(self, tiny_lm):
         cfg, _, params = tiny_lm
         engine = ServingEngine(cfg, params, ENGINE_CFG, dtype=jnp.float32)
@@ -1664,12 +1670,29 @@ class TestStepSpans:
         assert f"module @jit_{program} " in text and "_unknown" not in text
         for scope in _SCOPES:
             assert f"jit({program})/{scope}/" in text, scope
-        if program == "decode_step":  # the draft's and a tuned variant's, too
+        if program == "decode_step":  # the draft's, too
             draft = engine._spec._decode_jit.lower(
                 engine._spec.params, engine._spec._kv, *args
             ).as_text()
             assert "module @jit_decode_step " in draft
-            assert engine._fwd.decode_program(use_kernel=True, block=64).__name__ == "decode_step"
+
+    @pytest.mark.parametrize("program", _PROGRAMS)
+    def test_every_program_runs_the_one_layer_body(self, tiny_lm, program):
+        """Each program enters each of the six layer scopes exactly
+        ``num_layers`` times, in the body's order: walking the traced
+        program's equations in order, the scope changes
+        qkv -> kv_scatter -> kv_gather -> core -> out -> mlp once a layer."""
+        engine = _spec_engine(tiny_lm)
+        jitted, args = self._program(engine, program)
+        layer_scopes = [s for s in _SCOPES if s not in ("embed", "logits")]
+        entered = []
+        (call,) = jax.make_jaxpr(jitted)(engine.params, engine._kv, *args).jaxpr.eqns
+        for eqn in call.params["jaxpr"].jaxpr.eqns:
+            stack = str(eqn.source_info.name_stack)
+            scope = next((s for s in layer_scopes if stack.endswith(s)), None)
+            if scope is not None and (not entered or entered[-1] != scope):
+                entered.append(scope)
+        assert entered == layer_scopes * engine.config.num_layers
 
     @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["float", "int8"])
     @pytest.mark.parametrize("program", [*_PROGRAMS, *_PACKED])
@@ -1737,6 +1760,21 @@ def _prefilled(engine, prompts, tables):
                 engine.params, kv, jnp.asarray(table), jnp.asarray(chunk), jnp.int32(start), jnp.int32(n),
             )
     return kv
+
+
+def _slot_batch(e, slots, prompts, held):
+    """Host arrays of a decode step at the engine's ceilings: ``prompts[i]``
+    plus one fed token in slot ``slots[i]``, holding blocks ``held[i]``."""
+    tables = np.zeros((e.max_slots, e.max_blocks_per_seq), np.int32)
+    lengths = np.zeros((e.max_slots,), np.int32)
+    tokens = np.zeros((e.max_slots,), np.int32)
+    active = np.zeros((e.max_slots,), bool)
+    for slot, prompt, blocks in zip(slots, prompts, held):
+        tables[slot, :len(blocks)] = blocks
+        lengths[slot] = len(prompt) + 1
+        tokens[slot] = 17 + slot
+        active[slot] = True
+    return tables, lengths, tokens, active
 
 
 def _full_shape(engine):
@@ -1827,15 +1865,7 @@ class TestLiveShapes:
         slots = [3, 0][:min(rows, 2)]  # live rows in slots apart and out of order (the smallest bucket holds one)
         prompts = [rng.integers(1, 255, size=n).astype(np.int32) for n in (9, 6)][:len(slots)]
         held = [[7, 2, 11], [5, 9]][:len(slots)]  # blocks out of order, none the scratch block
-        tables = np.zeros((e.max_slots, e.max_blocks_per_seq), np.int32)
-        lengths = np.zeros((e.max_slots,), np.int32)
-        tokens = np.zeros((e.max_slots,), np.int32)
-        active = np.zeros((e.max_slots,), bool)
-        for slot, prompt, blocks in zip(slots, prompts, held):
-            tables[slot, :len(blocks)] = blocks
-            lengths[slot] = len(prompt) + 1
-            tokens[slot] = 17 + slot
-            active[slot] = True
+        tables, lengths, tokens, active = _slot_batch(e, slots, prompts, held)
         kv = _prefilled(engine, prompts, [tables[s] for s in slots])
         width = next(w for r, w in engine._decode_shapes if r == rows and w >= 3)
         packed = [np.zeros((rows, *a.shape[1:]), a.dtype) for a in (tables[:, :width], lengths, tokens, active)]
@@ -1942,6 +1972,31 @@ class TestLiveShapes:
         one = registry.snapshot()
         assert engine._gather_width(1) == 1 and engine._decode_shape(1, 2) == (1, 4)
         assert (one["serve_gather_blocks"], one["serve_live_blocks"]) == (1 + 4 + 4, 1 + 1 + 2)
+
+    @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["float", "int8"])
+    def test_verify_with_no_proposals_is_the_decode_step(self, tiny_lm, kv_dtype):
+        """``verify_step`` at ``W = 1``, ``n_live = 1`` feeds each row its
+        last known token alone: it returns ``decode_step``'s tokens and
+        leaves the pools as the decode step does (one body, two cores)."""
+        cfg, _, params = tiny_lm
+        engine = ServingEngine(cfg, params, dataclasses.replace(LIVE_CFG, kv_dtype=kv_dtype), dtype=jnp.float32)
+        e = engine.engine
+        rng = np.random.default_rng(3)
+        slots = [3, 0, 2]  # slot 1 stays inactive
+        prompts = [rng.integers(1, 255, size=n).astype(np.int32) for n in (9, 6, 4)]
+        held = [[7, 2, 11], [5, 9], [3, 4]]
+        tables, lengths, tokens, active = _slot_batch(e, slots, prompts, held)
+        kv = _prefilled(engine, prompts, [tables[s] for s in slots])
+        tables, lengths, tokens, active = map(jnp.asarray, (tables, lengths, tokens, active))
+        kv_dec, tok_dec = engine._decode_jit(engine.params, _own(kv), tables, lengths, tokens, active)
+        kv_ver, tok_ver = jax.jit(engine._fwd.verify_step)(
+            engine.params, _own(kv), tables, lengths, tokens[:, None], jnp.ones_like(lengths), active,
+        )
+        assert tok_ver.shape == (e.max_slots, 1)
+        assert [int(tok_ver[s, 0]) for s in slots] == [int(tok_dec[s]) for s in slots]
+        for a, b, before in zip(_pages(kv_ver), _pages(kv_dec), _pages(kv)):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+            assert (a != before).any()  # the step did write
 
     def test_the_verify_step_counts_its_gather_too(self, tiny_lm):
         """A speculating engine gathers ``max_slots`` rows at the widest

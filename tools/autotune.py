@@ -71,13 +71,6 @@ def build_parser() -> argparse.ArgumentParser:
     parser.add_argument("--decode_shape", action="append", default=[],
                         metavar="BxLxHkvxD",
                         help="decode KV-buffer shape to tune (repeatable)")
-    parser.add_argument("--decode_buckets", action="append", default=[],
-                        metavar="BxLxHkvxD",
-                        help="tune the decode schedule PER (batch, context) "
-                        "bucket over this gathered-pool shape — the serving "
-                        "engine consults the matching bucket entry every "
-                        "step when launched with use_kernel deferred to "
-                        "the DB (repeatable)")
     parser.add_argument("--spec_k", type=int, default=None,
                         metavar="DRAFT_LAYERS",
                         help="search the speculative proposal depth k "
@@ -271,35 +264,7 @@ def selftest() -> int:
             "corrupt DB consult degrades to None, never raises",
         )
 
-        # 6. Per-(batch, context)-bucket decode schedules: every bucket
-        # records its own winner and the live-value consult (the serving
-        # engine's per-step lookup) buckets its way to the right entry.
-        bucket_shape = (2, 64, 2, 16)
-        buckets = autotune.tune_decode_buckets(
-            bucket_shape, db=db, blocks=(16,), repeats=1,
-            batch_buckets=(1, 2), context_buckets=(32, 64),
-        )
-        check(len(buckets) == 4, f"decode buckets tuned: {len(buckets)}")
-        db.save()
-        autotune.set_default_db(autotune.TuningDB.load(db_path))
-        try:
-            live = autotune.tuned_decode_bucket(
-                2, 40, bucket_shape, jnp.float32
-            )  # batch 2 -> bucket 2, context 40 -> bucket 64
-            check(
-                live is not None and live.get("schedule") in
-                ("kernel", "einsum"),
-                f"live (2, 40) consult finds its bucket entry: {live}",
-            )
-        finally:
-            autotune.set_default_db(None)
-        check(
-            autotune.tuned_decode_bucket(2, 40, bucket_shape, jnp.float32)
-            is None,
-            "bucket consult without a DB degrades to None, never raises",
-        )
-
-        # 7. Speculative depth search: real engines race per candidate k
+        # 6. Speculative depth search: real engines race per candidate k
         # (greedy parity makes it a pure throughput race), the winner and
         # its measured acceptance rate persist and round-trip.
         spec = autotune.tune_spec_k(
@@ -337,10 +302,10 @@ def main(argv: list[str] | None = None) -> int:
         bootstrap.set_virtual_cpu_devices(args.virtual_devices)
     if args.selftest:
         return selftest()
-    if not (args.attn_shape or args.decode_shape or args.decode_buckets
-            or args.step or args.spec_k is not None):
+    if not (args.attn_shape or args.decode_shape or args.step
+            or args.spec_k is not None):
         print("nothing to tune: pass --attn_shape, --decode_shape, "
-              "--decode_buckets, --spec_k, and/or --step (or --selftest)",
+              "--spec_k, and/or --step (or --selftest)",
               file=sys.stderr)
         return 1
 
@@ -370,16 +335,6 @@ def main(argv: list[str] | None = None) -> int:
             repeats=args.repeats,
         )
         print(f"flash_decode {spec}: {params}", file=sys.stderr)
-    for spec in args.decode_buckets:
-        shape = _parse_shape(spec, "--decode_buckets")
-        entries = autotune.tune_decode_buckets(
-            shape, dtype, heads=args.heads, db=db, blocks=blocks,
-            repeats=args.repeats,
-        )
-        kernels = sum(1 for p in entries.values() if p["schedule"] == "kernel")
-        print(f"decode buckets {spec}: {len(entries)} bucket entries "
-              f"({kernels} kernel, {len(entries) - kernels} einsum)",
-              file=sys.stderr)
     if args.spec_k is not None:
         params = autotune.tune_spec_k(
             draft_layers=args.spec_k, dtype=dtype, db=db,
