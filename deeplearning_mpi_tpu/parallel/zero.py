@@ -80,6 +80,7 @@ from deeplearning_mpi_tpu.ops.loss import (
     _token_nll,
     bce_per_image,
     dice_per_image,
+    lm_token_nll,
 )
 from deeplearning_mpi_tpu.runtime.compat import (
     buffer_donation_supported,
@@ -234,11 +235,7 @@ def _mirrored_loss_terms(task: str, seg_loss: str) -> _LossTerms:
     if task == "lm":
 
         def lm_terms(outputs, chunk):
-            nll = _token_nll(outputs[:, :-1], chunk["tokens"][:, 1:])
-            mask = chunk.get("mask")
-            if mask is None:
-                return [(jnp.sum(nll), None, nll.size)]
-            w = mask[:, 1:].astype(jnp.float32)
+            nll, w = lm_token_nll(outputs, chunk["tokens"], chunk.get("mask"))
             return [(jnp.sum(nll * w), jnp.sum(w), nll.size)]
 
         return lm_terms

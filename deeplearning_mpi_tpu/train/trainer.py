@@ -215,7 +215,7 @@ def make_train_step(
         # The chunk loss's own denominator, so the cross-chunk weighted mean
         # reproduces the full-batch mean. Only the LM task can be ragged (a
         # [B, S] token mask); a masked-out chunk gets weight 0 — its 0.0
-        # masked_mean is then excluded, matching the full-batch sum.
+        # loss is then excluded, matching the full-batch sum.
         if task == "lm":
             mask = chunk.get("mask")
             if mask is not None:
@@ -297,7 +297,7 @@ def make_train_step(
             # final — no post-scan division that would also (wrongly) divide
             # the equally-weighted aux-loss gradient. maximum(1): an
             # every-token-masked batch yields 0 grads / 0 loss, like
-            # masked_mean's own guarded denominator.
+            # lm_cross_entropy's own guarded denominator.
             if task == "lm" and batch.get("mask") is not None:
                 # chunk_weight on the full batch = the sum over its chunks,
                 # keeping the mask[:, 1:] denominator convention in one place.

@@ -1,0 +1,250 @@
+"""The token cross-entropy against its plain spelling, and what it may not build.
+
+``ops/loss.py`` computes ``logsumexp(x) - x[label]`` with the label picked by
+an iota comparison, over all S rows of the LM's logits with the labels
+shifted. The spelling it replaced, ``-take_along_axis(log_softmax(f32(logits
+[:, :-1])), labels)``, stays here as the reference: same values and
+gradients, but it wrote a float32 log-prob tensor forward, scattered into a
+zero-filled logits-sized buffer backward and copied an S-1-row slice.
+"""
+
+import contextlib
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeplearning_mpi_tpu.ops import (
+    chunked_lm_loss,
+    lm_cross_entropy,
+    masked_mean,
+    softmax_cross_entropy,
+)
+
+# Neither the sequence nor the vocabulary tiles by 8 x 128.
+B, S, D, V = 2, 13, 8, 37
+DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
+
+
+def ref_nll(logits, labels):
+    log_probs = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    return -jnp.take_along_axis(log_probs, labels[..., None], axis=-1)[..., 0]
+
+
+def ref_lm(logits, tokens, mask=None):
+    nll = ref_nll(logits[:, :-1], tokens[:, 1:])
+    return masked_mean(nll, None if mask is None else mask[:, 1:])
+
+
+def ref_sce(logits, labels, where=None):
+    return masked_mean(ref_nll(logits, labels), where)
+
+
+def _data(dtype, seed=0):
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(B, S, D)), dtype)
+    w = jnp.asarray(rng.normal(size=(D, V)) * 0.5, dtype)
+    tokens = jnp.asarray(rng.integers(0, V, (B, S)), jnp.int32)
+    mask = jnp.asarray(rng.integers(0, 2, (B, S)), jnp.float32)
+    return x, w, tokens, mask
+
+
+def _lm_case(masked):
+    def build(dtype):
+        x, w, tokens, mask = _data(dtype)
+        m = mask if masked else None
+        return (
+            lambda logits: lm_cross_entropy(logits, tokens, m),
+            lambda logits: ref_lm(logits, tokens, m),
+            (x @ w,),
+        )
+    return build
+
+
+def _sce_case(masked):
+    def build(dtype):
+        x, w, tokens, mask = _data(dtype, seed=1)
+        labels, where = tokens[:, 0], (mask[:, 0].at[0].set(1.0) if masked else None)
+        return (
+            lambda logits: softmax_cross_entropy(logits, labels, where),
+            lambda logits: ref_sce(logits, labels, where),
+            ((x @ w)[:, 0],),
+        )
+    return build
+
+
+def _chunked_case(masked, chunk):
+    def build(dtype):
+        x, w, tokens, mask = _data(dtype, seed=2)
+        m = mask if masked else None
+        return (
+            lambda x, w: chunked_lm_loss(x, w, tokens, chunk_size=chunk, mask=m),
+            lambda x, w: ref_lm(x @ w, tokens, m),
+            (x, w),
+        )
+    return build
+
+
+CASES = {
+    "lm": _lm_case(False),
+    "lm_masked": _lm_case(True),
+    "classifier": _sce_case(False),
+    "classifier_where": _sce_case(True),
+    "chunked": _chunked_case(False, 5),
+    "chunked_masked": _chunked_case(True, 4),
+}
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("case", CASES)
+def test_value_and_gradient_match_the_plain_spelling(case, dtype):
+    ours, ref, args = CASES[case](DTYPES[dtype])
+    argnums = tuple(range(len(args)))
+    value, grads = jax.value_and_grad(ours, argnums)(*args)
+    ref_value, ref_grads = jax.value_and_grad(ref, argnums)(*args)
+    assert value.dtype == jnp.float32
+    np.testing.assert_allclose(float(value), float(ref_value), rtol=2e-6)
+    # Tolerances from the dtype: float32 rounding of a different order of the
+    # same sums; in bfloat16 the gradient is rounded once where it is
+    # produced (one ulp = 2**-8), and the chunked head adds its chunks'
+    # weight gradients in bfloat16 (a few ulp more).
+    eps = {"float32": 2e-6, "bfloat16": 2.0**-7 * (4 if "chunked" in case else 1)}[dtype]
+    for got, want, arg in zip(grads, ref_grads, args):
+        assert got.dtype == arg.dtype and got.shape == arg.shape
+        want = np.asarray(want, np.float32)
+        np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=eps, atol=eps * np.abs(want).max())
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("masked", [False, True], ids=["plain", "masked"])
+def test_last_position_gets_an_exactly_zero_gradient(masked, dtype):
+    ours, _, (logits,) = _lm_case(masked)(DTYPES[dtype])
+    grad = np.asarray(jax.grad(ours)(logits), np.float32)
+    assert not grad[:, -1].any()
+    assert grad[:, :-1].any()
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("poison", [np.nan, np.inf, -np.inf], ids=["nan", "inf", "neg_inf"])
+def test_non_finite_logit_at_the_last_position_changes_nothing(poison, dtype):
+    x, w, tokens, mask = _data(DTYPES[dtype], seed=3)
+    logits = x @ w
+    poisoned = logits.at[:, -1, 3].set(poison).at[0, -1].set(poison)
+    for m in (None, mask):
+        loss = lambda l: lm_cross_entropy(l, tokens, m)  # noqa: E731
+        value, grad = jax.value_and_grad(loss)(logits)
+        p_value, p_grad = jax.value_and_grad(loss)(poisoned)
+        assert np.isfinite(float(value)) and float(p_value) == float(value)
+        np.testing.assert_array_equal(np.asarray(p_grad, np.float32), np.asarray(grad, np.float32))
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("loss", ["dense", "chunked"])
+def test_all_zero_mask_gives_zero_loss_and_zero_gradients(loss, dtype):
+    x, w, tokens, mask = _data(DTYPES[dtype], seed=4)
+    zeros = jnp.zeros_like(mask)
+    fn = {
+        "dense": lambda x, w: lm_cross_entropy(x @ w, tokens, zeros),
+        "chunked": lambda x, w: chunked_lm_loss(x, w, tokens, chunk_size=5, mask=zeros),
+    }[loss]
+    value, grads = jax.value_and_grad(fn, (0, 1))(x, w)
+    assert float(value) == 0.0
+    for g in grads:
+        assert not np.asarray(g, np.float32).any()
+
+
+# ---- what the loss may not build -------------------------------------------
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def _primitives(fn, *args):
+    return {eqn.primitive.name for eqn in _equations(jax.make_jaxpr(fn)(*args).jaxpr)}
+
+
+def _logits_sized_f32_residuals(fn, logits):
+    """Float32 arrays over the vocabulary that the forward keeps for the backward."""
+    _, pullback = jax.vjp(fn, logits)
+    return [
+        leaf.shape
+        for leaf in jax.tree.leaves(pullback)
+        if leaf.dtype == jnp.float32 and leaf.shape[-1:] == logits.shape[-1:] and leaf.ndim == logits.ndim
+    ]
+
+
+def test_gradient_has_no_scatter_or_gather_and_saves_no_f32_logits():
+    x, w, tokens, mask = _data(jnp.bfloat16)
+    logits = x @ w
+    for m in (None, mask):
+        ours = lambda l: lm_cross_entropy(l, tokens, m)  # noqa: E731
+        ref = lambda l: ref_lm(l, tokens, m)  # noqa: E731
+        prims = _primitives(jax.grad(ours), logits)
+        assert not {p for p in prims if p.startswith(("scatter", "gather"))}, prims
+        assert _logits_sized_f32_residuals(ours, logits) == []
+        # The walker and the residual reader do see what the plain spelling builds.
+        assert "scatter-add" in _primitives(jax.grad(ref), logits)
+        assert _logits_sized_f32_residuals(ref, logits)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described (not attached) v5e chip to compile for; see the
+    ``on-chip-measurement`` guide for why this is a fixture."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from describing it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@contextlib.contextmanager
+def _no_compile_cache():
+    """A compile for a described device can be written to the persistent
+    cache but not read back; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def test_head_and_loss_compile_for_v5e_without_a_logits_sized_detour(one_chip):
+    """The head matmul, the loss and their gradients at ``lm-train-8k``'s
+    shape: the chip's compiler keeps the logits and their gradient once
+    each, in bfloat16, and moves 4.4 GB (3.35 GB are the three matmuls'; the
+    plain spelling moved 11.6 GB through an f32 log-prob tensor, a scatter
+    buffer and a copied 8,191-row slice, with 2.1 GB of temporaries)."""
+    seq, d_model, vocab = 8192, 4096, 32000
+
+    def step(x, w, tokens):
+        loss = lambda x, w: lm_cross_entropy(jnp.einsum("bsd,dv->bsv", x, w), tokens)  # noqa: E731
+        return jax.value_and_grad(loss, (0, 1))(x, w)
+
+    aval = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    with _no_compile_cache():
+        compiled = jax.jit(step).lower(
+            aval((1, seq, d_model), jnp.bfloat16), aval((d_model, vocab), jnp.bfloat16), aval((1, seq), jnp.int32)
+        ).compile()
+    # Instructions of the entry computation are what reaches memory; inside
+    # a fusion a float32 value of the logits' shape lives in registers.
+    text = compiled.as_text()
+    text = text[text.index("\nENTRY "):]
+    for shape in ("f32[8191,32000]", "f32[8192,32000]", "f32[1,8191,32000]", "f32[1,8192,32000]", "[262112000]", "[262144000]", "bf16[1,8191,32000]"):
+        assert shape not in text, shape
+    assert compiled.cost_analysis()["bytes accessed"] < 4.6e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1e9
