@@ -1,11 +1,12 @@
-"""Mixture-of-Experts MLP with capacity-based dense dispatch (GShard-style).
+"""Mixture-of-Experts MLPs: capacity-based dense dispatch (GShard-style) for
+training, and a dropless layer the serving engine can run.
 
 No reference analog (the reference's models are CNNs with no MoE —
 ``SURVEY.md`` §2c "Expert parallel: NO"), but expert parallelism is a
 first-class axis of this framework's mesh, and this layer is what exercises
 it.
 
-TPU-first design choices:
+:class:`MoEMLP` (``moe_routing`` ``token_choice`` / ``expert_choice``):
 - **Static shapes everywhere.** Routing uses the GShard/Switch dense-dispatch
   formulation: every expert processes a fixed-capacity ``[E, G, C, d]`` block
   and over-capacity tokens are dropped (their block output is zero, so they
@@ -17,10 +18,23 @@ TPU-first design choices:
   a ``data``-sharded operand with an ``expert``-sharded one and GSPMD inserts
   the all-to-alls — the hand-written ``a2a`` of GPU MoE stacks is a sharding
   annotation here.
-- f32 router. Routing decisions (softmax + top-k + cumsum positions) are
-  computed in float32; bf16 router logits flip top-k order at scale.
+- A token's output depends on which other tokens share its group (capacity
+  contention), so the serving engine refuses it.
 
-The layer slots into :class:`~deeplearning_mpi_tpu.models.transformer.Block`
+:func:`dropless_moe` / :class:`DroplessMoE` (``moe_routing='dropless'``): no
+capacity and nothing dropped. Every (token, expert) claim is served: the
+claims are sorted by expert and one grouped matrix product
+(``jax.lax.ragged_dot``) runs each expert over its own contiguous rows, so the
+work and the weight traffic are those of the experts the batch touched, and a
+token's output depends on the token alone — the serving engine's
+request-independence contract holds (``serving/engine.py``). The ``[B,S,E,C]``
+dispatch tensor of the capacity form does not exist here; at 128 experts it
+could not.
+
+Both: f32 router. Routing decisions (softmax + top-k) are computed in
+float32; bf16 router logits flip top-k order at scale.
+
+The layers slot into :class:`~deeplearning_mpi_tpu.models.transformer.Block`
 via its ``mlp_cls`` injection point (same positional ``(d_ff, dtype)``
 signature as ``SwiGLU``), so a dense LM becomes an MoE LM by configuration.
 """
@@ -63,6 +77,10 @@ def mlp_cls_from_config(config: Any) -> Any:
     """
     if not config.moe_experts:
         return None
+    if getattr(config, "moe_routing", "token_choice") == "dropless":
+        return functools.partial(
+            DroplessMoE, num_experts=config.moe_experts, top_k=config.moe_top_k
+        )
     return functools.partial(
         MoEMLP,
         num_experts=config.moe_experts,
@@ -256,3 +274,92 @@ class MoEMLP(nn.Module):
             return jnp.einsum(
                 "gsec,egcd->gsd", combine.astype(self.dtype), expert_out
             )
+
+
+def dropless_moe(
+    x: jax.Array,        # [N, d] tokens
+    router: jax.Array,   # [d, E]
+    w_gate: jax.Array,   # [E, d, f]
+    w_up: jax.Array,     # [E, d, f]
+    w_down: jax.Array,   # [E, f, d]
+    *,
+    top_k: int,
+    dtype: Any,
+    live: jax.Array | None = None,  # [N] bool: rows that are real tokens
+) -> tuple[jax.Array, jax.Array]:
+    """Dropless top-k mixture of SwiGLU experts over a flat batch of tokens.
+
+    ``p = softmax_f32(x @ router)``; the ``top_k`` largest renormalised to sum
+    1; ``y = sum_e g_e * down_e(silu(gate_e x) * up_e x)``. The ``N * top_k``
+    claims are sorted by expert (stable: by token within an expert) and each
+    of the three matrix products is ONE ``jax.lax.ragged_dot`` over the
+    groups, so an expert no token chose is neither computed nor read. Rows
+    with ``live`` false (padding) claim no expert and yield zeros.
+
+    Returns ``(y [N, d] in x's dtype, touched)``: ``touched`` is the int32
+    count of distinct experts the live rows routed to.
+    """
+    n_tok, n_exp = x.shape[0], router.shape[-1]
+    with annotate("moe/route"):
+        logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
+        gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        if live is not None:
+            # A padding row's claims go past the last group: no expert
+            # computes them, none is read or counted for them.
+            experts = jnp.where(live[:, None], experts, n_exp)
+        claim_expert = experts.reshape(-1)
+        order = jnp.argsort(claim_expert, stable=True)  # claims by expert
+        sizes = jnp.bincount(claim_expert, length=n_exp).astype(jnp.int32)
+        served = jnp.arange(n_tok * top_k) < jnp.sum(sizes)
+        xs = x.astype(dtype)[order // top_k]  # [N * k, d], grouped
+    with annotate("moe/experts"):
+        hidden = jax.nn.silu(
+            jax.lax.ragged_dot(xs, w_gate.astype(dtype), sizes)
+        ) * jax.lax.ragged_dot(xs, w_up.astype(dtype), sizes)
+        ys = jax.lax.ragged_dot(hidden, w_down.astype(dtype), sizes)
+        ys = jnp.where(served[:, None], ys, 0)
+    with annotate("moe/combine"):
+        # Un-sort, then a token's own k rows weighted in gate order: the sum
+        # never sees another token's rows.
+        back = ys[jnp.argsort(order)].reshape(n_tok, top_k, -1)
+        y = jnp.einsum(
+            "nkd,nk->nd", back.astype(jnp.float32), gates
+        ).astype(x.dtype)
+    return y, jnp.sum(sizes > 0).astype(jnp.int32)
+
+
+class DroplessMoE(nn.Module):
+    """:func:`dropless_moe` as a drop-in for :class:`SwiGLU`: same
+    ``(d_ff, dtype)`` leading attributes (``d_ff`` is ONE expert's width),
+    ``[B, S, d] -> [B, S, d]``, parameters named as :class:`MoEMLP`'s
+    (``router/kernel``, ``experts_gate/up/down`` with a leading expert dim,
+    the marker the expert-parallel sharding rule keys on). Sows nothing:
+    there is no capacity to overflow and no balance loss is defined."""
+
+    d_ff: int
+    dtype: Any = jnp.bfloat16
+    num_experts: int = 8
+    top_k: int = 2
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        batch, seq, d_model = x.shape
+        init = nn.initializers.lecun_normal()
+        # a one-leaf tree, so the kernel sits at ``router/kernel`` as MoEMLP's
+        # ``nn.Dense(name="router")`` puts it; dropless_moe applies it itself
+        router = self.param(
+            "router", lambda key, shape: {"kernel": init(key, shape, jnp.float32)},
+            (d_model, self.num_experts),
+        )["kernel"]
+        shape_in = (self.num_experts, d_model, self.d_ff)
+        w_gate = self.param("experts_gate", init, shape_in, jnp.float32)
+        w_up = self.param("experts_up", init, shape_in, jnp.float32)
+        w_down = self.param(
+            "experts_down", init, (self.num_experts, self.d_ff, d_model), jnp.float32
+        )
+        y, _ = dropless_moe(
+            x.reshape(batch * seq, d_model), router, w_gate, w_up, w_down,
+            top_k=self.top_k, dtype=self.dtype,
+        )
+        return y.reshape(batch, seq, d_model)

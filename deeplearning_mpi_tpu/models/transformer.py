@@ -23,6 +23,7 @@ TPU-first choices:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import flax.linen as nn
@@ -35,6 +36,7 @@ from deeplearning_mpi_tpu.ops.attention import (
     dense_attention,
     repeat_kv,
 )
+from deeplearning_mpi_tpu.ops.sparse_attention import sparse_attention
 
 # (q, k, v [B,S,H,D], causal=...) -> context [B,S,H,D]
 AttentionFn = Callable[..., jax.Array]
@@ -183,6 +185,34 @@ class _ProjFromBHSD(nn.Module):
         return jnp.einsum("bhsd,hdm->bsm", ctx.astype(self.dtype), k)
 
 
+class Indexer(nn.Module):
+    """The lightning indexer's projections (``ops/sparse_attention.py``):
+    from the normed hidden state, ``num_heads`` small query heads, ONE key
+    head (RMSNorm'd) and one scalar weight a query head; RoPE over all of
+    ``head_dim`` on queries and key. Returns ``(qI [B,S,Hi,Di], w [B,S,Hi],
+    kI [B,S,Di])``."""
+
+    num_heads: int
+    head_dim: int
+    rope_theta: float = 10_000.0
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(
+        self, x: jax.Array, positions: jax.Array
+    ) -> tuple[jax.Array, jax.Array, jax.Array]:
+        batch, seq, _ = x.shape
+        dense = _dense_factory(False, self.dtype)
+        q = dense(self.num_heads * self.head_dim, "q_proj")(x).reshape(
+            batch, seq, self.num_heads, self.head_dim
+        )
+        k = RMSNorm(name="k_norm")(dense(self.head_dim, "k_proj")(x))
+        w = dense(self.num_heads, "w_proj")(x)
+        q = apply_rope(q, positions, base=self.rope_theta)
+        k = apply_rope(k[:, :, None, :], positions, base=self.rope_theta)[:, :, 0]
+        return q, w, k
+
+
 class Attention(nn.Module):
     """Multi-head self-attention with RoPE and a pluggable attention core.
 
@@ -233,12 +263,36 @@ class Attention(nn.Module):
     #: schedule to the shards any query's window reaches (rotation
     #: skipping, ``parallel.ring_attention.windowed_rotations``).
     window: int = 0
+    #: rotary base (``TransformerConfig.rope_theta``)
+    rope_theta: float = 10_000.0
+    #: RMSNorm over each head's dims of q and k, before RoPE
+    qk_norm: bool = False
+    #: learned sparse attention: each query attends the ``topk`` keys its
+    #: :class:`Indexer` (``indexer_heads`` x ``indexer_head_dim``) scores
+    #: highest (0 = all). Full-sequence forward only: the KV-cached modes
+    #: keep no indexer-key cache and refuse it; long contexts go through
+    #: ``serving.ServingEngine``, whose paged pools hold one.
+    topk: int = 0
+    indexer_heads: int = 0
+    indexer_head_dim: int = 0
 
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array, *, causal: bool = True) -> jax.Array:
         features = self.num_heads * self.head_dim
         batch, seq, _ = x.shape
         kv_heads = self.num_kv_heads or self.num_heads
+        if self.topk and (self.decode or not causal or self.quantized or self.window):
+            raise NotImplementedError(
+                "attention_topk > 0 (learned sparse attention) runs in the "
+                "uncached causal full-sequence forward only: the flax KV "
+                "cache holds no indexer keys (serve it through "
+                "serving.ServingEngine), and it composes with neither a "
+                "sliding window nor quantized projections"
+            )
+        if (self.topk or self.qk_norm) and attention_fn_layout(self.attention_fn) == "bhsd":
+            raise NotImplementedError(
+                "qk_norm / attention_topk support the BSHD layout only"
+            )
         if self.num_heads % kv_heads:
             raise ValueError(
                 f"num_kv_heads ({kv_heads}) must divide num_heads ({self.num_heads})"
@@ -254,8 +308,11 @@ class Attention(nn.Module):
             proj = lambda heads, name: _ProjToBHSD(  # noqa: E731
                 heads, self.head_dim, self.dtype, name=name
             )
-            q = apply_rope(proj(self.num_heads, "q_proj")(x), positions, layout="bhsd")
-            k = apply_rope(proj(kv_heads, "k_proj")(x), positions, layout="bhsd")
+            rope = functools.partial(
+                apply_rope, positions=positions, base=self.rope_theta, layout="bhsd"
+            )
+            q = rope(proj(self.num_heads, "q_proj")(x))
+            k = rope(proj(kv_heads, "k_proj")(x))
             v = proj(kv_heads, "v_proj")(x)
             ctx = self.attention_fn(
                 q, repeat_kv(k, rep, axis=1), repeat_kv(v, rep, axis=1),
@@ -269,9 +326,18 @@ class Attention(nn.Module):
         )
         k = dense(kv_heads * self.head_dim, "k_proj")(x).reshape(kv_shape)
         v = dense(kv_heads * self.head_dim, "v_proj")(x).reshape(kv_shape)
-        q = apply_rope(q, positions)
-        k = apply_rope(k, positions)
-        if self.decode:
+        if self.qk_norm:
+            q = RMSNorm(name="q_norm")(q)
+            k = RMSNorm(name="k_norm")(k)
+        q = apply_rope(q, positions, base=self.rope_theta)
+        k = apply_rope(k, positions, base=self.rope_theta)
+        if self.topk:
+            q_idx, w_idx, k_idx = Indexer(
+                self.indexer_heads, self.indexer_head_dim, self.rope_theta,
+                self.dtype, name="indexer",
+            )(x, positions)
+            ctx = sparse_attention(q, k, v, q_idx, w_idx, k_idx, self.topk)
+        elif self.decode:
             ctx = self._cached_attention(q, k, v)
         else:
             attn = self.attention_fn or dense_attention
@@ -393,6 +459,12 @@ class Block(nn.Module):
     causal: bool = True
     #: sliding-window attention size (0 = unlimited); see Attention.window.
     window: int = 0
+    #: see the fields of the same names on :class:`Attention`
+    rope_theta: float = 10_000.0
+    qk_norm: bool = False
+    topk: int = 0
+    indexer_heads: int = 0
+    indexer_head_dim: int = 0
 
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array) -> jax.Array:
@@ -400,7 +472,10 @@ class Block(nn.Module):
             self.num_heads, self.head_dim, self.dtype,
             attention_fn=self.attention_fn, decode=self.decode,
             num_kv_heads=self.num_kv_heads, quantized=self.quantized,
-            window=self.window, name="attn",
+            window=self.window, rope_theta=self.rope_theta,
+            qk_norm=self.qk_norm, topk=self.topk,
+            indexer_heads=self.indexer_heads,
+            indexer_head_dim=self.indexer_head_dim, name="attn",
         )(RMSNorm(name="attn_norm")(x), positions, causal=self.causal)
         if self.quantized:
             if self.mlp_cls is not None:
@@ -419,8 +494,9 @@ class TransformerConfig:
     """Size knobs for :class:`TransformerLM`; ``tiny()`` is the test config.
 
     ``moe_experts > 0`` swaps every block's MLP for a routed
-    :class:`~deeplearning_mpi_tpu.models.moe.MoEMLP` (top-k routing, fixed
-    capacity, experts sharded over the mesh ``expert`` axis).
+    :class:`~deeplearning_mpi_tpu.models.moe.MoEMLP` (top-k routing; fixed
+    capacity with experts sharded over the mesh ``expert`` axis, or
+    ``moe_routing='dropless'``: every claim served, no capacity).
     """
 
     vocab_size: int = 32_000
@@ -444,6 +520,25 @@ class TransformerConfig:
     #: expert takes its top-C tokens; balanced by construction — see
     #: MoEMLP's causality caveat before using it in a causal LM).
     moe_routing: str = "token_choice"
+    #: width of one expert's SwiGLU (0 = ``d_ff``). Models that publish an
+    #: expert width apart from the dense width set it; ``d_ff`` then sizes
+    #: nothing in an all-expert stack.
+    moe_d_ff: int = 0
+    #: rotary base. A MODEL property like the window: every forward (train,
+    #: cached decode, the serving engine) rotates with it.
+    rope_theta: float = 10_000.0
+    #: RMSNorm over each head's ``head_dim`` of q and k, before RoPE
+    #: (learned scales ``attn/q_norm``, ``attn/k_norm``).
+    qk_norm: bool = False
+    #: learned sparse attention (a lightning indexer): each query attends
+    #: the ``attention_topk`` keys at or before it that an indexer of
+    #: ``indexer_heads`` x ``indexer_head_dim`` (one key head) scores highest;
+    #: all of them while fewer exist. 0 = every key. The selection is not
+    #: differentiable and no objective for the indexer is defined here, so
+    #: this is a forward-only property (``ops/sparse_attention.py``).
+    attention_topk: int = 0
+    indexer_heads: int = 0
+    indexer_head_dim: int = 0
     #: sliding-window (local) attention: each query attends its last N
     #: tokens (0 = unlimited). A MODEL property, not a runtime knob — train,
     #: prefill, and KV-cached decode all mask with it, so a window-trained
@@ -459,6 +554,12 @@ class TransformerConfig:
     #: a dot-general keeps per-rank partial sums + all-reduce — the same
     #: association the shard_map path computes (parallel/zero.py).
     onehot_embed: bool = False
+
+    @property
+    def mlp_width(self) -> int:
+        """Width of the block's MLP: one expert's where the model publishes
+        it apart from the dense width, else ``d_ff``."""
+        return self.moe_d_ff if self.moe_experts and self.moe_d_ff else self.d_ff
 
     @staticmethod
     def tiny() -> "TransformerConfig":
@@ -600,10 +701,13 @@ class TransformerLM(nn.Module):
         block_cls = _remat_block(self.remat)
         for i in range(cfg.num_layers):
             x = block_cls(
-                cfg.num_heads, cfg.head_dim, cfg.d_ff, self.dtype,
+                cfg.num_heads, cfg.head_dim, cfg.mlp_width, self.dtype,
                 attention_fn=self.attention_fn, mlp_cls=mlp_cls,
                 decode=self.decode, num_kv_heads=cfg.num_kv_heads,
                 quantized=self.quantized, window=cfg.attention_window,
+                rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm,
+                topk=cfg.attention_topk, indexer_heads=cfg.indexer_heads,
+                indexer_head_dim=cfg.indexer_head_dim,
                 name=f"layer_{i}",
             )(x, positions)
         x = RMSNorm(name="final_norm")(x)

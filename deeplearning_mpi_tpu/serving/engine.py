@@ -17,7 +17,8 @@ tables, fill levels, last tokens, active mask).
 Layer map (see ``docs/SERVING.md`` for the full walkthrough):
 
 - :mod:`~deeplearning_mpi_tpu.serving.kv_pool` owns block accounting and
-  the ``[num_layers, num_blocks, block_size, Hkv, D]`` device pools;
+  the ``[num_layers, num_blocks, block_size, Hkv, D]`` device pools (and
+  the ``[..., block_size, Di]`` indexer-key pool of a selecting model);
 - :mod:`~deeplearning_mpi_tpu.serving.scheduler` owns policy (admission,
   deadlines, oldest-first eviction under KV pressure, bucketed decode-batch
   formation);
@@ -51,15 +52,29 @@ cached-attention module cannot express per-slot fill levels. Parity with
 the offline path is pinned by ``tests/test_serving.py`` (greedy outputs
 identical per request, speculative and plain).
 
-Greedy-only, dense models only: MoE routing makes a token's output depend
-on which OTHER tokens share its batch (capacity contention), which would
-break the engine's request-independence contract — co-batched strangers
-must never change your completion.
+Greedy-only. Two layer kinds beyond the dense block are written once, in
+``PagedForward._layers``, and chosen by the model's config alone:
+
+- learned sparse attention (``attention_topk > 0``): a third pool holds one
+  indexer key a position beside K and V; a query scores the table's indexer
+  keys, keeps the ``attention_topk`` highest and gathers only those K/V rows
+  through the block table (``ops/sparse_attention.py``), in the decode step
+  and in the prefill chunk alike. The verify step does not select, and the
+  indexer pool has no integer storage: ``spec_k > 0`` and an integer
+  ``kv_dtype`` are refused for such a model;
+- a dropless expert layer (``moe_routing='dropless'``,
+  ``models/moe.py:dropless_moe``): every claim is served, so a token's
+  output depends on the token alone. CAPACITY routing stays refused: there a
+  token's output depends on which OTHER tokens share its batch (capacity
+  contention), which would break the engine's request-independence contract
+  — co-batched strangers must never change your completion.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 import time
 from typing import Any, Callable, Iterable, Optional
 
@@ -79,7 +94,15 @@ from deeplearning_mpi_tpu.ops.attention import (
     repeat_kv,
 )
 from deeplearning_mpi_tpu.analysis import sanitizer as _sanitizer
+from deeplearning_mpi_tpu.models.moe import dropless_moe
 from deeplearning_mpi_tpu.ops.quant import dequantize_kv, quantize_kv
+from deeplearning_mpi_tpu.ops.sparse_attention import (
+    attend_masked,
+    attend_selected,
+    indexer_scores,
+    select_mask,
+    select_topk,
+)
 from deeplearning_mpi_tpu.serving.kv_pool import (
     SCRATCH_BLOCK,
     PagedKVPool,
@@ -93,6 +116,13 @@ from deeplearning_mpi_tpu.serving.scheduler import (
 from deeplearning_mpi_tpu.telemetry.trace import annotate, span
 
 __all__ = ["EngineConfig", "KVBuffers", "PagedForward", "ServingEngine"]
+
+#: Queries a selecting layer scores and selects for at a time. A prefill
+#: chunk's queries go through in tiles of this many: per tile the float32
+#: indexer products are ``[tile, Hi, L]`` and the attention scores
+#: ``[H, tile, L]`` (at 16 and 32 heads and L = 65,536: 0.27 GB and 0.54
+#: GB), where the whole chunk at once would not fit beside the weights.
+SELECT_TILE = 64
 
 
 def _table_shapes(
@@ -193,7 +223,8 @@ class EngineConfig:
 class KVBuffers:
     """Mutable holder for the device KV pools a :class:`ServingEngine`
     threads through its jitted steps — ``(k, v)`` for float storage,
-    ``(k, v, k_scale, v_scale)`` for quantized storage (see
+    ``(k, v, k_scale, v_scale)`` for quantized storage, ``(k, v, k_index)``
+    for a model with learned sparse attention (see
     :func:`~deeplearning_mpi_tpu.serving.kv_pool.init_kv_buffers`).
 
     The indirection exists for disaggregation: a prefill-only and a
@@ -252,6 +283,9 @@ class PagedForward:
         self.quantized = kv_dtype is not None and jnp.issubdtype(
             jnp.dtype(kv_dtype), jnp.integer
         )
+        #: learned sparse attention: the kv tuple carries a third pool, the
+        #: indexer keys, and wide tables go through the selecting layer
+        self.selecting = config.attention_topk > 0
         self._tick = tick or (lambda: None)
 
     # -- paged scatter/gather (the storage-format seam) ----------------------
@@ -263,19 +297,26 @@ class PagedForward:
         off: jax.Array,
         k: jax.Array,
         v: jax.Array,
+        k_idx: jax.Array | None = None,
     ) -> tuple[jax.Array, ...]:
         """Write this step's new K/V rows (``[..., Hkv, D]``) through the
-        block table at layer ``i``. Quantized storage also writes the
-        per-row scales — data and scales land in ONE jitted program, which
-        is what makes the pool's scale/block epoch check a real invariant
-        rather than a race window."""
+        block table at layer ``i``; a selecting model's indexer keys
+        (``k_idx [..., Di]``) go to the third pool at the same places.
+        Quantized storage also writes the per-row scales — data and scales
+        land in ONE jitted program, which is what makes the pool's
+        scale/block epoch check a real invariant rather than a race
+        window."""
         with annotate("attn/kv_scatter"):
             if not self.quantized:
-                k_pool, v_pool = kv
-                return (
+                k_pool, v_pool, *index = kv
+                out = (
                     k_pool.at[i, bid, off].set(k.astype(k_pool.dtype)),
                     v_pool.at[i, bid, off].set(v.astype(v_pool.dtype)),
                 )
+                if index:
+                    (i_pool,) = index
+                    out += (i_pool.at[i, bid, off].set(k_idx.astype(i_pool.dtype)),)
+                return out
             k_pool, v_pool, k_scale, v_scale = kv
             qk, sk = quantize_kv(k)
             qv, sv = quantize_kv(v)
@@ -300,7 +341,7 @@ class PagedForward:
         program, 13 ms of a 37 ms decode step on the v5e (PERF.md, PR 28)."""
         with annotate("attn/kv_gather"):
             if not self.quantized:
-                k_pool, v_pool = kv
+                k_pool, v_pool = kv[:2]
                 return k_pool[i, tables], v_pool[i, tables]
             k_pool, v_pool, k_scale, v_scale = kv
             return (
@@ -313,8 +354,8 @@ class PagedForward:
         self, kv: tuple[jax.Array, ...], src: jax.Array, dst: jax.Array
     ) -> tuple[jax.Array, ...]:
         """Copy every pool's pages for block ``src`` into block ``dst``
-        (all layers, data AND scales in one program — same atomicity
-        argument as :meth:`_kv_scatter`). The prefix cache's CoW step: an
+        (all layers, data AND scales — or indexer keys — in one program:
+        same atomicity argument as :meth:`_kv_scatter`). The prefix cache's CoW step: an
         adopter of a partially-matched shared block gets a private copy to
         write its divergent tail into. ``src``/``dst`` are traced scalars,
         so one compilation covers every copy."""
@@ -355,11 +396,16 @@ class PagedForward:
 
     def _attn_proj(
         self, lp: Any, x: jax.Array, pos: jax.Array
-    ) -> tuple[jax.Array, jax.Array, jax.Array]:
-        """Pre-attention norm, Q/K/V projections and RoPE of one layer."""
+    ) -> tuple[jax.Array, jax.Array, jax.Array, tuple[jax.Array, ...] | None]:
+        """Pre-attention norm, Q/K/V projections (per-head RMSNorm where the
+        model has it) and RoPE of one layer; for a selecting model also the
+        indexer's projections (``models.transformer.Indexer`` numerics)
+        ``(qI [rows, seq, Hi, Di], w [rows, seq, Hi], kI [rows, seq, Di])``,
+        else None."""
         cfg = self.config
         rows, seq = x.shape[0], x.shape[1]
         kv_heads = cfg.num_kv_heads or cfg.num_heads
+        rope = functools.partial(apply_rope, positions=pos, base=cfg.rope_theta)
         with annotate("attn/qkv"):
             h = self._rmsnorm(x, lp["attn_norm"]["scale"])
             q = self._lin(h, lp["attn"]["q_proj"]["kernel"]).reshape(
@@ -371,7 +417,24 @@ class PagedForward:
             v = self._lin(h, lp["attn"]["v_proj"]["kernel"]).reshape(
                 rows, seq, kv_heads, cfg.head_dim
             )
-            return apply_rope(q, pos), apply_rope(k, pos), v
+            if cfg.qk_norm:
+                q = self._rmsnorm(q, lp["attn"]["q_norm"]["scale"])
+                k = self._rmsnorm(k, lp["attn"]["k_norm"]["scale"])
+            index = None
+            if self.selecting:
+                ip = lp["attn"]["indexer"]
+                q_idx = self._lin(h, ip["q_proj"]["kernel"]).reshape(
+                    rows, seq, cfg.indexer_heads, cfg.indexer_head_dim
+                )
+                k_idx = self._rmsnorm(
+                    self._lin(h, ip["k_proj"]["kernel"]), ip["k_norm"]["scale"]
+                )
+                index = (
+                    rope(q_idx),
+                    self._lin(h, ip["w_proj"]["kernel"]),
+                    rope(k_idx[:, :, None])[:, :, 0],
+                )
+            return rope(q), rope(k), v, index
 
     def _attn_out(self, lp: Any, x: jax.Array, ctx: jax.Array) -> jax.Array:
         """Residual add of the attention output projection; ``ctx`` is
@@ -382,6 +445,24 @@ class PagedForward:
                 lp["attn"]["out_proj"]["kernel"],
             )
 
+    def _moe(
+        self, lp: Any, x: jax.Array, live: jax.Array
+    ) -> tuple[jax.Array, jax.Array]:
+        """The dropless expert layer (``models.moe.dropless_moe``) over
+        this step's rows as one flat batch; padding rows (``live`` false)
+        claim no expert. Returns the residual sum and the count of experts
+        the live rows touched."""
+        mp = lp["mlp"]
+        with annotate("mlp"):
+            h = self._rmsnorm(x, lp["mlp_norm"]["scale"])
+            y, touched = dropless_moe(
+                h.reshape(-1, h.shape[-1]), mp["router"]["kernel"],
+                mp["experts_gate"], mp["experts_up"], mp["experts_down"],
+                top_k=self.config.moe_top_k, dtype=self.dtype,
+                live=live.reshape(-1),
+            )
+            return x + y.reshape(x.shape), touched
+
     def _mlp(self, lp: Any, x: jax.Array) -> jax.Array:
         with annotate("mlp"):
             h = self._rmsnorm(x, lp["mlp_norm"]["scale"])
@@ -389,6 +470,72 @@ class PagedForward:
                 self._lin(h, lp["mlp"]["gate_proj"]["kernel"])
             ) * self._lin(h, lp["mlp"]["up_proj"]["kernel"])
             return x + self._lin(hidden, lp["mlp"]["down_proj"]["kernel"])
+
+    def _select_attend(
+        self,
+        kv: tuple[jax.Array, ...],
+        i: int,
+        tables: jax.Array,  # [rows, MB]
+        q: jax.Array,       # [rows, seq, H, D]
+        q_idx: jax.Array,   # [rows, seq, Hi, Di]
+        w_idx: jax.Array,   # [rows, seq, Hi]
+        q_pos: jax.Array,   # [rows, seq] absolute position, -1 = padding
+    ) -> jax.Array:
+        """Attention of a selecting layer over the paged pools: gather the
+        table's INDEXER keys (the small pool), score every key for every
+        query, keep the ``attention_topk`` highest at or before the query,
+        attend those. Which K/V traffic that takes is read off the shapes:
+
+        - few queries (a decode step: one a row): the kept positions
+          (:func:`select_topk`) are gathered row by row through the block
+          table (token ``t`` lives at ``tables[t // BS], t % BS``) — the
+          table's K/V pages as a whole are never read;
+        - ``seq * topk`` rows would be more than the table holds (a prefill
+          chunk): the table's pages are gathered once for all the chunk's
+          queries and each attends under its own mask
+          (:func:`select_mask`).
+
+        Queries go through in tiles of :data:`SELECT_TILE`. Padding queries
+        see no key and yield zeros. -> ``[rows, seq, H, D]``."""
+        cfg, BS = self.config, self.engine.block_size
+        k_pool, v_pool, i_pool = kv
+        rows, seq = q_pos.shape
+        span = tables.shape[1] * BS
+        with annotate("attn/kv_gather"):
+            k_idx = i_pool[i, tables].reshape(rows, span, -1)
+        pages = seq * cfg.attention_topk > span
+        if pages:
+            k_seq, v_seq = (
+                a.reshape((rows, span) + a.shape[-2:])
+                for a in self._kv_gather(kv, i, tables)
+            )
+        k_pos = jnp.arange(span, dtype=jnp.int32)
+        row = jnp.arange(rows)[:, None, None]
+
+        def attend_tile(tile: tuple[jax.Array, ...]) -> jax.Array:
+            q_t, qi_t, w_t, pos_t = tile
+            scores = indexer_scores(qi_t, w_t, k_idx)
+            visible = k_pos[None, None, :] <= pos_t[:, :, None]
+            if pages:
+                mask = select_mask(scores, visible, cfg.attention_topk)
+                with annotate("attn/core"):
+                    return attend_masked(q_t, k_seq, v_seq, mask)
+            ids, kept = select_topk(scores, visible, cfg.attention_topk)
+            with annotate("attn/sparse_gather"):
+                blk, off = tables[row, ids // BS], ids % BS
+                k_sel, v_sel = k_pool[i, blk, off], v_pool[i, blk, off]
+            with annotate("attn/core"):
+                return attend_selected(q_t, k_sel, v_sel, kept)
+
+        width = math.gcd(seq, SELECT_TILE)
+        if width == seq:
+            return attend_tile((q, q_idx, w_idx, q_pos))
+        # [rows, seq, ...] -> [tiles, rows, width, ...], one tile at a time
+        split = lambda a: jnp.moveaxis(  # noqa: E731
+            a.reshape((rows, seq // width, width) + a.shape[2:]), 1, 0
+        )
+        out = jax.lax.map(attend_tile, tuple(map(split, (q, q_idx, w_idx, q_pos))))
+        return jnp.moveaxis(out, 0, 1).reshape(q.shape)
 
     def _layers(
         self,
@@ -400,33 +547,67 @@ class PagedForward:
         off: jax.Array,     # offset in that block; same shape as ``bid``
         tables: jax.Array,  # [rows, MB] block ids, or [MB] for one row
         attend: Callable[[jax.Array, jax.Array, jax.Array], jax.Array],
-    ) -> tuple[tuple[jax.Array, ...], jax.Array]:
+    ) -> tuple[tuple[jax.Array, ...], jax.Array, jax.Array]:
         """THE layer loop of all three programs: per layer, project, scatter
-        the new K/V rows through ``(bid, off)``, gather the table's pages
-        back into position order (the block table IS the logical->physical
-        map, so indexing the pool with it yields a contiguous
-        ``[rows, L, Hkv, D]`` view of every sequence, this step's rows
-        included), ``attend(q, k_seq, v_seq) -> [rows, seq, H, D]`` under
-        the ``attn/core`` scope, output projection, MLP; then the final
-        norm. A program is its index math for ``(pos, bid, off)``, its
-        ``attend`` and its head; a new layer kind is written here once."""
+        the new K/V rows through ``(bid, off)``, attend, output projection,
+        MLP; then the final norm. A program is its index math for
+        ``(pos, bid, off)``, its ``attend`` and its head; a layer kind is
+        written here once. Two kinds of attention:
+
+        - every key (the default): gather the table's pages back into
+          position order (the block table IS the logical->physical map, so
+          indexing the pool with it yields a contiguous ``[rows, L, Hkv, D]``
+          view of every sequence, this step's rows included) and
+          ``attend(q, k_seq, v_seq) -> [rows, seq, H, D]`` under the
+          ``attn/core`` scope;
+        - selecting (``attention_topk > 0`` and a table wider than it):
+          the indexer keys are scattered beside K/V and
+          :meth:`_select_attend` reads only the selected rows. A table of
+          at most ``attention_topk`` positions selects everything, which is
+          the first kind (its indexer keys are still written: later, wider
+          steps read them).
+
+        And two kinds of MLP: dense SwiGLU, or the dropless expert layer
+        over the step's rows (a row that writes to the scratch block is
+        padding and claims no expert). Returns the pools, the normed
+        activations and the experts touched per layer (``[layers]`` int32;
+        empty for a dense model)."""
         cfg = self.config
         head = (cfg.num_kv_heads or cfg.num_heads, cfg.head_dim)
         new = bid.shape + head  # this step's K/V rows, one per (bid, off)
-        seq = (x.shape[0], tables.shape[-1] * self.engine.block_size) + head
+        span = tables.shape[-1] * self.engine.block_size
+        seq = (x.shape[0], span) + head
+        selecting = self.selecting and span > cfg.attention_topk
+        live = (bid != SCRATCH_BLOCK).reshape(x.shape[:2])
+        touched = []
         for i in range(cfg.num_layers):
             lp = params[f"layer_{i}"]
-            q, k, v = self._attn_proj(lp, x, pos)
+            q, k, v, index = self._attn_proj(lp, x, pos)
             kv = self._kv_scatter(
-                kv, i, bid, off, k.reshape(new), v.reshape(new)
+                kv, i, bid, off, k.reshape(new), v.reshape(new),
+                index and index[2].reshape(bid.shape + index[2].shape[-1:]),
             )
-            k_seq, v_seq = self._kv_gather(kv, i, tables)
-            k_seq, v_seq = k_seq.reshape(seq), v_seq.reshape(seq)
-            with annotate("attn/core"):
-                ctx = attend(q, k_seq, v_seq)
+            if selecting:
+                ctx = self._select_attend(
+                    kv, i, tables.reshape(x.shape[0], -1), q, *index[:2],
+                    jnp.where(live, pos, -1),
+                )
+            else:
+                k_seq, v_seq = self._kv_gather(kv, i, tables)
+                k_seq, v_seq = k_seq.reshape(seq), v_seq.reshape(seq)
+                with annotate("attn/core"):
+                    ctx = attend(q, k_seq, v_seq)
             x = self._attn_out(lp, x, ctx)
-            x = self._mlp(lp, x)
-        return kv, self._rmsnorm(x, params["final_norm"]["scale"])
+            if cfg.moe_experts:
+                x, count = self._moe(lp, x, live)
+                touched.append(count)
+            else:
+                x = self._mlp(lp, x)
+        return (
+            kv,
+            self._rmsnorm(x, params["final_norm"]["scale"]),
+            jnp.stack(touched) if touched else jnp.zeros((0,), jnp.int32),
+        )
 
     # -- jitted decode step --------------------------------------------------
     def decode_step(
@@ -437,8 +618,11 @@ class PagedForward:
         lengths: jax.Array,  # [S] int32 known tokens (prompt + generated)
         tokens: jax.Array,   # [S] int32 token fed this step (position len-1)
         active: jax.Array,   # [S] bool
-    ) -> tuple[tuple[jax.Array, ...], jax.Array]:
-        """One token for each of the table's ``S`` rows. Static per
+    ) -> tuple[tuple[jax.Array, ...], jax.Array, jax.Array]:
+        """One token for each of the table's ``S`` rows, and the experts
+        each layer's rows touched (``[layers]`` int32, an output of its own
+        that the step's one fetch brings with the tokens; ``[0]`` for a
+        dense model). Static per
         PROGRAM: ``S`` and ``MB``, read off ``tables`` (any row count up to
         ``max_slots``, any width up to ``max_blocks_per_seq``; a row is a
         packed position, not a slot). Static per ENGINE: ``block_size``,
@@ -474,9 +658,11 @@ class PagedForward:
         def attend(q: jax.Array, k_seq: jax.Array, v_seq: jax.Array) -> jax.Array:
             return batched_decode_attention(q, k_seq, v_seq, idx, window=window)
 
-        kv, x = self._layers(params, kv, x, pos, bid, p % BS, tables, attend)
+        kv, x, touched = self._layers(
+            params, kv, x, pos, bid, p % BS, tables, attend
+        )
         logits = self._logits(x[:, 0], params)  # [S, V] f32
-        return kv, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return kv, jnp.argmax(logits, axis=-1).astype(jnp.int32), touched
 
     # -- jitted prefill chunk ------------------------------------------------
     def prefill_chunk(
@@ -519,7 +705,7 @@ class PagedForward:
                 causal=True, window=window, q_offset=start,
             )
 
-        kv, x = self._layers(params, kv, x, pos, bid, p % BS, table, attend)
+        kv, x, _ = self._layers(params, kv, x, pos, bid, p % BS, table, attend)
         # Only the last VALID row's logits matter (and only on the final
         # chunk — the host ignores them otherwise). Padded rows compute
         # garbage that is never read and whose K/V went to scratch.
@@ -607,7 +793,7 @@ class PagedForward:
                 preferred_element_type=jnp.float32,
             ).astype(q.dtype)
 
-        kv, x = self._layers(params, kv, x, pos, bid, p % BS, tables, attend)
+        kv, x, _ = self._layers(params, kv, x, pos, bid, p % BS, tables, attend)
         logits = self._logits(x, params)  # [S, W, V] f32
         return kv, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
@@ -659,11 +845,13 @@ class ServingEngine:
         tracer: Any = None,
     ) -> None:
         engine = engine or EngineConfig()
-        if config.moe_experts > 0:
+        if config.moe_experts > 0 and config.moe_routing != "dropless":
             raise NotImplementedError(
-                "serving engine is dense-MLP only: MoE capacity routing "
-                "makes a token's output depend on co-batched strangers, "
-                "which breaks the engine's request-independence contract"
+                f"serving engine refuses moe_routing={config.moe_routing!r}: "
+                "capacity routing makes a token's output depend on "
+                "co-batched strangers, which breaks the engine's "
+                "request-independence contract (moe_routing='dropless' "
+                "serves every claim and is admitted)"
             )
         if "kernel" not in params["layer_0"]["attn"]["q_proj"]:
             raise NotImplementedError(
@@ -685,6 +873,17 @@ class ServingEngine:
                 "a self-draft from the target's own first N layers)"
             )
         storage = jnp.dtype(engine.kv_dtype) if engine.kv_dtype else None
+        if config.attention_topk > 0:
+            if engine.spec_k > 0:
+                raise NotImplementedError(
+                    "attention_topk > 0 with spec_k > 0: the verify step "
+                    "does not select (serving/speculative.py)"
+                )
+            if storage is not None and jnp.issubdtype(storage, jnp.integer):
+                raise NotImplementedError(
+                    f"attention_topk > 0 with kv_dtype={engine.kv_dtype!r}: "
+                    "an integer indexer-key pool is not implemented"
+                )
         if storage is not None and jnp.issubdtype(storage, jnp.integer):
             if storage != jnp.dtype(jnp.int8):
                 raise NotImplementedError(
@@ -740,6 +939,7 @@ class ServingEngine:
                 config.num_layers, engine.num_blocks, engine.block_size,
                 config.num_kv_heads or config.num_heads, config.head_dim,
                 storage if storage is not None else dtype,
+                index_dim=config.indexer_head_dim if config.attention_topk else 0,
             ))
         self._kvh = kv_buffers
         self._kv_dtype_name = (storage or jnp.dtype(dtype)).name
@@ -804,6 +1004,16 @@ class ServingEngine:
                 # are set alongside the engine's other gauges each step.
                 registry.gauge("serve_prefix_nodes")
                 registry.gauge("serve_prefix_blocks")
+            if config.attention_topk > 0:
+                # Per decode step, summed over its rows: keys a row holds,
+                # and keys its queries attend (min(length, topk)).
+                registry.counter("serve_select_live_keys")
+                registry.counter("serve_select_kept_keys")
+            if config.moe_experts > 0:
+                # Per decode step, summed over layers: distinct experts the
+                # step's rows routed to, and experts held.
+                registry.counter("serve_moe_experts_touched")
+                registry.counter("serve_moe_expert_slots")
         self._fwd = PagedForward(
             config, engine, dtype,
             tick=lambda: self._inc("serve_compile_total"),
@@ -1253,9 +1463,11 @@ class ServingEngine:
         # inactive.
         held = [len(r.blocks) for r in decoding]
         rows, width = self._decode_shape(len(decoding), max(held))
+        cfg = self.config
         with span(
             "serve/decode_launch",
             rows=len(decoding), table_rows=rows, width=width,
+            topk=cfg.attention_topk,
         ):
             tables = np.zeros((rows, width), np.int32)
             lengths = np.zeros((rows,), np.int32)
@@ -1266,7 +1478,7 @@ class ServingEngine:
                 lengths[i] = req.length
                 tokens[i] = req.generated[-1]
                 active[i] = True
-            self._kv, next_tok = self._decode_fn(
+            self._kv, next_tok, touched = self._decode_fn(
                 self.params, self._kv,
                 jnp.asarray(tables), jnp.asarray(lengths),
                 jnp.asarray(tokens), jnp.asarray(active),
@@ -1278,8 +1490,25 @@ class ServingEngine:
             self._inc("serve_decode_steps")
             self._inc("serve_gather_blocks", rows * width)
             self._inc("serve_live_blocks", sum(held))
+            if cfg.attention_topk:
+                self._inc("serve_select_live_keys", sum(r.length for r in decoding))
+                self._inc(
+                    "serve_select_kept_keys",
+                    sum(min(r.length, cfg.attention_topk) for r in decoding),
+                )
         with span("serve/token_fetch"):
-            next_np = np.asarray(jax.device_get(next_tok))  # dmt-lint: disable=DMT003 — THE audited sync: one sampled-token fetch per decode step (EOS/retire decisions are host-side)
+            # THE audited sync: one fetch per decode step brings the sampled
+            # tokens (EOS/retire decisions are host-side) and, for an expert
+            # model, the touched-experts counts beside them (a second array
+            # in the same fetch costs ~0.1 ms of host time a step on the
+            # v5e, so a dense model fetches the tokens alone).
+            if cfg.moe_experts:
+                next_np, touched_np = jax.device_get((next_tok, touched))  # dmt-lint: disable=DMT003 — the audited sync
+            else:
+                next_np = np.asarray(jax.device_get(next_tok))  # dmt-lint: disable=DMT003 — the audited sync
+        if cfg.moe_experts:
+            self._inc("serve_moe_experts_touched", int(touched_np.sum()))
+            self._inc("serve_moe_expert_slots", cfg.num_layers * cfg.moe_experts)
         with span("serve/retire") as sp:
             before = len(finished)
             now = self._clock()
@@ -1507,6 +1736,7 @@ class ServingEngine:
         with span(
             "serve/prefill_launch",
             rid=req.rid, start=start, n=n_valid, width=width,
+            topk=self.config.attention_topk,
         ):
             chunk = np.zeros((e.prefill_chunk,), np.int32)
             chunk[:n_valid] = req.prompt[start : start + n_valid]

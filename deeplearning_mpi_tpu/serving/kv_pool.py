@@ -21,7 +21,9 @@ Split of responsibilities:
   (``tests/test_serving.py`` drives alloc/free storms and checks the
   invariants below).
 - The device buffers (``[num_layers, num_blocks, block_size, Hkv, D]`` for
-  K and V) are created by :func:`init_kv_buffers` and owned by the engine,
+  K and V; for a model with learned sparse attention a third,
+  ``[num_layers, num_blocks, block_size, Di]``, for its indexer keys) are
+  created by :func:`init_kv_buffers` and owned by the engine,
   which scatters/gathers through the block tables inside its jitted step
   (``serving/engine.py``).
 
@@ -353,8 +355,16 @@ def init_kv_buffers(
     kv_heads: int,
     head_dim: int,
     kv_dtype: Any,
+    *,
+    index_dim: int = 0,
 ) -> tuple[Any, ...]:
     """Zero-initialized device pools in the explicit storage ``kv_dtype``.
+
+    ``index_dim > 0`` (a model with learned sparse attention, float storage
+    only) adds a THIRD pool, the indexer keys: ``(k, v, k_index)`` with
+    ``k_index`` ``[num_layers, num_blocks, block_size, index_dim]`` — one
+    small key a position, addressed by the same block tables, so one
+    allocation covers all three and a block copy carries all three.
 
     Float dtypes return ``(k, v)``, each ``[num_layers, num_blocks,
     block_size, kv_heads, head_dim]``. Integer dtypes (the int8 KV cache)
@@ -375,7 +385,15 @@ def init_kv_buffers(
     k = jnp.zeros(shape, kv_dtype)
     v = jnp.zeros(shape, kv_dtype)
     if not jnp.issubdtype(jnp.dtype(kv_dtype), jnp.integer):
+        if index_dim:
+            index_shape = (num_layers, num_blocks, block_size, index_dim)
+            return k, v, jnp.zeros(index_shape, kv_dtype)
         return k, v
+    if index_dim:
+        raise NotImplementedError(
+            "an indexer-key pool in integer storage is not implemented "
+            "(quantize_kv is a per-head scheme for K and V)"
+        )
     # Scales default to 1 (not 0): a gather from a never-written block then
     # dequantizes zeros to zeros instead of 0 * 0 hiding a missing write
     # behind an all-zero page that happens to look plausible.

@@ -186,7 +186,7 @@ class SpeculativeDecoder:
         """Compile the draft decode program for one narrower gather-width
         bucket (ServingEngine.warmup drives this with all-inactive rows —
         scratch-block writes, harmless execution)."""
-        self._kv, _ = self._decode_jit(
+        self._kv, _, _ = self._decode_jit(
             self.params, self._kv, tables, idle, idle, off
         )
 
@@ -243,7 +243,7 @@ class SpeculativeDecoder:
         steps = 0
         for j in range(min(last_j, K) + 1):
             act = act_rows & (j <= budget)
-            self._kv, out = self._decode_fn(
+            self._kv, out, _ = self._decode_fn(
                 self.params, self._kv,
                 jnp.asarray(tables),
                 jnp.asarray(lengths + j, dtype=np.int32),

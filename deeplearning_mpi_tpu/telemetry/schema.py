@@ -59,6 +59,13 @@ METRICS: dict[str, tuple[str, frozenset[str]]] = {
     # ratio is the fill of the gather (docs/SERVING.md "The fixed-shape step")
     "serve_gather_blocks": ("counter", frozenset()),
     "serve_live_blocks": ("counter", frozenset()),
+    # a selecting model's decode steps: keys its rows hold, keys their queries
+    # attend (min(length, topk)); an expert model's: distinct experts a step's
+    # rows routed to, summed over layers, against layers x experts held
+    "serve_select_live_keys": ("counter", frozenset()),
+    "serve_select_kept_keys": ("counter", frozenset()),
+    "serve_moe_experts_touched": ("counter", frozenset()),
+    "serve_moe_expert_slots": ("counter", frozenset()),
     "serve_handoff_depth": ("gauge", frozenset()),
     "serve_handoff_stalls_total": ("counter", frozenset()),
     "serve_handoffs_total": ("counter", frozenset()),

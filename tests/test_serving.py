@@ -818,7 +818,7 @@ class TestEngineValidation:
         import dataclasses
 
         cfg = dataclasses.replace(TransformerConfig.tiny(), moe_experts=4)
-        with pytest.raises(NotImplementedError, match="dense-MLP only"):
+        with pytest.raises(NotImplementedError, match="capacity routing"):
             ServingEngine(cfg, {}, EngineConfig())
 
     def test_rejects_quantized_param_trees(self):
@@ -1432,10 +1432,10 @@ class TestPrefixCacheDisagg:
 _PHASE_LABELS = {
     "serve/step": {"step", "t"},
     "serve/admit": {"admitted", "queued"},
-    "serve/prefill_launch": {"rid", "start", "n", "width"},
+    "serve/prefill_launch": {"rid", "start", "n", "width", "topk"},
     "serve/first_token_fetch": {"rid"},
     "serve/grow": set(),
-    "serve/decode_launch": {"rows", "table_rows", "width"},
+    "serve/decode_launch": {"rows", "table_rows", "width", "topk"},
     "serve/token_fetch": set(),
     "serve/retire": {"finished"},
     "serve/gauges": set(),
@@ -1872,8 +1872,8 @@ class TestLiveShapes:
         for i, slot in enumerate(slots):
             for dst, src in zip(packed, (tables[:, :width], lengths, tokens, active)):
                 dst[i] = src[slot]
-        kv_full, tok_full = engine._decode_jit(engine.params, _own(kv), *map(jnp.asarray, (tables, lengths, tokens, active)))
-        kv_packed, tok_packed = engine._decode_jit(engine.params, _own(kv), *map(jnp.asarray, packed))
+        kv_full, tok_full, _ = engine._decode_jit(engine.params, _own(kv), *map(jnp.asarray, (tables, lengths, tokens, active)))
+        kv_packed, tok_packed, _ = engine._decode_jit(engine.params, _own(kv), *map(jnp.asarray, packed))
         assert tok_packed.shape == (rows,)
         assert [int(tok_packed[i]) for i in range(len(slots))] == [int(tok_full[s]) for s in slots]
         for a, b, before in zip(_pages(kv_packed), _pages(kv_full), _pages(kv)):
@@ -1988,7 +1988,7 @@ class TestLiveShapes:
         tables, lengths, tokens, active = _slot_batch(e, slots, prompts, held)
         kv = _prefilled(engine, prompts, [tables[s] for s in slots])
         tables, lengths, tokens, active = map(jnp.asarray, (tables, lengths, tokens, active))
-        kv_dec, tok_dec = engine._decode_jit(engine.params, _own(kv), tables, lengths, tokens, active)
+        kv_dec, tok_dec, _ = engine._decode_jit(engine.params, _own(kv), tables, lengths, tokens, active)
         kv_ver, tok_ver = jax.jit(engine._fwd.verify_step)(
             engine.params, _own(kv), tables, lengths, tokens[:, None], jnp.ones_like(lengths), active,
         )
