@@ -125,10 +125,33 @@ __all__ = ["EngineConfig", "KVBuffers", "PagedForward", "ServingEngine"]
 SELECT_TILE = 64
 
 
+def window_first_block(length: Any, window: int, block_size: int) -> Any:
+    """Index, within a sequence's block table, of the first block a query
+    at the last of ``length`` known positions can reach under a sliding
+    window of ``window`` positions: the query sits at ``length - 1`` and
+    sees ``length - window .. length - 1``, so every block before
+    ``max(length - window, 0) // block_size`` is out of reach.
+
+    THE one place the formula lives. ``ServingEngine._plain_decode`` cuts a
+    row's table here (``length`` a host int or numpy array) and
+    ``PagedForward.decode_step`` shifts its block index and its attention
+    index by it (``length`` a traced array): operators only, so both get
+    their own kind back and the two halves cannot drift."""
+    past = length - window  # positions the window has left behind
+    return past * (past > 0) // block_size
+
+
+def window_blocks(window: int, block_size: int) -> int:
+    """The most blocks a row's table can hold from
+    :func:`window_first_block` on: ``window`` positions that start
+    anywhere in a block."""
+    return -(-window // block_size) + 1
+
+
 def _table_shapes(
-    max_slots: int, max_blocks: int
+    max_slots: int, max_blocks: int, decode_blocks: int | None = None
 ) -> tuple[tuple[int, ...], tuple[tuple[int, int], ...]]:
-    """The static shapes a block table may take, from the engine's two
+    """The static shapes a block table may take, from the engine's
     ceilings alone: the ladder of widths (the prefill chunk's; the verify
     step's and the draft's at ``max_slots`` rows) and the decode step's
     (rows, width) pairs. Together they are the programs ``warmup()`` pays
@@ -136,22 +159,27 @@ def _table_shapes(
 
     Widths: the powers of two from an eighth of the full table up, then the
     full table; a rung below an eighth would save under a sixteenth of the
-    full gather. Decode pairs: a quarter, a half and all of ``max_slots``
-    rows at the two widest rungs, and ``max_slots`` rows at every rung. The
-    gather costs rows x width, so a narrow table is cheap already and a rung
-    saves the most where the rows are many: the narrow rungs are not
-    multiplied by the row buckets."""
-    widths = []
-    w = 1
-    while w < max_blocks:
-        if 8 * w >= max_blocks:
-            widths.append(w)
-        w *= 2
-    widths.append(max_blocks)
+    full gather. Decode pairs: the same ladder built on ``decode_blocks``,
+    the most blocks a decode row can hand over (``max_blocks`` unless a
+    sliding window caps it lower: :func:`window_blocks`); a quarter, a half
+    and all of ``max_slots`` rows at its two widest rungs, and ``max_slots``
+    rows at every rung. The gather costs rows x width, so a narrow table is
+    cheap already and a rung saves the most where the rows are many: the
+    narrow rungs are not multiplied by the row buckets."""
+
+    def ladder(top: int) -> list[int]:
+        rungs, w = [], 1
+        while w < top:
+            if 8 * w >= top:
+                rungs.append(w)
+            w *= 2
+        return rungs + [top]
+
+    decode = ladder(decode_blocks or max_blocks)
     rows = {-(-max_slots // 4), -(-max_slots // 2), max_slots}
-    pairs = {(r, w) for r in rows for w in widths[-2:]}
-    pairs |= {(max_slots, w) for w in widths}
-    return tuple(widths), tuple(sorted(pairs))
+    pairs = {(r, w) for r in rows for w in decode[-2:]}
+    pairs |= {(max_slots, w) for w in decode}
+    return tuple(ladder(max_blocks)), tuple(sorted(pairs))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -275,11 +303,23 @@ class PagedForward:
         *,
         tick: Callable[[], None] | None = None,
         kv_dtype: Any = None,
+        window_cut: bool = False,
     ) -> None:
         self.config = config
         self.engine = engine
         self.dtype = dtype
         self.kv_dtype = kv_dtype
+        #: the sliding window, where the caller of :meth:`decode_step` hands
+        #: it tables that start at :func:`window_first_block`
+        #: (``window_cut``: the engine's own decode launch does, the draft's
+        #: propose loop hands whole tables) AND a sequence can outgrow the
+        #: window; else 0, and the decode program is the one of a model
+        #: without a window
+        self.decode_window = (
+            config.attention_window
+            if window_cut and 0 < config.attention_window < engine.max_seq_len
+            else 0
+        )
         self.quantized = kv_dtype is not None and jnp.issubdtype(
             jnp.dtype(kv_dtype), jnp.integer
         )
@@ -627,7 +667,13 @@ class PagedForward:
         ``max_slots``, any width up to ``max_blocks_per_seq``; a row is a
         packed position, not a slot). Static per ENGINE: ``block_size``,
         the model, the pools. Rows with ``active`` false are padding: they
-        write to the scratch block and their token is garbage."""
+        write to the scratch block and their token is garbage.
+
+        Under :attr:`decode_window`, ``tables[s, 0]`` is the block of row
+        ``s`` that holds the first position its window can reach
+        (:func:`window_first_block` of ``lengths[s]``), not its block 0: a
+        row past the window hands over, gathers and attends at most
+        :func:`window_blocks` blocks however long it has grown."""
         # Host side effect at TRACE time only: one tick per compilation of
         # this program. A warmed engine calls the AOT executable directly
         # (never retraces), so "zero compiles on the first request" is an
@@ -644,15 +690,28 @@ class PagedForward:
         x = self._embed(params, tokens)[:, None, :]  # [S, 1, d]
         pos = jnp.maximum(lengths - 1, 0)[:, None]  # [S, 1] absolute
         p = pos[:, 0]
+        # The new row's block and the query's index, in the TABLE's
+        # coordinates: absolute where the table starts at block 0, less the
+        # blocks the host left out where it starts at the window's first.
+        # RoPE stays absolute (K is stored rotated at its own position) and
+        # the attention mask is relative to the index, so nothing below
+        # knows the difference.
+        first = None
+        if self.decode_window:
+            first = window_first_block(lengths, self.decode_window, BS)
+
+        def in_table(x: jax.Array, unit: int) -> jax.Array:
+            return x if first is None else x - first * unit
+
         # Inactive slots route their (garbage) writes to the scratch block.
         bid = jnp.where(
             active,
-            tables[jnp.arange(S), jnp.minimum(p // BS, MB - 1)],
+            tables[jnp.arange(S), jnp.minimum(in_table(p // BS, 1), MB - 1)],
             SCRATCH_BLOCK,
         )
         # Row b attends its own filled prefix 0..lengths[b]-1; negative
         # marks the row inactive (zero output).
-        idx = jnp.where(active, lengths - 1, -1)
+        idx = jnp.where(active, in_table(lengths - 1, BS), -1)
         window = self.config.attention_window or None
 
         def attend(q: jax.Array, k_seq: jax.Array, v_seq: jax.Array) -> jax.Array:
@@ -943,12 +1002,6 @@ class ServingEngine:
             ))
         self._kvh = kv_buffers
         self._kv_dtype_name = (storage or jnp.dtype(dtype)).name
-        # The static shapes a step's block table can take (docs/SERVING.md
-        # "The fixed-shape step"): derived from max_slots and
-        # max_blocks_per_seq alone, and the very lists warmup() walks.
-        self._widths, self._decode_shapes = _table_shapes(
-            engine.max_slots, engine.max_blocks_per_seq
-        )
         self._next_rid = 0
         self.steps = 0
         self._metrics = registry
@@ -964,6 +1017,7 @@ class ServingEngine:
                 "serve_decode_steps", "serve_requeued_total",
                 "serve_tokens_discarded_total",
                 "serve_gather_blocks", "serve_live_blocks",
+                "serve_window_skipped_blocks",
             ):
                 registry.counter(name)
             # A role-labeled engine (one half of a disaggregated pair)
@@ -1017,7 +1071,20 @@ class ServingEngine:
         self._fwd = PagedForward(
             config, engine, dtype,
             tick=lambda: self._inc("serve_compile_total"),
-            kv_dtype=storage,
+            kv_dtype=storage, window_cut=True,
+        )
+        # The static shapes a step's block table can take (docs/SERVING.md
+        # "The fixed-shape step"): derived from max_slots,
+        # max_blocks_per_seq and, for the decode step, the blocks a sliding
+        # window can reach; the very lists warmup() walks.
+        decode_blocks = engine.max_blocks_per_seq
+        if self._fwd.decode_window:
+            decode_blocks = min(
+                decode_blocks,
+                window_blocks(self._fwd.decode_window, engine.block_size),
+            )
+        self._widths, self._decode_shapes = _table_shapes(
+            engine.max_slots, engine.max_blocks_per_seq, decode_blocks
         )
         # KV-cache donation, vetoed where unsafe (XLA:CPU + persistent
         # compile cache — compiler.cache.donation_safe, reached through the
@@ -1144,12 +1211,13 @@ class ServingEngine:
             return jnp.zeros(shape, dtype)
 
         # One program for every table shape the bucket functions can emit
-        # (_table_shapes): the count of these is warm-up's cost. The full
-        # shape keeps the bare name.
+        # (_table_shapes): the count of these is warm-up's cost. The widest
+        # shape of each list keeps the bare name.
         full = (e.max_slots, e.max_blocks_per_seq)
         decode_names = {
             shape: "serve_decode_step" + (
-                "" if shape == full else "@{}x{}".format(*shape)
+                "" if shape == self._decode_shapes[-1]
+                else "@{}x{}".format(*shape)
             )
             for shape in self._decode_shapes
         }
@@ -1440,16 +1508,17 @@ class ServingEngine:
         transition."""
         return next(w for w in self._widths if w >= blocks)
 
-    def _decode_shape(self, rows: int, blocks_held: int) -> tuple[int, int]:
+    def _decode_shape(self, rows: int, blocks: int) -> tuple[int, int]:
         """Static (rows, width) of this step's decode table: of the pairs
         in ``_decode_shapes`` (:func:`_table_shapes`) that hold the ``rows``
-        that decode and the widest live row's ``blocks_held``, the one of
-        least rows x width (the gather and the attention over it are linear
-        in that product), with fewer rows on a tie. Both sizes are static
-        per PROGRAM, so the step costs what is live."""
+        that decode and the ``blocks`` the widest live row hands over (what
+        it holds, from the first its window can reach), the one of least
+        rows x width (the gather and the attention over it are linear in
+        that product), with fewer rows on a tie. Both sizes are static per
+        PROGRAM, so the step costs what is live."""
         return min(
             (s for s in self._decode_shapes
-             if s[0] >= rows and s[1] >= blocks_held),
+             if s[0] >= rows and s[1] >= blocks),
             key=lambda s: (s[0] * s[1], s),
         )
 
@@ -1461,20 +1530,30 @@ class ServingEngine:
         # least rows x width; row i of every array — and of the tokens that
         # come back — is decoding[i], whatever its slot. Pad rows are
         # inactive.
-        held = [len(r.blocks) for r in decoding]
-        rows, width = self._decode_shape(len(decoding), max(held))
-        cfg = self.config
+        # Under a sliding window that can bind, a row's table starts at the
+        # first block its query can reach (the program shifts its indices
+        # by the same window_first_block); the blocks before it stay the
+        # request's, unread.
+        cfg, BS = self.config, self.engine.block_size
+        window = self._fwd.decode_window
+        first = [
+            window_first_block(r.length, window, BS) if window else 0
+            for r in decoding
+        ]
+        reach = [len(r.blocks) - f for r, f in zip(decoding, first)]
+        skipped = sum(first)
+        rows, width = self._decode_shape(len(decoding), max(reach))
         with span(
             "serve/decode_launch",
             rows=len(decoding), table_rows=rows, width=width,
-            topk=cfg.attention_topk,
+            skipped=skipped, topk=cfg.attention_topk,
         ):
             tables = np.zeros((rows, width), np.int32)
             lengths = np.zeros((rows,), np.int32)
             tokens = np.zeros((rows,), np.int32)
             active = np.zeros((rows,), bool)
             for i, req in enumerate(decoding):
-                tables[i, : held[i]] = req.blocks
+                tables[i, : reach[i]] = req.blocks[first[i]:]
                 lengths[i] = req.length
                 tokens[i] = req.generated[-1]
                 active[i] = True
@@ -1483,13 +1562,13 @@ class ServingEngine:
                 jnp.asarray(tables), jnp.asarray(lengths),
                 jnp.asarray(tokens), jnp.asarray(active),
             )
-            BS = self.engine.block_size
             self._record_writes(
                 {req.blocks[(req.length - 1) // BS] for req in decoding}
             )
             self._inc("serve_decode_steps")
             self._inc("serve_gather_blocks", rows * width)
-            self._inc("serve_live_blocks", sum(held))
+            self._inc("serve_live_blocks", sum(reach))
+            self._inc("serve_window_skipped_blocks", skipped)
             if cfg.attention_topk:
                 self._inc("serve_select_live_keys", sum(r.length for r in decoding))
                 self._inc(

@@ -56,9 +56,13 @@ METRICS: dict[str, tuple[str, frozenset[str]]] = {
     "serve_decode_steps": ("counter", frozenset()),
     # blocks the programs' tables gather (rows x width a decode or verify step,
     # width a chunk) against blocks the live rows hold / the chunk can see: their
-    # ratio is the fill of the gather (docs/SERVING.md "The fixed-shape step")
+    # ratio is the fill of the gather (docs/SERVING.md "The fixed-shape step");
+    # under a sliding window a decode row's table starts at the first block
+    # its query can reach: live counts the blocks from there on, skipped the
+    # blocks before it (held, not gathered)
     "serve_gather_blocks": ("counter", frozenset()),
     "serve_live_blocks": ("counter", frozenset()),
+    "serve_window_skipped_blocks": ("counter", frozenset()),
     # a selecting model's decode steps: keys its rows hold, keys their queries
     # attend (min(length, topk)); an expert model's: distinct experts a step's
     # rows routed to, summed over layers, against layers x experts held

@@ -1435,13 +1435,14 @@ _PHASE_LABELS = {
     "serve/prefill_launch": {"rid", "start", "n", "width", "topk"},
     "serve/first_token_fetch": {"rid"},
     "serve/grow": set(),
-    "serve/decode_launch": {"rows", "table_rows", "width", "topk"},
+    "serve/decode_launch": {"rows", "table_rows", "width", "skipped", "topk"},
     "serve/token_fetch": set(),
     "serve/retire": {"finished"},
     "serve/gauges": set(),
 }
 _PROGRAMS = ["decode_step", "prefill_chunk", "verify_step"]
 _PACKED = ["decode_step@packed", "prefill_chunk@packed"]  # at the smallest table shape
+_WINDOW_CUT = "decode_step@window"  # a model with a window of 8: the table starts at the window's first block
 _SCOPES = (
     "embed", "attn/qkv", "attn/kv_scatter", "attn/kv_gather", "attn/core",
     "attn/out", "mlp", "logits",
@@ -1644,7 +1645,10 @@ class TestStepSpans:
         ``<program>@packed`` at the smallest table the bucket functions emit."""
         e = engine.engine
         program, _, packed = program.partition("@")
-        rows, width = engine._decode_shapes[0] if packed else (e.max_slots, e.max_blocks_per_seq)
+        rows, width = {
+            "": (e.max_slots, e.max_blocks_per_seq), "packed": engine._decode_shapes[0],
+            "window": engine._decode_shapes[-1],
+        }[packed]
         slots = jnp.zeros((rows,), jnp.int32)
         tables = jnp.zeros((rows, width), jnp.int32)
         live = jnp.zeros((rows,), bool)
@@ -1695,14 +1699,19 @@ class TestStepSpans:
         assert entered == layer_scopes * engine.config.num_layers
 
     @pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["float", "int8"])
-    @pytest.mark.parametrize("program", [*_PROGRAMS, *_PACKED])
+    @pytest.mark.parametrize("program", [*_PROGRAMS, *_PACKED, _WINDOW_CUT])
     def test_page_gather_reads_the_pool_in_place(self, tiny_lm, program, kv_dtype):
         """No value of a per-layer pool's shape exists in any program, and
         every gather of KV pages (or their scales) takes a whole pool as its
         operand: ``pool[i][tables]`` would copy layer ``i``'s whole pool
         once per K and V per layer before gathering from the copy."""
+        if program == _WINDOW_CUT:
+            cfg, model, params = tiny_lm
+            tiny_lm = (dataclasses.replace(cfg, attention_window=8), model, params)
         engine = _spec_engine(tiny_lm, base_cfg=dataclasses.replace(ENGINE_CFG, kv_dtype=kv_dtype))
         jitted, args = self._program(engine, program)
+        if program == _WINDOW_CUT:  # 8 positions lie in at most 3 blocks of 4, of the 8 a sequence may hold
+            assert engine._fwd.decode_window == 8 and args[0].shape == (ENGINE_CFG.max_slots, 3)
         pools = {buf.shape for buf in engine._kv}
         assert len(pools) == (2 if kv_dtype else 1)
         layer_slices = {shape[1:] for shape in pools}
@@ -2012,3 +2021,259 @@ class TestLiveShapes:
         assert req.state is RequestState.FINISHED and steps >= 1
         assert snap["serve_gather_blocks"] > snap["serve_live_blocks"] > 1
         assert 1 + 3 * steps <= snap["serve_gather_blocks"] <= 1 + 3 * 2 * steps
+
+
+# ---- the decode table from the window's first block (PR 35) ----------------
+# A window of 16 positions over blocks of 4 lies in 4 or 5 blocks of the 32 a
+# sequence may hold: the decode ladder is built on 5 (1, 2, 4, 5), the prefill
+# chunk's on 32 (4, 8, 16, 32).
+WINDOW = 16
+WINDOW_CFG = EngineConfig(
+    max_slots=4, block_size=4, num_blocks=128, max_blocks_per_seq=32,
+    prefill_chunk=8, max_queue=16,
+)
+WINDOW_CAP = -(-WINDOW // WINDOW_CFG.block_size) + 1
+WINDOW_PROMPTS = (37, 21, 5)  # two past the window at admission, one that crosses it while it decodes
+WINDOW_NEW = (60, 45, 40)
+
+
+@pytest.fixture(scope="module")
+def windowed_lm():
+    """Grouped KV heads and a sliding window, as the served model has."""
+    cfg = dataclasses.replace(TransformerConfig.tiny(), num_kv_heads=2, attention_window=WINDOW)
+    model = TransformerLM(config=cfg, dtype=jnp.float32)
+    params = model.init(jax.random.key(35), jnp.zeros((1, 8), jnp.int32))["params"]
+    return cfg, model, params
+
+
+def _first_blocks(lengths):
+    """The formula spelled out, apart from the engine's own function."""
+    return [max(int(n) - WINDOW, 0) // WINDOW_CFG.block_size for n in lengths]
+
+
+@pytest.fixture(scope="module")
+def window_run(windowed_lm):
+    """Three requests through a warmed engine whose window binds, the last
+    submitted once the first two decode. Records every decode launch (the
+    span's labels, the table's shape, the rows' lengths, the counters'
+    rise) and the logits of every request's last prefill chunk."""
+    from deeplearning_mpi_tpu.serving import engine as engine_module
+
+    cfg, model, params = windowed_lm
+    registry = MetricsRegistry()
+    engine = ServingEngine(cfg, params, WINDOW_CFG, dtype=jnp.float32, registry=registry)
+    engine.warmup()
+    warmed = registry.snapshot()["serve_compile_total"]
+    warm = (engine._decode_fn, engine._prefill_fn)
+    launches, first_logits = [], {}
+
+    def decode_fn(params, kv, tables, lengths, tokens, active):
+        launches[-1].update(shape=tuple(tables.shape), lengths=np.asarray(lengths)[np.asarray(active)])
+        return warm[0](params, kv, tables, lengths, tokens, active)
+
+    def prefill_fn(params, kv, table, tokens, start, n_valid):
+        kv, logits = warm[1](params, kv, table, tokens, start, n_valid)
+        first_logits[int(start) + int(n_valid)] = np.asarray(logits)  # the last chunk's overwrites: keyed by prompt length
+        return kv, logits
+
+    def recording_span(name, **labels):
+        if name == "serve/decode_launch":
+            launches.append({"labels": labels, "skipped_before": registry.snapshot()["serve_window_skipped_blocks"]})
+        return real_span(name, **labels)
+
+    engine._decode_fn, engine._prefill_fn = decode_fn, prefill_fn
+    real_span, engine_module.span = engine_module.span, recording_span
+    try:
+        rng = np.random.default_rng(35)
+        prompts = [rng.integers(1, 255, size=n).astype(np.int32) for n in WINDOW_PROMPTS]
+        reqs = [engine.submit(p, n) for p, n in zip(prompts[:2], WINDOW_NEW)]
+        while not all(r.state is RequestState.DECODE for r in reqs):
+            engine.step()
+        reqs.append(engine.submit(prompts[2], WINDOW_NEW[2]))
+        engine.run_until_idle()
+    finally:
+        engine_module.span = real_span
+    snapshot = registry.snapshot()
+    for launch, after in zip(launches, [*(l["skipped_before"] for l in launches[1:]), snapshot["serve_window_skipped_blocks"]]):
+        launch["skipped_counted"] = after - launch["skipped_before"]
+    return {
+        "engine": engine, "reqs": reqs, "prompts": prompts, "launches": launches, "first_logits": first_logits,
+        "warmed": warmed, "warm": warm, "snapshot": snapshot,
+    }
+
+
+@pytest.fixture(scope="module")
+def window_engine(windowed_lm):
+    """An engine whose window binds and the same program over whole tables
+    (what the draft's propose loop and the parent's decode step run)."""
+    from deeplearning_mpi_tpu.serving.engine import PagedForward
+
+    cfg, _, params = windowed_lm
+    engine = ServingEngine(cfg, params, WINDOW_CFG, dtype=jnp.float32)
+    whole = jax.jit(PagedForward(cfg, WINDOW_CFG, jnp.float32).decode_step)
+    return engine, whole
+
+
+class TestWindowedDecode:
+    def test_streams_are_offline_greedy_of_the_windowed_model(self, window_run, windowed_lm):
+        """The first tier-1 hold on the engine's window at all: chunked
+        prefill (chunks of 8 under a window of 16) and decode from the
+        window's first block, token for token ``generate``'s."""
+        _, model, params = windowed_lm
+        for req, prompt, new in zip(window_run["reqs"], window_run["prompts"], WINDOW_NEW):
+            assert req.state is RequestState.FINISHED and req.length == len(prompt) + new > 2 * WINDOW
+            assert req.generated == _offline_greedy(model, params, prompt, new), req.rid
+
+    def test_served_tokens_and_first_logits_are_the_uncached_forward_s(self, window_run, windowed_lm):
+        """Against ``TransformerLM``'s plain forward over the whole stream
+        (no cache, the window as ``dense_attention``'s mask): every served
+        token is its argmax at that position, and the last prefill chunk's
+        logits are its logits at the prompt's last position."""
+        _, model, params = windowed_lm
+        for req, prompt in zip(window_run["reqs"], window_run["prompts"]):
+            ids = np.concatenate([prompt, np.asarray(req.generated, np.int32)])
+            logits = np.asarray(model.apply({"params": params}, jnp.asarray(ids)[None]))[0]
+            assert np.argmax(logits[len(prompt) - 1:-1], axis=-1).tolist() == req.generated
+            np.testing.assert_allclose(window_run["first_logits"][len(prompt)], logits[len(prompt) - 1], rtol=1e-4, atol=1e-4)
+
+    def test_width_and_skipped_blocks_follow_the_window(self, window_run):
+        """A launch's table is as wide as the widest row's blocks from its
+        window's first on, never wider than the window's cap once every row
+        is past it; ``skipped`` and ``serve_window_skipped_blocks`` are the
+        sum of the rows' first blocks, ``serve_live_blocks`` what is left."""
+        run, BS = window_run, WINDOW_CFG.block_size
+        engine = run["engine"]
+        assert engine._fwd.decode_window == WINDOW
+        assert engine._widths == (4, 8, 16, 32)
+        assert engine._decode_shapes == ((1, 4), (1, 5), (2, 4), (2, 5), (4, 1), (4, 2), (4, 4), (4, 5))
+        past = [l for l in run["launches"] if l["lengths"].min() > WINDOW]
+        assert len(past) > 40 and any(len(l["lengths"]) == 3 for l in past)
+        reach = 0
+        for launch in run["launches"]:
+            lengths, labels = launch["lengths"], launch["labels"]
+            first = _first_blocks(lengths)
+            blocks = [-(-int(n) // BS) - f for n, f in zip(lengths, first)]
+            assert launch["shape"] == (labels["table_rows"], labels["width"]) == engine._decode_shape(len(lengths), max(blocks))
+            assert labels["skipped"] == launch["skipped_counted"] == sum(first)
+            assert labels["width"] <= WINDOW_CAP or lengths.min() <= WINDOW
+            reach += sum(blocks)
+        assert all(l["labels"]["width"] <= WINDOW_CAP for l in past)
+        assert max(l["labels"]["skipped"] for l in past) > 3 * WINDOW_CAP  # far more left out than handed over
+        prefill_blocks = sum(-(-n // BS) for p in WINDOW_PROMPTS for n in range(WINDOW_CFG.prefill_chunk, p + WINDOW_CFG.prefill_chunk, WINDOW_CFG.prefill_chunk))
+        assert run["snapshot"]["serve_live_blocks"] <= reach + prefill_blocks
+        assert run["snapshot"]["serve_window_skipped_blocks"] == sum(l["labels"]["skipped"] for l in run["launches"])
+
+    def test_no_compile_while_rows_cross_the_window(self, window_run):
+        run = window_run
+        engine = run["engine"]
+        crossing = [l for l in run["launches"] if l["lengths"].min() <= WINDOW < l["lengths"].max()]
+        assert crossing and {l["shape"][1] for l in run["launches"]} >= {4, 5}
+        assert run["warmed"] == len(engine._decode_shapes) + len(engine._widths)
+        assert run["snapshot"]["serve_compile_total"] == run["warmed"]
+        assert [fn.fallback_calls for fn in run["warm"]] == [0, 0]
+
+    @pytest.mark.parametrize("past", [-1, 0, WINDOW_CFG.block_size - 1, WINDOW_CFG.block_size, WINDOW_CFG.block_size + 1])
+    def test_host_and_device_agree_on_the_first_block(self, window_engine, past):
+        """At ``length - window`` = ``past``: the host's ints, numpy arrays
+        and the traced program give one first block, and the step over the
+        table cut there returns the token, and writes the page, of the step
+        over the whole table."""
+        from deeplearning_mpi_tpu.serving.engine import window_first_block
+
+        engine, whole = window_engine
+        e, BS = engine.engine, WINDOW_CFG.block_size
+        length = WINDOW + past
+        first = window_first_block(length, WINDOW, BS)
+        assert first == _first_blocks([length])[0] == (0, 0, 0, 1, 1)[(-1, 0, BS - 1, BS, BS + 1).index(past)]
+        lengths = np.asarray([length, 1, 3 * WINDOW + past], np.int32)
+        on_device = jax.jit(lambda n: window_first_block(n, WINDOW, BS))(jnp.asarray(lengths))
+        assert on_device.dtype == jnp.int32
+        assert np.asarray(on_device).tolist() == window_first_block(lengths, WINDOW, BS).tolist() == _first_blocks(lengths)
+        prompt = np.random.default_rng(length).integers(1, 255, size=length - 1).astype(np.int32)
+        held = [9, 3, 14, 6, 11, 2, 8][:-(-length // BS)]  # out of order, none the scratch block
+        table = np.zeros((1, e.max_blocks_per_seq), np.int32)
+        table[0, :len(held)] = held
+        kv = _prefilled(engine, [prompt], [table[0]])
+        rest = tuple(jnp.asarray(a) for a in (np.asarray([length], np.int32), np.asarray([23], np.int32), np.asarray([True])))
+        cut = np.zeros(engine._decode_shape(1, len(held) - first), np.int32)
+        cut[0, :len(held) - first] = held[first:]
+        assert cut.shape[1] <= WINDOW_CAP < table.shape[1]
+        kv_whole, tok_whole, _ = whole(engine.params, _own(kv), jnp.asarray(table), *rest)
+        kv_cut, tok_cut, _ = engine._decode_jit(engine.params, _own(kv), jnp.asarray(cut), *rest)
+        assert int(tok_cut[0]) == int(tok_whole[0])
+        for a, b, before in zip(_pages(kv_cut), _pages(kv_whole), _pages(kv)):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+            assert (a != before).any()  # the step did write
+
+    @pytest.mark.parametrize("case", ["max_seq_len_within_the_window", "no_window"])
+    def test_a_window_that_cannot_bind_leaves_the_program_and_the_ladder_alone(self, windowed_lm, case):
+        """Where no sequence can outgrow the window, or the model has none,
+        ``decode_step`` traces to the text of the program over whole tables
+        (no first-block arithmetic in it) and the shapes are those of the two
+        ceilings alone."""
+        from deeplearning_mpi_tpu.serving.engine import PagedForward, _table_shapes
+
+        cfg, _, params = windowed_lm
+        if case == "no_window":
+            cfg, ecfg = dataclasses.replace(cfg, attention_window=0), WINDOW_CFG
+        else:
+            ecfg = dataclasses.replace(WINDOW_CFG, max_blocks_per_seq=WINDOW // WINDOW_CFG.block_size)
+            assert ecfg.max_seq_len == cfg.attention_window
+        engine = ServingEngine(cfg, params, ecfg, dtype=jnp.float32)
+        assert engine._fwd.decode_window == 0
+        assert (engine._widths, engine._decode_shapes) == _table_shapes(ecfg.max_slots, ecfg.max_blocks_per_seq)
+        assert engine._decode_shapes[-1] == (ecfg.max_slots, ecfg.max_blocks_per_seq)
+        rows, width = engine._decode_shapes[0]
+        args = (
+            engine.params, engine._kv, jnp.zeros((rows, width), jnp.int32),
+            jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), bool),
+        )
+        whole = PagedForward(cfg, ecfg, jnp.float32)
+        text = str(jax.make_jaxpr(engine._fwd.decode_step)(*args))
+        assert text == str(jax.make_jaxpr(whole.decode_step)(*args))
+        # the control: a window that binds does change the trace
+        binding = ServingEngine(windowed_lm[0], params, WINDOW_CFG, dtype=jnp.float32)
+        assert str(jax.make_jaxpr(binding._fwd.decode_step)(*args)) != str(
+            jax.make_jaxpr(PagedForward(windowed_lm[0], WINDOW_CFG, jnp.float32).decode_step)(*args)
+        )
+
+    def test_the_long_session_cell_s_ladder(self, windowed_lm):
+        """At the benchmark's long-session engine (32 slots, 1,152 blocks of
+        16 a sequence, a window of 4,096): the decode step is warmed up to
+        257 blocks a row, in no more pairs than up to 1,152; the prefill
+        chunk's widths stay."""
+        from deeplearning_mpi_tpu.serving.engine import _table_shapes, window_blocks
+
+        assert window_blocks(4096, 16) == 257 and window_blocks(WINDOW, WINDOW_CFG.block_size) == WINDOW_CAP
+        widths, pairs = _table_shapes(32, 1152, 257)
+        assert widths == (256, 512, 1024, 1152) == _table_shapes(32, 1152)[0]
+        assert pairs == ((8, 256), (8, 257), (16, 256), (16, 257), (32, 64), (32, 128), (32, 256), (32, 257))
+        assert len(pairs) == len(_table_shapes(32, 1152)[1])
+        cfg, _, params = windowed_lm
+        engine = ServingEngine(
+            dataclasses.replace(cfg, attention_window=4096), params,
+            EngineConfig(max_slots=32, block_size=16, num_blocks=1154, max_blocks_per_seq=1152), dtype=jnp.float32,
+        )
+        assert (engine._widths, engine._decode_shapes) == (widths, pairs)
+        assert engine._decode_shape(8, 257) == (8, 257) and engine._decode_shape(8, 256) == (8, 256)
+
+    def test_the_draft_keeps_whole_tables_and_speculation_keeps_parity(self, windowed_lm):
+        """The verify step and the draft's propose loop read tables from
+        block 0; the plain decode step a suspended speculation falls back to
+        reads them from the window's first block. Switching between the two
+        mid-stream leaves the tokens offline greedy's."""
+        cfg, model, params = windowed_lm
+        engine = _spec_engine(windowed_lm, spec_k=2, base_cfg=WINDOW_CFG)
+        assert engine._fwd.decode_window == WINDOW and engine._spec._fwd.decode_window == 0
+        rng = np.random.default_rng(53)
+        prompts = [rng.integers(1, 255, size=n).astype(np.int32) for n in (19, 7)]
+        reqs = [engine.submit(p, 36) for p in prompts]
+        for stage in (0, 2, 0, 2):
+            engine.set_brownout(stage)
+            for _ in range(9):
+                engine.step()
+        engine.set_brownout(0)
+        engine.run_until_idle()
+        for req, prompt in zip(reqs, prompts):
+            assert req.state is RequestState.FINISHED
+            assert req.generated == _offline_greedy(model, params, prompt, 36), req.rid
