@@ -159,7 +159,8 @@ class WarmProgram:
     in a correctly-warmed engine).
 
     A function warmed at several static shapes hands in a dict of programs
-    keyed by the shape of its ``shape_arg``-th argument: the call picks its
+    keyed by the shape of its ``shape_arg``-th argument (of the first array,
+    where that argument is a tuple of arrays): the call picks its
     executable by that shape, so no listed shape pays a raised and caught
     exception on its way to the program."""
 
@@ -178,7 +179,10 @@ class WarmProgram:
     def __call__(self, *args: Any) -> Any:
         program = self.program
         if self.shape_arg is not None:
-            program = program.get(args[self.shape_arg].shape)
+            key = args[self.shape_arg]
+            if isinstance(key, tuple):  # several arrays: the first one's shape
+                key = key[0]
+            program = program.get(key.shape)
         if program is not None:
             try:
                 return program.compiled(*args)

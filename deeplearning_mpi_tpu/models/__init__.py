@@ -33,6 +33,7 @@ from deeplearning_mpi_tpu.models.moe import (  # noqa: F401
     collect_dropped_fraction,
 )
 from deeplearning_mpi_tpu.models.transformer import (  # noqa: F401
+    LayerSpec,
     TransformerConfig,
     TransformerLM,
     draft_config,

@@ -76,6 +76,12 @@ class StageBlocks(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array) -> jax.Array:
         cfg = self.config
+        if cfg.layers:
+            raise NotImplementedError(
+                "a pipelined LM over layers of several kinds "
+                "(TransformerConfig.layers): a stage does not know which of "
+                "the model's layers it holds"
+            )
         block_cls = _remat_block(self.remat)
         for i in range(self.num_blocks):
             x = block_cls(

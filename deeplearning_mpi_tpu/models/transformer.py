@@ -24,11 +24,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Callable
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from deeplearning_mpi_tpu.ops.attention import (
@@ -71,14 +73,43 @@ def attention_fn_accepts_gqa(fn: AttentionFn | None) -> bool:
     return False
 
 
+def yarn_inv_freq(
+    head_dim: int, base: float, factor: float, original_max: int,
+    beta_fast: float, beta_slow: float,
+) -> np.ndarray:
+    """YaRN's rotary frequencies, ``[head_dim // 2]`` float32, as
+    ``transformers``' ``_compute_yarn_parameters`` has them: dimension ``j``
+    turns at ``f_j = base^(-2j/d)``; dimensions that make more than
+    ``beta_fast`` turns over the original context keep ``f_j``
+    (extrapolated), those that make fewer than ``beta_slow`` get ``f_j /
+    factor`` (interpolated), a linear ramp between. Host numbers: a layer's
+    frequencies are constants of its program."""
+    half = head_dim // 2
+    f = base ** (-np.arange(half, dtype=np.float64) / half)
+
+    def corr(turns: float) -> float:  # the dimension that makes ``turns`` turns
+        return head_dim * math.log(original_max / (2 * math.pi * turns)) / (2 * math.log(base))
+
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return ((f / factor) * ramp + f * (1.0 - ramp)).astype(np.float32)
+
+
 def apply_rope(
     x: jax.Array,
     positions: jax.Array,
     *,
     base: float = 10000.0,
     layout: str = "bshd",
+    inv_freq: Any = None,
+    factor: float = 1.0,
 ) -> jax.Array:
     """Rotary position embedding over ``[B, S, H, D]`` (D even).
+
+    ``inv_freq`` (``[D // 2]``) replaces the frequencies ``base`` gives, and
+    ``factor`` scales cos and sin alike (so q.k grows by its square): the
+    two things a frequency-scaled RoPE (:func:`yarn_inv_freq`) changes.
 
     Angles and cos/sin are computed in f32 — bf16 *phase* accumulation
     drifts at long context — but the rotation arithmetic runs in ``x``'s
@@ -93,14 +124,16 @@ def apply_rope(
     no layout copy either way.
     """
     half = x.shape[-1] // 2
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions[:, :, None].astype(jnp.float32) * freqs  # [B, S, half]
-    if layout == "bhsd":
-        cos = jnp.cos(angles)[:, None, :, :].astype(x.dtype)  # [B, 1, S, half]
-        sin = jnp.sin(angles)[:, None, :, :].astype(x.dtype)
+    if inv_freq is None:
+        freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     else:
-        cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)  # [B, S, 1, half]
-        sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
+        freqs = jnp.asarray(inv_freq, jnp.float32)
+    angles = positions[:, :, None].astype(jnp.float32) * freqs  # [B, S, half]
+    # [B, 1, S, half] or [B, S, 1, half]: the head axis broadcasts
+    heads = (slice(None), None) if layout == "bhsd" else (slice(None), slice(None), None)
+    scaled = (lambda t: t * factor) if factor != 1.0 else (lambda t: t)
+    cos = scaled(jnp.cos(angles))[heads].astype(x.dtype)
+    sin = scaled(jnp.sin(angles))[heads].astype(x.dtype)
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
@@ -263,8 +296,11 @@ class Attention(nn.Module):
     #: schedule to the shards any query's window reaches (rotation
     #: skipping, ``parallel.ring_attention.windowed_rotations``).
     window: int = 0
-    #: rotary base (``TransformerConfig.rope_theta``)
+    #: rotary base (``TransformerConfig.rope_theta``, or this layer's own:
+    #: :class:`LayerSpec`)
     rope_theta: float = 10_000.0
+    #: YaRN frequency scaling of this layer's RoPE (:attr:`LayerSpec.yarn`)
+    rope_yarn: tuple[float, int, float, float, float] | None = None
     #: RMSNorm over each head's dims of q and k, before RoPE
     qk_norm: bool = False
     #: learned sparse attention: each query attends the ``topk`` keys its
@@ -304,12 +340,13 @@ class Attention(nn.Module):
                 "kernel-native layout is a training-path optimization; "
                 "quantization is inference-only)"
             )
+        rope_kw = rope_kwargs(self.head_dim, self.rope_theta, self.rope_yarn)
         if not self.decode and attention_fn_layout(self.attention_fn) == "bhsd":
             proj = lambda heads, name: _ProjToBHSD(  # noqa: E731
                 heads, self.head_dim, self.dtype, name=name
             )
             rope = functools.partial(
-                apply_rope, positions=positions, base=self.rope_theta, layout="bhsd"
+                apply_rope, positions=positions, layout="bhsd", **rope_kw
             )
             q = rope(proj(self.num_heads, "q_proj")(x))
             k = rope(proj(kv_heads, "k_proj")(x))
@@ -329,8 +366,8 @@ class Attention(nn.Module):
         if self.qk_norm:
             q = RMSNorm(name="q_norm")(q)
             k = RMSNorm(name="k_norm")(k)
-        q = apply_rope(q, positions, base=self.rope_theta)
-        k = apply_rope(k, positions, base=self.rope_theta)
+        q = apply_rope(q, positions, **rope_kw)
+        k = apply_rope(k, positions, **rope_kw)
         if self.topk:
             q_idx, w_idx, k_idx = Indexer(
                 self.indexer_heads, self.indexer_head_dim, self.rope_theta,
@@ -461,6 +498,7 @@ class Block(nn.Module):
     window: int = 0
     #: see the fields of the same names on :class:`Attention`
     rope_theta: float = 10_000.0
+    rope_yarn: tuple[float, int, float, float, float] | None = None
     qk_norm: bool = False
     topk: int = 0
     indexer_heads: int = 0
@@ -473,6 +511,7 @@ class Block(nn.Module):
             attention_fn=self.attention_fn, decode=self.decode,
             num_kv_heads=self.num_kv_heads, quantized=self.quantized,
             window=self.window, rope_theta=self.rope_theta,
+            rope_yarn=self.rope_yarn,
             qk_norm=self.qk_norm, topk=self.topk,
             indexer_heads=self.indexer_heads,
             indexer_head_dim=self.indexer_head_dim, name="attn",
@@ -487,6 +526,37 @@ class Block(nn.Module):
         else:
             mlp = (self.mlp_cls or SwiGLU)(self.d_ff, self.dtype, name="mlp")
         return x + mlp(RMSNorm(name="mlp_norm")(x))
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """What may differ from layer to layer of one model: the attention's
+    reach and its RoPE (``TransformerConfig.layers``)."""
+
+    #: sliding window of this layer (0 = every earlier key)
+    window: int = 0
+    #: rotary base of this layer
+    rope_theta: float = 10_000.0
+    #: YaRN scaling of this layer's RoPE: ``(factor, original_max,
+    #: beta_fast, beta_slow, attention_factor)``; None = plain RoPE
+    yarn: tuple[float, int, float, float, float] | None = None
+
+
+def rope_kwargs(
+    head_dim: int, rope_theta: float,
+    yarn: tuple[float, int, float, float, float] | None,
+) -> dict[str, Any]:
+    """:func:`apply_rope`'s keywords for one layer: its base, or under YaRN
+    its scaled frequencies and the attention factor."""
+    if yarn is None:
+        return {"base": rope_theta}
+    factor, original_max, beta_fast, beta_slow, attention_factor = yarn
+    return {
+        "inv_freq": yarn_inv_freq(
+            head_dim, rope_theta, factor, original_max, beta_fast, beta_slow
+        ),
+        "factor": float(attention_factor),
+    }
 
 
 @dataclasses.dataclass(frozen=True)
@@ -544,6 +614,12 @@ class TransformerConfig:
     #: prefill, and KV-cached decode all mask with it, so a window-trained
     #: checkpoint decodes with the same receptive field it learned.
     attention_window: int = 0
+    #: layers of several kinds in one model: one :class:`LayerSpec` a layer
+    #: (its window, its RoPE base and scaling). Empty = every layer as the
+    #: global ``attention_window`` and ``rope_theta`` say, which then must
+    #: not be set beside it. The uncached forward, the flax KV cache and the
+    #: serving engine all read a layer's own through :meth:`layer_spec`.
+    layers: tuple[LayerSpec, ...] = ()
     #: embedding lookup as a one-hot matmul instead of a gather. Forward
     #: values are identical (rows of exact 0/1 select the same f32 bits),
     #: but the *gradient* becomes a dot-general instead of a scatter-add —
@@ -554,6 +630,32 @@ class TransformerConfig:
     #: a dot-general keeps per-rank partial sums + all-reduce — the same
     #: association the shard_map path computes (parallel/zero.py).
     onehot_embed: bool = False
+
+    def __post_init__(self) -> None:
+        if not self.layers:
+            return
+        if len(self.layers) != self.num_layers:
+            raise ValueError(
+                f"layers describes {len(self.layers)} layers, num_layers is "
+                f"{self.num_layers}"
+            )
+        if self.attention_window:
+            raise ValueError(
+                "attention_window beside layers: a layer's window is its "
+                "LayerSpec's"
+            )
+        if self.attention_topk:
+            raise NotImplementedError(
+                "attention_topk > 0 with layers: a selecting layer among "
+                "layers of several kinds is not implemented"
+            )
+
+    def layer_spec(self, i: int) -> LayerSpec:
+        """Layer ``i``'s window and RoPE: its own where ``layers`` is
+        given, else the model's global ones."""
+        if self.layers:
+            return self.layers[i]
+        return LayerSpec(self.attention_window, self.rope_theta)
 
     @property
     def mlp_width(self) -> int:
@@ -700,12 +802,14 @@ class TransformerLM(nn.Module):
             mlp_cls = mlp_cls_from_config(cfg)
         block_cls = _remat_block(self.remat)
         for i in range(cfg.num_layers):
+            spec = cfg.layer_spec(i)
             x = block_cls(
                 cfg.num_heads, cfg.head_dim, cfg.mlp_width, self.dtype,
                 attention_fn=self.attention_fn, mlp_cls=mlp_cls,
                 decode=self.decode, num_kv_heads=cfg.num_kv_heads,
-                quantized=self.quantized, window=cfg.attention_window,
-                rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm,
+                quantized=self.quantized, window=spec.window,
+                rope_theta=spec.rope_theta, rope_yarn=spec.yarn,
+                qk_norm=cfg.qk_norm,
                 topk=cfg.attention_topk, indexer_heads=cfg.indexer_heads,
                 indexer_head_dim=cfg.indexer_head_dim,
                 name=f"layer_{i}",

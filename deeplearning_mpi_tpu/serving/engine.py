@@ -53,7 +53,11 @@ the offline path is pinned by ``tests/test_serving.py`` (greedy outputs
 identical per request, speculative and plain).
 
 Greedy-only. Two layer kinds beyond the dense block are written once, in
-``PagedForward._layers``, and chosen by the model's config alone:
+``PagedForward._layers``, and chosen by the model's config alone (a third
+thing the config alone decides: a model whose layers differ in their sliding
+window, ``TransformerConfig.layers``, keeps its full and its window layers in
+two GROUPS, each with its own pools, allocator and block table:
+:func:`layer_groups`):
 
 - learned sparse attention (``attention_topk > 0``): a third pool holds one
   indexer key a position beside K and V; a query scores the table's indexer
@@ -76,7 +80,7 @@ import dataclasses
 import functools
 import math
 import time
-from typing import Any, Callable, Iterable, Optional
+from typing import Any, Callable, Iterable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +90,7 @@ from deeplearning_mpi_tpu.runtime.compat import buffer_donation_supported
 from deeplearning_mpi_tpu.models.transformer import (
     TransformerConfig,
     apply_rope,
+    rope_kwargs,
 )
 from deeplearning_mpi_tpu.ops.attention import (
     NEG_INF,
@@ -115,14 +120,82 @@ from deeplearning_mpi_tpu.serving.scheduler import (
 )
 from deeplearning_mpi_tpu.telemetry.trace import annotate, span
 
-__all__ = ["EngineConfig", "KVBuffers", "PagedForward", "ServingEngine"]
+__all__ = [
+    "EngineConfig", "KVBuffers", "LayerGroup", "PagedForward", "ServingEngine",
+    "layer_groups",
+]
 
-#: Queries a selecting layer scores and selects for at a time. A prefill
-#: chunk's queries go through in tiles of this many: per tile the float32
+#: Queries that attend a long table at a time: a selecting layer's, and the
+#: full layers' of a model with window and full layers. A prefill chunk's
+#: queries go through in tiles of this many: per tile the float32
 #: indexer products are ``[tile, Hi, L]`` and the attention scores
 #: ``[H, tile, L]`` (at 16 and 32 heads and L = 65,536: 0.27 GB and 0.54
 #: GB), where the whole chunk at once would not fit beside the weights.
 SELECT_TILE = 64
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerGroup:
+    """Layers whose cached state is of one kind: they share ``(k, v)`` pools
+    of ``[layers in group, blocks of group, block_size, Hkv, D]``, one
+    allocator, one block list a request and one table on the device."""
+
+    #: the layers' sliding window (0: every earlier key, whole tables)
+    window: int
+    #: the model's layer numbers in this group, in order: layer
+    #: ``layers[j]`` lives at index ``j`` of the group's pools
+    layers: tuple[int, ...]
+
+
+def layer_groups(config: TransformerConfig) -> tuple[LayerGroup, ...]:
+    """The model's layers by kind of cached state. One window for every
+    layer (or none) is ONE group, the engine of a one-kind model. Full and
+    window layers side by side are two: the full group first, then the
+    window group, whose tables start at :func:`window_first_block` and whose
+    blocks behind it go back to its pool."""
+    windows = [config.layer_spec(i).window for i in range(config.num_layers)]
+    kinds = sorted(set(windows))
+    if len(kinds) > 2 or (len(kinds) == 2 and kinds[0] != 0):
+        raise NotImplementedError(
+            f"layers of sliding windows {kinds}: the serving engine keeps "
+            "one group of full layers and one of window layers (window "
+            "groups of several sizes are not implemented)"
+        )
+    return tuple(
+        LayerGroup(w, tuple(i for i, lw in enumerate(windows) if lw == w))
+        for w in kinds
+    )
+
+
+def _by_query_tiles(
+    attend_tile: Callable[[tuple[jax.Array, ...]], jax.Array],
+    per_query: tuple[jax.Array, ...],
+) -> jax.Array:
+    """``attend_tile`` over tiles of :data:`SELECT_TILE` queries, one tile
+    at a time: ``per_query`` are ``[rows, seq, ...]`` arrays, the first the
+    queries ``[rows, seq, H, D]``, whose shape the result has."""
+    q = per_query[0]
+    rows, seq = q.shape[:2]
+    width = math.gcd(seq, SELECT_TILE)
+    if width == seq:
+        return attend_tile(per_query)
+    # [rows, seq, ...] -> [tiles, rows, width, ...], one tile at a time
+    split = lambda a: jnp.moveaxis(  # noqa: E731
+        a.reshape((rows, seq // width, width) + a.shape[2:]), 1, 0
+    )
+    out = jax.lax.map(attend_tile, tuple(map(split, per_query)))
+    return jnp.moveaxis(out, 0, 1).reshape(q.shape)
+
+
+class GroupView(NamedTuple):
+    """What one program hands :meth:`PagedForward._layers` for one group of
+    layers: where this step's K/V rows go, the group's table, and the
+    attention over its gathered pages."""
+
+    bid: jax.Array     # block id each new K/V row is written to
+    off: jax.Array     # offset in that block; same shape as ``bid``
+    tables: jax.Array  # [rows, MB] block ids, or [MB] for one row
+    attend: Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
 
 
 def window_first_block(length: Any, window: int, block_size: int) -> Any:
@@ -191,8 +264,14 @@ class EngineConfig:
     max_slots: int = 4
     #: token positions per KV block
     block_size: int = 16
-    #: pool blocks per layer, scratch block included
+    #: pool blocks per layer, scratch block included (of a model with full
+    #: and window layers: the full group's)
     num_blocks: int = 64
+    #: the window group's pool blocks per layer, scratch block included: a
+    #: model with full and window layers side by side (:func:`layer_groups`)
+    #: keeps the window layers' K/V in a pool of their own, sized for the
+    #: window and not for the sequence. 0 for every other model.
+    window_num_blocks: int = 0
     #: block-table width = admission ceiling: a sequence may span at most
     #: ``max_blocks_per_seq * block_size`` positions (prompt + generation)
     max_blocks_per_seq: int = 8
@@ -253,7 +332,9 @@ class KVBuffers:
     threads through its jitted steps — ``(k, v)`` for float storage,
     ``(k, v, k_scale, v_scale)`` for quantized storage, ``(k, v, k_index)``
     for a model with learned sparse attention (see
-    :func:`~deeplearning_mpi_tpu.serving.kv_pool.init_kv_buffers`).
+    :func:`~deeplearning_mpi_tpu.serving.kv_pool.init_kv_buffers`); for a
+    model with full and window layers one such tuple a group
+    (:func:`layer_groups`), ``((k, v), (k, v))``.
 
     The indirection exists for disaggregation: a prefill-only and a
     decode-only engine share ONE set of pools (handoff transfers block-
@@ -270,7 +351,7 @@ class KVBuffers:
 
     @property
     def nbytes(self) -> int:
-        return sum(int(b.nbytes) for b in self.bufs)
+        return sum(int(b.nbytes) for b in jax.tree.leaves(self.bufs))
 
 
 class PagedForward:
@@ -309,15 +390,33 @@ class PagedForward:
         self.engine = engine
         self.dtype = dtype
         self.kv_dtype = kv_dtype
+        #: the layers by kind of cached state (:func:`layer_groups`). One
+        #: group: every program takes ONE table and a flat ``kv`` tuple. Two
+        #: (``mixed``): a table a group, ``(full, window)``, and ``kv`` a
+        #: tuple of the groups' tuples.
+        self.groups = layer_groups(config)
+        self.mixed = len(self.groups) > 1
+        #: layer -> (its group, its index in the group's pools)
+        self._place = {
+            layer: (g, j)
+            for g, group in enumerate(self.groups)
+            for j, layer in enumerate(group.layers)
+        }
+        #: each layer's own RoPE (``apply_rope``'s keywords)
+        self._rope_kw = [
+            rope_kwargs(config.head_dim, spec.rope_theta, spec.yarn)
+            for spec in map(config.layer_spec, range(config.num_layers))
+        ]
         #: the sliding window, where the caller of :meth:`decode_step` hands
         #: it tables that start at :func:`window_first_block`
         #: (``window_cut``: the engine's own decode launch does, the draft's
-        #: propose loop hands whole tables) AND a sequence can outgrow the
-        #: window; else 0, and the decode program is the one of a model
-        #: without a window
+        #: propose loop hands whole tables; the window group of a mixed
+        #: model always) AND a sequence can outgrow the window; else 0, and
+        #: the decode program is the one of a model without a window
+        window = self.groups[-1].window
         self.decode_window = (
-            config.attention_window
-            if window_cut and 0 < config.attention_window < engine.max_seq_len
+            window
+            if (window_cut or self.mixed) and 0 < window < engine.max_seq_len
             else 0
         )
         self.quantized = kv_dtype is not None and jnp.issubdtype(
@@ -435,17 +534,18 @@ class PagedForward:
             )
 
     def _attn_proj(
-        self, lp: Any, x: jax.Array, pos: jax.Array
+        self, lp: Any, x: jax.Array, pos: jax.Array, i: int
     ) -> tuple[jax.Array, jax.Array, jax.Array, tuple[jax.Array, ...] | None]:
         """Pre-attention norm, Q/K/V projections (per-head RMSNorm where the
-        model has it) and RoPE of one layer; for a selecting model also the
-        indexer's projections (``models.transformer.Indexer`` numerics)
+        model has it) and RoPE of layer ``i`` (its own base and scaling); for
+        a selecting model also the indexer's projections
+        (``models.transformer.Indexer`` numerics)
         ``(qI [rows, seq, Hi, Di], w [rows, seq, Hi], kI [rows, seq, Di])``,
         else None."""
         cfg = self.config
         rows, seq = x.shape[0], x.shape[1]
         kv_heads = cfg.num_kv_heads or cfg.num_heads
-        rope = functools.partial(apply_rope, positions=pos, base=cfg.rope_theta)
+        rope = functools.partial(apply_rope, positions=pos, **self._rope_kw[i])
         with annotate("attn/qkv"):
             h = self._rmsnorm(x, lp["attn_norm"]["scale"])
             q = self._lin(h, lp["attn"]["q_proj"]["kernel"]).reshape(
@@ -567,32 +667,24 @@ class PagedForward:
             with annotate("attn/core"):
                 return attend_selected(q_t, k_sel, v_sel, kept)
 
-        width = math.gcd(seq, SELECT_TILE)
-        if width == seq:
-            return attend_tile((q, q_idx, w_idx, q_pos))
-        # [rows, seq, ...] -> [tiles, rows, width, ...], one tile at a time
-        split = lambda a: jnp.moveaxis(  # noqa: E731
-            a.reshape((rows, seq // width, width) + a.shape[2:]), 1, 0
-        )
-        out = jax.lax.map(attend_tile, tuple(map(split, (q, q_idx, w_idx, q_pos))))
-        return jnp.moveaxis(out, 0, 1).reshape(q.shape)
+        return _by_query_tiles(attend_tile, (q, q_idx, w_idx, q_pos))
 
     def _layers(
         self,
         params: Any,
-        kv: tuple[jax.Array, ...],
+        kv: tuple[Any, ...],
         x: jax.Array,       # [rows, seq, d] embedded tokens
         pos: jax.Array,     # [rows, seq] absolute positions (RoPE)
-        bid: jax.Array,     # block id each new K/V row is written to
-        off: jax.Array,     # offset in that block; same shape as ``bid``
-        tables: jax.Array,  # [rows, MB] block ids, or [MB] for one row
-        attend: Callable[[jax.Array, jax.Array, jax.Array], jax.Array],
-    ) -> tuple[tuple[jax.Array, ...], jax.Array, jax.Array]:
+        views: list[GroupView],  # one a group of layers
+    ) -> tuple[tuple[Any, ...], jax.Array, jax.Array]:
         """THE layer loop of all three programs: per layer, project, scatter
-        the new K/V rows through ``(bid, off)``, attend, output projection,
-        MLP; then the final norm. A program is its index math for
-        ``(pos, bid, off)``, its ``attend`` and its head; a layer kind is
-        written here once. Two kinds of attention:
+        the new K/V rows through its group's ``(bid, off)``, attend, output
+        projection, MLP; then the final norm. A program is its index math
+        for ``pos`` and each group's :class:`GroupView`, and its head; a
+        layer kind is written here once. A layer reads its group's pools at
+        its index in the group, through its group's table, with its group's
+        ``attend`` and its own RoPE; a one-kind model has one group and
+        ``kv`` is that group's tuple. Two kinds of attention:
 
         - every key (the default): gather the table's pages back into
           position order (the block table IS the logical->physical map, so
@@ -614,26 +706,29 @@ class PagedForward:
         empty for a dense model)."""
         cfg = self.config
         head = (cfg.num_kv_heads or cfg.num_heads, cfg.head_dim)
-        new = bid.shape + head  # this step's K/V rows, one per (bid, off)
-        span = tables.shape[-1] * self.engine.block_size
-        seq = (x.shape[0], span) + head
-        selecting = self.selecting and span > cfg.attention_topk
-        live = (bid != SCRATCH_BLOCK).reshape(x.shape[:2])
+        pools = list(kv) if self.mixed else [kv]
+        live = (views[0].bid != SCRATCH_BLOCK).reshape(x.shape[:2])
         touched = []
         for i in range(cfg.num_layers):
+            g, j = self._place[i]
+            bid, off, tables, attend = views[g]
+            new = bid.shape + head  # this step's K/V rows, one per (bid, off)
+            span = tables.shape[-1] * self.engine.block_size
+            seq = (x.shape[0], span) + head
+            selecting = self.selecting and span > cfg.attention_topk
             lp = params[f"layer_{i}"]
-            q, k, v, index = self._attn_proj(lp, x, pos)
-            kv = self._kv_scatter(
-                kv, i, bid, off, k.reshape(new), v.reshape(new),
+            q, k, v, index = self._attn_proj(lp, x, pos, i)
+            pools[g] = self._kv_scatter(
+                pools[g], j, bid, off, k.reshape(new), v.reshape(new),
                 index and index[2].reshape(bid.shape + index[2].shape[-1:]),
             )
             if selecting:
                 ctx = self._select_attend(
-                    kv, i, tables.reshape(x.shape[0], -1), q, *index[:2],
+                    pools[g], j, tables.reshape(x.shape[0], -1), q, *index[:2],
                     jnp.where(live, pos, -1),
                 )
             else:
-                k_seq, v_seq = self._kv_gather(kv, i, tables)
+                k_seq, v_seq = self._kv_gather(pools[g], j, tables)
                 k_seq, v_seq = k_seq.reshape(seq), v_seq.reshape(seq)
                 with annotate("attn/core"):
                     ctx = attend(q, k_seq, v_seq)
@@ -644,7 +739,7 @@ class PagedForward:
             else:
                 x = self._mlp(lp, x)
         return (
-            kv,
+            tuple(pools) if self.mixed else pools[0],
             self._rmsnorm(x, params["final_norm"]["scale"]),
             jnp.stack(touched) if touched else jnp.zeros((0,), jnp.int32),
         )
@@ -653,12 +748,12 @@ class PagedForward:
     def decode_step(
         self,
         params: Any,
-        kv: tuple[jax.Array, ...],  # pools (+ scales when quantized)
-        tables: jax.Array,   # [S, MB] int32 block ids (0-padded)
+        kv: tuple[Any, ...],  # pools (+ scales when quantized); a tuple a group
+        tables: Any,         # [S, MB] int32 block ids (0-padded); one a group
         lengths: jax.Array,  # [S] int32 known tokens (prompt + generated)
         tokens: jax.Array,   # [S] int32 token fed this step (position len-1)
         active: jax.Array,   # [S] bool
-    ) -> tuple[tuple[jax.Array, ...], jax.Array, jax.Array]:
+    ) -> tuple[tuple[Any, ...], jax.Array, jax.Array]:
         """One token for each of the table's ``S`` rows, and the experts
         each layer's rows touched (``[layers]`` int32, an output of its own
         that the step's one fetch brings with the tokens; ``[0]`` for a
@@ -673,53 +768,67 @@ class PagedForward:
         ``s`` that holds the first position its window can reach
         (:func:`window_first_block` of ``lengths[s]``), not its block 0: a
         row past the window hands over, gathers and attends at most
-        :func:`window_blocks` blocks however long it has grown."""
+        :func:`window_blocks` blocks however long it has grown. A model with
+        full and window layers takes ``tables = (full [S, MBf], window [S,
+        MBw])``: whole tables for the full group, tables from the window's
+        first block for the window group; ``S`` is one, the widths two."""
         # Host side effect at TRACE time only: one tick per compilation of
         # this program. A warmed engine calls the AOT executable directly
         # (never retraces), so "zero compiles on the first request" is an
         # assertable counter delta, not a timing heuristic.
         self._tick()
         BS = self.engine.block_size
-        # Both static sizes come from the TABLE, not the engine's ceilings:
-        # the host packs the rows that decode into this step's row bucket
-        # and cuts the table to its width bucket
-        # (ServingEngine._decode_shape), so the page gather and the
-        # attention over it cost O(rows x width) of what is live. One
-        # compile per distinct (S, MB).
-        S, MB = tables.shape
         x = self._embed(params, tokens)[:, None, :]  # [S, 1, d]
         pos = jnp.maximum(lengths - 1, 0)[:, None]  # [S, 1] absolute
         p = pos[:, 0]
-        # The new row's block and the query's index, in the TABLE's
-        # coordinates: absolute where the table starts at block 0, less the
-        # blocks the host left out where it starts at the window's first.
-        # RoPE stays absolute (K is stored rotated at its own position) and
-        # the attention mask is relative to the index, so nothing below
-        # knows the difference.
-        first = None
-        if self.decode_window:
-            first = window_first_block(lengths, self.decode_window, BS)
 
-        def in_table(x: jax.Array, unit: int) -> jax.Array:
-            return x if first is None else x - first * unit
+        def view(tables: jax.Array, window: int, cut: int) -> GroupView:
+            """One group's step: ``window`` masks, and under ``cut`` (the
+            window again) the table starts at the window's first block."""
+            # Both static sizes come from the TABLE, not the engine's
+            # ceilings: the host packs the rows that decode into this step's
+            # row bucket and cuts the table to its width bucket
+            # (ServingEngine._decode_shape), so the page gather and the
+            # attention over it cost O(rows x width) of what is live. One
+            # compile per distinct (S, MB).
+            S, MB = tables.shape
+            # The new row's block and the query's index, in the TABLE's
+            # coordinates: absolute where the table starts at block 0, less
+            # the blocks the host left out where it starts at the window's
+            # first. RoPE stays absolute (K is stored rotated at its own
+            # position) and the attention mask is relative to the index, so
+            # nothing below knows the difference.
+            first = None
+            if cut:
+                first = window_first_block(lengths, cut, BS)
 
-        # Inactive slots route their (garbage) writes to the scratch block.
-        bid = jnp.where(
-            active,
-            tables[jnp.arange(S), jnp.minimum(in_table(p // BS, 1), MB - 1)],
-            SCRATCH_BLOCK,
-        )
-        # Row b attends its own filled prefix 0..lengths[b]-1; negative
-        # marks the row inactive (zero output).
-        idx = jnp.where(active, in_table(lengths - 1, BS), -1)
-        window = self.config.attention_window or None
+            def in_table(x: jax.Array, unit: int) -> jax.Array:
+                return x if first is None else x - first * unit
 
-        def attend(q: jax.Array, k_seq: jax.Array, v_seq: jax.Array) -> jax.Array:
-            return batched_decode_attention(q, k_seq, v_seq, idx, window=window)
+            # Inactive slots route their (garbage) writes to the scratch block.
+            bid = jnp.where(
+                active,
+                tables[jnp.arange(S), jnp.minimum(in_table(p // BS, 1), MB - 1)],
+                SCRATCH_BLOCK,
+            )
+            # Row b attends its own filled prefix 0..lengths[b]-1; negative
+            # marks the row inactive (zero output).
+            idx = jnp.where(active, in_table(lengths - 1, BS), -1)
 
-        kv, x, touched = self._layers(
-            params, kv, x, pos, bid, p % BS, tables, attend
-        )
+            def attend(q: jax.Array, k_seq: jax.Array, v_seq: jax.Array) -> jax.Array:
+                return batched_decode_attention(
+                    q, k_seq, v_seq, idx, window=window or None
+                )
+
+            return GroupView(bid, p % BS, tables, attend)
+
+        # decode_window is the window group's (or the one group's) cut; a
+        # full group's table is whole
+        views = [
+            view(t, g.window, self.decode_window if g.window else 0)
+            for t, g in zip(tables if self.mixed else (tables,), self.groups)
+        ]
+        kv, x, touched = self._layers(params, kv, x, pos, views)
         logits = self._logits(x[:, 0], params)  # [S, V] f32
         return kv, jnp.argmax(logits, axis=-1).astype(jnp.int32), touched
 
@@ -727,12 +836,12 @@ class PagedForward:
     def prefill_chunk(
         self,
         params: Any,
-        kv: tuple[jax.Array, ...],  # pools (+ scales when quantized)
-        table: jax.Array,   # [MB] int32 this slot's block table (0-padded)
+        kv: tuple[Any, ...],  # pools (+ scales when quantized); a tuple a group
+        table: Any,         # [MB] int32 this slot's block table (0-padded); one a group
         tokens: jax.Array,  # [C] int32 prompt chunk (0-padded past n_valid)
         start: jax.Array,   # scalar int32: absolute position of tokens[0]
         n_valid: jax.Array,  # scalar int32: real rows in the chunk
-    ) -> tuple[tuple[jax.Array, ...], jax.Array]:
+    ) -> tuple[tuple[Any, ...], jax.Array]:
         """One chunk of one prompt through the slot's block table. Static
         per PROGRAM: the table's width ``MB`` — the host cuts it to the
         bucket covering the positions the chunk can see
@@ -740,7 +849,14 @@ class PagedForward:
         the chunk's reach are never gathered, repeated or scored. Static
         per ENGINE: ``prefill_chunk`` and ``block_size``. Rows past
         ``n_valid`` clamp to the table's last position and write to the
-        scratch block."""
+        scratch block.
+
+        A model with full and window layers takes ``table = (full [MBf],
+        window [MBw])``. The window group's table starts at the first block
+        the chunk's FIRST query can reach (:func:`window_first_block` of
+        ``start + 1``) and covers ``[start - W + 1, start + C)``; the full
+        group's chunk attends in tiles of :data:`SELECT_TILE` queries, so
+        that no ``[H, C, L]`` score tensor exists over a long table."""
         # Trace-time compile tick — see decode_step.
         self._tick()
         cfg, e = self.config, self.engine
@@ -749,22 +865,54 @@ class PagedForward:
         x = self._embed(params, tokens)[None]  # [1, C, d]
         offs = jnp.arange(C, dtype=jnp.int32)
         pos = (start + offs)[None]  # [1, C] absolute
-        p = jnp.minimum(start + offs, table.shape[0] * BS - 1)
-        bid = jnp.where(offs < n_valid, table[p // BS], SCRATCH_BLOCK)
-        window = cfg.attention_window or None
 
-        def attend(q: jax.Array, k_seq: jax.Array, v_seq: jax.Array) -> jax.Array:
-            # The chunk's queries see every earlier chunk's pages PLUS this
-            # chunk's own rows (just scattered); causal masking in absolute
-            # coordinates via q_offset. Stale rows from a previous owner of
-            # a recycled block sit at positions strictly after the last
-            # valid query and are causally masked.
-            return dense_attention(
-                q, repeat_kv(k_seq, rep), repeat_kv(v_seq, rep),
-                causal=True, window=window, q_offset=start,
+        def view(table: jax.Array, window: int, cut: int, tiled: bool) -> GroupView:
+            # As in decode_step: under ``cut`` the table starts at the first
+            # block the chunk can reach and every index below is the
+            # table's own; the mask is relative, RoPE absolute.
+            def in_table(x: jax.Array) -> jax.Array:
+                if not cut:
+                    return x
+                return x - window_first_block(start + 1, cut, BS) * BS
+
+            p = jnp.minimum(in_table(start + offs), table.shape[0] * BS - 1)
+            bid = jnp.where(offs < n_valid, table[p // BS], SCRATCH_BLOCK)
+
+            def attend_from(q: jax.Array, k_seq: jax.Array, v_seq: jax.Array, first: Any) -> jax.Array:
+                # The chunk's queries see every earlier chunk's pages PLUS
+                # this chunk's own rows (just scattered); causal masking in
+                # the table's coordinates via q_offset. Stale rows from a
+                # previous owner of a recycled block sit at positions
+                # strictly after the last valid query and are causally
+                # masked.
+                return dense_attention(
+                    q, k_seq, v_seq,
+                    causal=True, window=window or None, q_offset=first,
+                )
+
+            def attend(q: jax.Array, k_seq: jax.Array, v_seq: jax.Array) -> jax.Array:
+                k_seq, v_seq = repeat_kv(k_seq, rep), repeat_kv(v_seq, rep)
+                if not tiled:
+                    return attend_from(q, k_seq, v_seq, in_table(start))
+                return _by_query_tiles(
+                    lambda t: attend_from(t[0], k_seq, v_seq, t[1][0, 0]),
+                    (q, in_table(pos)),
+                )
+
+            return GroupView(bid, p % BS, table, attend)
+
+        # Only beside a full group is the window group's chunk table cut and
+        # the full group's chunk tiled; a one-kind model's chunk keeps its
+        # table from block 0 and attends at once.
+        views = [
+            view(
+                t, g.window,
+                cut=self.decode_window if self.mixed and g.window else 0,
+                tiled=self.mixed and not g.window,
             )
-
-        kv, x, _ = self._layers(params, kv, x, pos, bid, p % BS, table, attend)
+            for t, g in zip(table if self.mixed else (table,), self.groups)
+        ]
+        kv, x, _ = self._layers(params, kv, x, pos, views)
         # Only the last VALID row's logits matter (and only on the final
         # chunk — the host ignores them otherwise). Padded rows compute
         # garbage that is never read and whose K/V went to scratch.
@@ -852,7 +1000,9 @@ class PagedForward:
                 preferred_element_type=jnp.float32,
             ).astype(q.dtype)
 
-        kv, x, _ = self._layers(params, kv, x, pos, bid, p % BS, tables, attend)
+        kv, x, _ = self._layers(
+            params, kv, x, pos, [GroupView(bid, p % BS, tables, attend)]
+        )
         logits = self._logits(x, params)  # [S, W, V] f32
         return kv, jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
@@ -943,6 +1093,42 @@ class ServingEngine:
                     f"attention_topk > 0 with kv_dtype={engine.kv_dtype!r}: "
                     "an integer indexer-key pool is not implemented"
                 )
+        groups = layer_groups(config)
+        if len(groups) > 1:
+            # Full and window layers side by side: what this engine does
+            # not make work for two groups is refused here, by name.
+            window = groups[1].window
+            refused = {
+                "the prefix cache (adoption and copy-on-write over a group "
+                "that releases blocks)": engine.prefix_cache or prefix_cache is not None,
+                "spec_k > 0 (the verify step and the draft take one table)": engine.spec_k > 0,
+                f"kv_dtype={engine.kv_dtype!r} (integer pools a group)":
+                    storage is not None and jnp.issubdtype(storage, jnp.integer),
+                "a disaggregated hand-off (injected pool, kv_buffers or role)":
+                    pool is not None or kv_buffers is not None or role is not None,
+                f"a window of {window} that cannot bind within max_seq_len "
+                f"{engine.max_seq_len}": window >= engine.max_seq_len,
+            }
+            for what, asked in refused.items():
+                if asked:
+                    raise NotImplementedError(
+                        "a model with full and window attention layers "
+                        f"is not served with {what}"
+                    )
+            need = self._window_reach(engine, window + engine.prefill_chunk - 1)
+            if engine.window_num_blocks - 1 < need:
+                raise ValueError(
+                    f"window pool capacity ({engine.window_num_blocks - 1} "
+                    f"blocks) below the {need} blocks one prefill chunk of "
+                    f"{engine.prefill_chunk} under a window of {window} can "
+                    "reach: set EngineConfig.window_num_blocks"
+                )
+        elif engine.window_num_blocks:
+            raise ValueError(
+                "window_num_blocks is the window group's pool of a model "
+                "with full and window layers; this model's layers are of "
+                "one kind"
+            )
         if storage is not None and jnp.issubdtype(storage, jnp.integer):
             if storage != jnp.dtype(jnp.int8):
                 raise NotImplementedError(
@@ -972,6 +1158,12 @@ class ServingEngine:
                 f"{engine.num_blocks}x{engine.block_size}"
             )
         self.pool = pool
+        #: the window group's allocator (a model with full and window
+        #: layers; else None): its own free list over its own pools
+        self.window_pool = (
+            PagedKVPool(engine.window_num_blocks, engine.block_size, kv_dtype=storage)
+            if len(groups) > 1 else None
+        )
         # Radix prefix cache: built here when enabled, or injected shared
         # (the disaggregated pair indexes ONE cache over its shared pool).
         # Injection implies enabled regardless of the config flag.
@@ -992,14 +1184,22 @@ class ServingEngine:
             max_hold_steps=engine.max_hold_steps,
             prefix_cache=self.prefix_cache,
             tenants=tenants,
+            window_pool=self.window_pool,
+            window_admit_blocks=self.pool.blocks_for(engine.prefill_chunk),
         )
         if kv_buffers is None:
-            kv_buffers = KVBuffers(init_kv_buffers(
-                config.num_layers, engine.num_blocks, engine.block_size,
-                config.num_kv_heads or config.num_heads, config.head_dim,
-                storage if storage is not None else dtype,
-                index_dim=config.indexer_head_dim if config.attention_topk else 0,
-            ))
+            bufs = tuple(
+                init_kv_buffers(
+                    len(group.layers), blocks, engine.block_size,
+                    config.num_kv_heads or config.num_heads, config.head_dim,
+                    storage if storage is not None else dtype,
+                    index_dim=config.indexer_head_dim if config.attention_topk else 0,
+                )
+                for group, blocks in zip(
+                    groups, (engine.num_blocks, engine.window_num_blocks)
+                )
+            )
+            kv_buffers = KVBuffers(bufs if len(groups) > 1 else bufs[0])
         self._kvh = kv_buffers
         self._kv_dtype_name = (storage or jnp.dtype(dtype)).name
         self._next_rid = 0
@@ -1017,7 +1217,7 @@ class ServingEngine:
                 "serve_decode_steps", "serve_requeued_total",
                 "serve_tokens_discarded_total",
                 "serve_gather_blocks", "serve_live_blocks",
-                "serve_window_skipped_blocks",
+                "serve_window_skipped_blocks", "serve_window_released_blocks",
             ):
                 registry.counter(name)
             # A role-labeled engine (one half of a disaggregated pair)
@@ -1077,14 +1277,22 @@ class ServingEngine:
         # "The fixed-shape step"): derived from max_slots,
         # max_blocks_per_seq and, for the decode step, the blocks a sliding
         # window can reach; the very lists warmup() walks.
-        decode_blocks = engine.max_blocks_per_seq
-        if self._fwd.decode_window:
-            decode_blocks = min(
-                decode_blocks,
-                window_blocks(self._fwd.decode_window, engine.block_size),
-            )
+        # A model with full and window layers builds the ladder on the full
+        # group's whole tables; its window group's table has ONE width a
+        # program kind (what the window, or a chunk under it, can reach:
+        # rows not yet past the window pad to it), so a decode program is
+        # (rows, full width, that width) and the count does not multiply.
+        window = self._fwd.decode_window
+        #: the window group's table width in the decode step and in the
+        #: prefill chunk (None: one group of layers)
+        self._window_widths = (
+            self._window_reach(engine, window),
+            self._window_reach(engine, window + engine.prefill_chunk - 1),
+        ) if self._fwd.mixed else None
         self._widths, self._decode_shapes = _table_shapes(
-            engine.max_slots, engine.max_blocks_per_seq, decode_blocks
+            engine.max_slots, engine.max_blocks_per_seq,
+            self._window_reach(engine, window)
+            if window and not self._fwd.mixed else None,
         )
         # KV-cache donation, vetoed where unsafe (XLA:CPU + persistent
         # compile cache — compiler.cache.donation_safe, reached through the
@@ -1144,6 +1352,14 @@ class ServingEngine:
                 self._fwd.verify_step, donate_argnums=self._kv_donate
             )
             self._verify_fn = self._timed_first_call(self._verify_jit)
+
+    @staticmethod
+    def _window_reach(engine: EngineConfig, positions: int) -> int:
+        """The most blocks of a table that ``positions`` consecutive
+        positions can touch, a sequence's whole table at most."""
+        return min(
+            window_blocks(positions, engine.block_size), engine.max_blocks_per_seq
+        )
 
     @property
     def _kv(self) -> tuple[Any, ...]:
@@ -1221,10 +1437,18 @@ class ServingEngine:
             )
             for shape in self._decode_shapes
         }
+        def tables(*shape: int, kind: int) -> Any:
+            """A zero table of ``shape``; for a model with full and window
+            layers the pair, the window group's at its one width for this
+            ``kind`` of program (0 the decode step, 1 the prefill chunk)."""
+            if self._window_widths is None:
+                return zeros(*shape)
+            return zeros(*shape), zeros(*shape[:-1], self._window_widths[kind])
+
         for (rows, wb), name in decode_names.items():
             reg.register(
                 name, self._decode_jit,
-                self.params, self._kv, zeros(rows, wb),
+                self.params, self._kv, tables(rows, wb, kind=0),
                 zeros(rows), zeros(rows), zeros(rows, dtype=bool),
             )
         prefill_names = {
@@ -1234,7 +1458,7 @@ class ServingEngine:
         for (wb,), name in prefill_names.items():
             reg.register(
                 name, self._prefill_jit,
-                self.params, self._kv, zeros(wb), zeros(e.prefill_chunk),
+                self.params, self._kv, tables(wb, kind=1), zeros(e.prefill_chunk),
                 jnp.int32(0), jnp.int32(1),
             )
         if self._spec is not None:
@@ -1473,6 +1697,8 @@ class ServingEngine:
                     if not self.scheduler.grow(req, shed_reason=shed_reason):
                         self._inc("serve_requests_shed")
                         break
+                if self.window_pool is not None and req.state is RequestState.DECODE:
+                    self._grow_window(req, req.length)
             # grow() may have evicted requests from the snapshot above.
             return [
                 r for r in self.scheduler.running()
@@ -1540,35 +1766,69 @@ class ServingEngine:
             window_first_block(r.length, window, BS) if window else 0
             for r in decoding
         ]
-        reach = [len(r.blocks) - f for r, f in zip(decoding, first)]
-        skipped = sum(first)
+        labels: dict[str, int] = {}
+        if self._fwd.mixed:
+            # Two tables: the full group's whole one (what the ladder's
+            # width covers), and the window group's from each row's window
+            # on, at its one width. A row holds the window group's blocks
+            # from ``window_first`` (<= first: what lies behind went back).
+            handed = [r.blocks for r in decoding]
+            held = [r.window_blocks[f - r.window_first:] for r, f in zip(decoding, first)]
+            skipped = sum(f - r.window_first for r, f in zip(decoding, first))
+            labels = {
+                "live": sum(map(len, handed)),
+                "window_width": self._window_widths[0],
+                "window_live": sum(map(len, held)),
+            }
+        else:
+            handed = [r.blocks[f:] for r, f in zip(decoding, first)]
+            skipped = sum(first)
+        reach = list(map(len, handed))
         rows, width = self._decode_shape(len(decoding), max(reach))
         with span(
             "serve/decode_launch",
             rows=len(decoding), table_rows=rows, width=width,
-            skipped=skipped, topk=cfg.attention_topk,
-        ):
+            skipped=skipped, topk=cfg.attention_topk, **labels,
+        ) as launch:
             tables = np.zeros((rows, width), np.int32)
             lengths = np.zeros((rows,), np.int32)
             tokens = np.zeros((rows,), np.int32)
             active = np.zeros((rows,), bool)
             for i, req in enumerate(decoding):
-                tables[i, : reach[i]] = req.blocks[first[i]:]
+                tables[i, : reach[i]] = handed[i]
                 lengths[i] = req.length
                 tokens[i] = req.generated[-1]
                 active[i] = True
+            tables = jnp.asarray(tables)
+            if self._fwd.mixed:
+                behind = np.zeros((rows, labels["window_width"]), np.int32)
+                for i, blocks in enumerate(held):
+                    behind[i, : len(blocks)] = blocks
+                tables = (tables, jnp.asarray(behind))
             self._kv, next_tok, touched = self._decode_fn(
                 self.params, self._kv,
-                jnp.asarray(tables), jnp.asarray(lengths),
+                tables, jnp.asarray(lengths),
                 jnp.asarray(tokens), jnp.asarray(active),
             )
-            self._record_writes(
-                {req.blocks[(req.length - 1) // BS] for req in decoding}
-            )
+            at = [(req.length - 1) // BS for req in decoding]
+            self._record_writes({req.blocks[b] for req, b in zip(decoding, at)})
             self._inc("serve_decode_steps")
             self._inc("serve_gather_blocks", rows * width)
             self._inc("serve_live_blocks", sum(reach))
             self._inc("serve_window_skipped_blocks", skipped)
+            if self._fwd.mixed:
+                # The counters sum the groups. While the program runs: what
+                # the NEXT step's window leaves behind goes back to the pool
+                # (the device runs its programs in order, so a block handed
+                # to another row is written after this step has read it).
+                self.window_pool.record_fill(
+                    {req.window_blocks[b - req.window_first] for req, b in zip(decoding, at)}
+                )
+                self._inc("serve_gather_blocks", rows * labels["window_width"])
+                self._inc("serve_live_blocks", labels["window_live"])
+                launch.set_metadata(released=sum(
+                    self._release_behind(req, req.length + 1) for req in decoding
+                ))
             if cfg.attention_topk:
                 self._inc("serve_select_live_keys", sum(r.length for r in decoding))
                 self._inc(
@@ -1789,6 +2049,11 @@ class ServingEngine:
             live = self.prefix_cache.referenced_blocks()
         stats = self.pool.reconcile(live)
         self.pool.check()
+        if self.window_pool is not None:
+            # requeue() emptied every request's list: nothing is live
+            behind = self.window_pool.reconcile([])
+            self.window_pool.check()
+            stats = {k: v + behind[k] for k, v in stats.items()}
         self._inc("serve_requeued_total", len(inflight))
         self._inc("serve_tokens_discarded_total", discarded)
         if self.chaos is not None:
@@ -1812,26 +2077,58 @@ class ServingEngine:
         # cover the whole prompt from admission on.
         reach = self.pool.blocks_for(start + n_valid)
         width = self._gather_width(reach)
+        labels: dict[str, int] = {}
+        if self._fwd.mixed:
+            # The window group's blocks come a chunk at a time: take what
+            # this chunk writes (the pool may evict for it, this request
+            # too), and hand over the blocks from the first its first query
+            # can reach.
+            if not self._grow_window(req, start + n_valid):
+                return
+            first = window_first_block(
+                start + 1, self._fwd.decode_window, e.block_size
+            )
+            held = req.window_blocks[first - req.window_first:]
+            labels = {
+                "window_width": self._window_widths[1], "window_live": len(held),
+            }
+        written = slice(
+            start // e.block_size, (start + n_valid - 1) // e.block_size + 1
+        )
         with span(
             "serve/prefill_launch",
             rid=req.rid, start=start, n=n_valid, width=width,
-            topk=self.config.attention_topk,
-        ):
+            topk=self.config.attention_topk, **labels,
+        ) as launch:
             chunk = np.zeros((e.prefill_chunk,), np.int32)
             chunk[:n_valid] = req.prompt[start : start + n_valid]
             table = np.zeros((e.max_blocks_per_seq,), np.int32)
             table[: len(req.blocks)] = req.blocks
+            table = jnp.asarray(table[:width])
+            if self._fwd.mixed:
+                behind = np.zeros((labels["window_width"],), np.int32)
+                behind[: len(held)] = held
+                table = (table, jnp.asarray(behind))
             self._kv, last_logits = self._prefill_fn(
                 self.params, self._kv,
-                jnp.asarray(table[:width]), jnp.asarray(chunk),
+                table, jnp.asarray(chunk),
                 jnp.int32(start), jnp.int32(n_valid),
             )
             self._inc("serve_gather_blocks", width)
             self._inc("serve_live_blocks", reach)
-            self._record_writes(
-                req.blocks[start // e.block_size :
-                           (start + n_valid - 1) // e.block_size + 1]
-            )
+            self._record_writes(req.blocks[written])
+            if self._fwd.mixed:
+                # as in _plain_decode: the counters sum the groups, and what
+                # the next chunk (or the first decode step) cannot reach
+                # goes back while this one runs
+                self.window_pool.record_fill(req.window_blocks[
+                    written.start - req.window_first : written.stop - req.window_first
+                ])
+                self._inc("serve_gather_blocks", labels["window_width"])
+                self._inc("serve_live_blocks", len(held))
+                launch.set_metadata(
+                    released=self._release_behind(req, start + n_valid + 1)
+                )
             if self._spec is not None:
                 # The draft ingests the prompt alongside the target (same
                 # chunk, same table, its own pools) so its propose loop has a
@@ -1884,6 +2181,27 @@ class ServingEngine:
         is entering DECODE. No-op in the colocated engine; the
         disaggregated prefill engine overrides this to hand the sequence —
         block table and all — to its decode peer (``serving/disagg.py``)."""
+
+    def _grow_window(self, req: Request, length: int) -> bool:
+        """The window group's blocks for ``req``'s first ``length``
+        positions (those before ``window_first`` are behind it already),
+        evicting under pressure as :meth:`_phase_grow` does for the full
+        group. False iff ``req`` itself was shed on the way."""
+        need = self.window_pool.blocks_for(length)
+        while req.window_first + len(req.window_blocks) < need:
+            if not self.scheduler.grow(req, window=True):
+                self._inc("serve_requests_shed")
+                return False
+        return True
+
+    def _release_behind(self, req: Request, length: int) -> int:
+        """Give back the window group's blocks that a query at the last of
+        ``length`` positions, and so every later one, cannot reach."""
+        released = self.scheduler.release_behind(req, window_first_block(
+            length, self._fwd.decode_window, self.engine.block_size
+        ))
+        self._inc("serve_window_released_blocks", released)
+        return released
 
     def _record_writes(self, blocks: Iterable[int]) -> None:
         """Log this dispatch's KV writes against the pool's per-block
@@ -1984,6 +2302,7 @@ class ServingEngine:
             )
             self._metrics.gauge(self._role_name("serve_kv_blocks_in_use")).set(
                 self.pool.in_use
+                + (self.window_pool.in_use if self.window_pool is not None else 0)
             )
             from deeplearning_mpi_tpu.telemetry.registry import labeled
 

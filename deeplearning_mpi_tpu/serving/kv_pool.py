@@ -27,6 +27,16 @@ Split of responsibilities:
   which scatters/gathers through the block tables inside its jitted step
   (``serving/engine.py``).
 
+A model with full and window attention layers side by side keeps them in
+two **groups** (``serving/engine.py:layer_groups``): one :class:`PagedKVPool`
+and one set of device buffers a group, each ``[layers in group, blocks of
+group, ...]``, and a request holds a block list in each. The full group's list
+covers the whole sequence; the window group's starts at the first block the
+window can reach, and the scheduler gives the blocks behind it back to the
+window group's pool as the sequence advances
+(``Scheduler.release_behind``). Nothing in this class knows which group it
+serves.
+
 Block 0 is a reserved **scratch block**, never allocated: the engine's
 fixed-shape step always writes *somewhere*, and inactive slots / padded
 prefill rows route their writes to block 0 so they can't corrupt a live

@@ -30,6 +30,12 @@ them is exercised by ``tests/test_serving.py`` under a fake clock):
   committed tokens (prompt + max_new over queued + running) would exceed
   its budget is shed at submit with reason ``tenant_budget``; non-zero
   priorities reorder admission (higher first, arrival ties FCFS).
+- **Groups of layers** (``window_pool=``): a model with full and window
+  layers keeps two pools, and a request a block list in each. The window
+  group's list (``Request.window_blocks``, block indices from
+  ``Request.window_first`` on) is taken a chunk at a time, never for the
+  whole prompt, and :meth:`release_behind` gives back what the window has
+  left behind; admission, growth, eviction and release count both.
 - **Oldest-first eviction on OOM pressure**: when a decoding sequence
   needs one more KV block and the pool is empty, the OLDEST running
   request is shed and its blocks reclaimed. Oldest-first is the
@@ -96,6 +102,11 @@ class Request:
     shed_reason: Optional[str] = None
     slot: Optional[int] = None
     blocks: list[int] = dataclasses.field(default_factory=list)
+    #: the window group's blocks (a model with full and window layers): the
+    #: sequence's blocks number ``window_first`` and up; those before it,
+    #: out of the window's reach for good, went back to the window pool
+    window_blocks: list[int] = dataclasses.field(default_factory=list)
+    window_first: int = 0
     #: tokens generated so far (the first comes from the prefill logits)
     generated: list[int] = dataclasses.field(default_factory=list)
     #: prompt positions prefilled so far (chunk cursor)
@@ -156,12 +167,20 @@ class Scheduler:
         prefix_cache: Any = None,
         tenants: dict[str, dict[str, Any]] | None = None,
         brownout_min_deadline_s: float = 0.25,
+        window_pool: PagedKVPool | None = None,
+        window_admit_blocks: int = 0,
     ) -> None:
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1, got {max_slots}")
         if any(b < 1 for b in decode_buckets):
             raise ValueError(f"decode_buckets must be >= 1: {decode_buckets}")
         self.pool = pool
+        #: the window group's allocator (None: one group of layers), and
+        #: the most blocks of it a request takes at admission (what its
+        #: first prefill chunk writes; the engine grows the rest chunk by
+        #: chunk)
+        self.window_pool = window_pool
+        self.window_admit_blocks = window_admit_blocks
         self.max_slots = max_slots
         self.max_seq_len = max_seq_len
         self.max_queue = max_queue
@@ -327,6 +346,14 @@ class Scheduler:
                 priv = self.pool.alloc(n_total - n_full)
         if priv is None:
             return False
+        if self.window_pool is not None:
+            first_chunk = self.window_pool.alloc(
+                min(n_total, self.window_admit_blocks)
+            )
+            if first_chunk is None:  # all-or-nothing over both groups
+                self.pool.free(priv)
+                return False
+            req.window_blocks, req.window_first = first_chunk, 0
         if n_full:
             self.pool.share(chain)
         if partial is not None:
@@ -346,8 +373,11 @@ class Scheduler:
         self.slots[slot] = req
         return True
 
-    def grow(self, req: Request, *, shed_reason: str = "evicted") -> bool:
-        """Give ``req`` one more KV block, evicting under OOM pressure.
+    def grow(
+        self, req: Request, *, shed_reason: str = "evicted", window: bool = False
+    ) -> bool:
+        """Give ``req`` one more KV block (``window``: of the window group),
+        evicting under OOM pressure.
 
         Returns False iff ``req`` itself was shed (it was the oldest, or
         eviction could not free a block) — the caller must drop it from
@@ -357,10 +387,14 @@ class Scheduler:
         verify batch was being assembled); victims evicted on the way are
         always labeled ``"evicted"``.
         """
+        pool, held = (
+            (self.window_pool, req.window_blocks) if window
+            else (self.pool, req.blocks)
+        )
         while True:
-            blocks = self.pool.alloc(1)
+            blocks = pool.alloc(1)
             if blocks is not None:
-                req.blocks.extend(blocks)
+                held.extend(blocks)
                 return True
             if self.prefix_cache is not None and self.prefix_cache.evict(1):
                 continue  # an unreferenced cache branch paid for the block
@@ -378,6 +412,18 @@ class Scheduler:
         self._release(req)
         self._shed(req, reason)
         self.evicted_count += 1
+
+    def release_behind(self, req: Request, first: int) -> int:
+        """Give the window group's blocks of ``req`` before block number
+        ``first`` back to the window pool: no later query of the sequence
+        can reach them. Returns how many went."""
+        n = min(first - req.window_first, len(req.window_blocks))
+        if n <= 0:
+            return 0
+        self.window_pool.free(req.window_blocks[:n])
+        del req.window_blocks[:n]
+        req.window_first += n
+        return n
 
     def shrink(self, req: Request, keep: int) -> list[int]:
         """Return ``req``'s tail blocks past the first ``keep`` to the
@@ -519,6 +565,9 @@ class Scheduler:
             # hold?) — the reuse-proving test reads them — but hand
             # ownership back: a stale list must not be freeable twice.
             req.blocks = list(req.blocks)
+        if req.window_blocks:
+            self.window_pool.free(req.window_blocks)
+            req.window_blocks = list(req.window_blocks)  # as above
         if req.slot is not None:
             self.slots[req.slot] = None
 
@@ -534,6 +583,7 @@ class Scheduler:
             self.slots[req.slot] = None
         req.slot = None
         req.blocks = []
+        req.window_blocks, req.window_first = [], 0
         req.generated = []
         req.prefilled = 0
         req.state = RequestState.QUEUED

@@ -279,10 +279,15 @@ def transformer_fwd_flops(config: Any, batch: int, seq_len: int) -> float:
     vocab = config.vocab_size
     tokens = batch * seq_len
 
-    window = getattr(config, "attention_window", 0)
-    s_visible = seq_len / 2.0
-    if window:  # 0/None = full causal attention, no cap
-        s_visible = min(s_visible, float(window))
+    # A layer's own window where the model describes its layers one by one
+    # (TransformerConfig.layers), else the global one; the mean over layers.
+    windows = [spec.window for spec in getattr(config, "layers", ())] or [
+        getattr(config, "attention_window", 0)
+    ]
+    # 0/None = full causal attention, no cap
+    s_visible = sum(
+        min(seq_len / 2.0, float(w)) if w else seq_len / 2.0 for w in windows
+    ) / len(windows)
 
     per_token_block = 0.0
     # Projections: q (d→H·Dh), k+v (d→Hkv·Dh each), out (H·Dh→d).

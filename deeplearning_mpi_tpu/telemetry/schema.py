@@ -59,10 +59,16 @@ METRICS: dict[str, tuple[str, frozenset[str]]] = {
     # ratio is the fill of the gather (docs/SERVING.md "The fixed-shape step");
     # under a sliding window a decode row's table starts at the first block
     # its query can reach: live counts the blocks from there on, skipped the
-    # blocks before it (held, not gathered)
+    # blocks before it (held, not gathered). A model with full and window
+    # layers keeps two groups of pools: gather, live, serve_kv_bytes and
+    # serve_kv_blocks_in_use are SUMMED over the groups (the launch spans'
+    # labels carry the window group's own part), skipped stays 0 there, and
+    # released counts the window group's blocks given back to its pool
+    # once the window has left them behind
     "serve_gather_blocks": ("counter", frozenset()),
     "serve_live_blocks": ("counter", frozenset()),
     "serve_window_skipped_blocks": ("counter", frozenset()),
+    "serve_window_released_blocks": ("counter", frozenset()),
     # a selecting model's decode steps: keys its rows hold, keys their queries
     # attend (min(length, topk)); an expert model's: distinct experts a step's
     # rows routed to, summed over layers, against layers x experts held

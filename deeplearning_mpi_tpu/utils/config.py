@@ -261,7 +261,8 @@ def arch_mismatch_error(cfg, ckpt_dir) -> str | None:
     if not path.is_file():
         return None
     saved = json.loads(path.read_text())
-    current = dataclasses.asdict(cfg)
+    # as the file would hold it: a tuple (``layers``) comes back a list
+    current = json.loads(json.dumps(dataclasses.asdict(cfg)))
     lines = [
         f"{key}: checkpoint={saved[key]!r}, flags={current[key]!r}"
         for key in saved
