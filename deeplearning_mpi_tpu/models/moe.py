@@ -22,14 +22,23 @@ it.
   contention), so the serving engine refuses it.
 
 :func:`dropless_moe` / :class:`DroplessMoE` (``moe_routing='dropless'``): no
-capacity and nothing dropped. Every (token, expert) claim is served: the
-claims are sorted by expert and one grouped matrix product
-(``jax.lax.ragged_dot``) runs each expert over its own contiguous rows, so the
-work and the weight traffic are those of the experts the batch touched, and a
+capacity and nothing dropped. Every (token, expert) claim is served, and a
 token's output depends on the token alone — the serving engine's
 request-independence contract holds (``serving/engine.py``). The ``[B,S,E,C]``
 dispatch tensor of the capacity form does not exist here; at 128 experts it
-could not.
+could not. The three expert products take one of two forms, which
+:func:`dropless_form` picks from the static shapes: *grouped* — the claims
+sorted by expert and one grouped matrix product (``jax.lax.ragged_dot``) that
+runs each expert over its own contiguous rows, so the work and the weight
+traffic are those of the experts the batch touched (a prefill chunk; a decode
+step whose rows claim fewer experts than there are) — and *batched*, for few
+rows whose claims are at least as many as the experts (a decode step that
+reads nearly every expert whatever is done): no sort, every expert over every
+row as three batched products that stream each matrix once, at the pace the
+memory delivers them. The forms run the same sums in another order and so
+round differently: bit for bit a token's output is its own within a form,
+and the serving engine builds its decode programs in one form
+(``ServingEngine._decode_shapes``).
 
 Both: f32 router. Routing decisions (softmax + top-k) are computed in
 float32; bf16 router logits flip top-k order at scale.
@@ -276,6 +285,61 @@ class MoEMLP(nn.Module):
             )
 
 
+#: The most rows the batched form of :func:`dropless_moe` takes: a bound under
+#: which its three products stay ahead of the grouped ones. Measured on a TPU
+#: v5e in bf16, one whole layer, ms (PERF.md section 6, PR 37), grouped /
+#: batched. At 64 experts of 2304 x 896: 1.76 / 1.16 at 4 rows, 4.03 / 1.18 at
+#: 16, 5.56 / 1.16 at 128 (the batched form is flat from 4 to 128 rows, at 83%
+#: of what 819 GB/s allows for all 64), 5.76 / 1.35 at 256, 6.22 / 2.64 at 512
+#: and 7.04 / 5.88 at 1,024, where the products' own work binds. At 128
+#: experts of 2048 x 768: 0.62 / 1.72 at 4 rows, 1.05 / 1.70 at 8, 1.79 / 1.72
+#: at 16 (the crossover, where the claims rule of :func:`dropless_form`
+#: switches), 4.56 / 1.73 at 128, 4.66 / 2.04 at 256, 4.89 / 4.06 at 512 and
+#: 5.38 / 8.40 at 1,024 (the upper crossover, between 512 and 1,024). 256 is
+#: the most rows measured at which the batched form is at least twice ahead
+#: on both widths.
+BATCHED_MAX_ROWS = 256
+
+
+def dropless_form(n_tokens: int, top_k: int, n_experts: int) -> str:
+    """Which form of the three expert products :func:`dropless_moe` runs,
+    from the static shapes alone: ``'batched'`` iff the rows' claims are at
+    least as many as the experts (nearly every expert is read whatever is
+    done) and the rows are at most :data:`BATCHED_MAX_ROWS`; else
+    ``'grouped'``. One function for the program and for the host that
+    labels its launches."""
+    if n_tokens * top_k >= n_experts and n_tokens <= BATCHED_MAX_ROWS:
+        return "batched"
+    return "grouped"
+
+
+def _batched_experts(
+    x: jax.Array,        # [N, d]
+    gates: jax.Array,    # [N, k] float32
+    experts: jax.Array,  # [N, k] int32; n_experts on a row that is not live
+    w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, dtype: Any,
+) -> tuple[jax.Array, jax.Array]:
+    """The batched form of :func:`dropless_moe`'s products and sum."""
+    with annotate("moe/route"):
+        claims = experts[:, :, None] == jnp.arange(w_gate.shape[0])  # [N, k, E]
+        chosen = jnp.any(claims, axis=1).T[:, :, None]               # [E, N, 1]
+        weight = jnp.sum(
+            jnp.where(claims, gates[:, :, None], 0.0), axis=1
+        ).T[:, :, None]                                              # [E, N, 1]
+    with annotate("moe/experts"):
+        xs = x.astype(dtype)
+        hidden = jax.nn.silu(
+            jnp.einsum("nd,edf->enf", xs, w_gate.astype(dtype))
+        ) * jnp.einsum("nd,edf->enf", xs, w_up.astype(dtype))
+        ys = jnp.einsum("enf,efd->end", hidden, w_down.astype(dtype))
+    with annotate("moe/combine"):
+        # masked, not only weighted by 0: an unchosen expert's inf stays out
+        y = jnp.sum(
+            jnp.where(chosen, ys.astype(jnp.float32) * weight, 0.0), axis=0
+        ).astype(x.dtype)
+    return y, jnp.sum(jnp.any(chosen, axis=1)).astype(jnp.int32)
+
+
 def dropless_moe(
     x: jax.Array,        # [N, d] tokens
     router: jax.Array,   # [d, E]
@@ -290,14 +354,26 @@ def dropless_moe(
     """Dropless top-k mixture of SwiGLU experts over a flat batch of tokens.
 
     ``p = softmax_f32(x @ router)``; the ``top_k`` largest renormalised to sum
-    1; ``y = sum_e g_e * down_e(silu(gate_e x) * up_e x)``. The ``N * top_k``
-    claims are sorted by expert (stable: by token within an expert) and each
-    of the three matrix products is ONE ``jax.lax.ragged_dot`` over the
-    groups, so an expert no token chose is neither computed nor read. Rows
-    with ``live`` false (padding) claim no expert and yield zeros.
+    1; ``y = sum_e g_e * down_e(silu(gate_e x) * up_e x)``. Rows with ``live``
+    false (padding) claim no expert and yield zeros. Each expert's output is
+    rounded to ``dtype`` and the weighted sum runs in float32, in either of
+    two forms chosen by :func:`dropless_form` from ``N``, ``top_k`` and ``E``:
+
+    - *grouped*: the ``N * top_k`` claims are sorted by expert (stable: by
+      token within an expert) and each of the three matrix products is ONE
+      ``jax.lax.ragged_dot`` over the groups, so an expert no token chose is
+      neither computed nor read.
+    - *batched* (few rows that claim most experts anyway, a decode step):
+      no sort; every expert runs over every row as three batched products,
+      each matrix read exactly once, and a gate matrix ``[N, E]`` that is 0
+      at the experts a row did not choose weights the sum. An unchosen
+      expert's output is masked before it, so its overflow stays out.
+
+    Within a form a row's output is the same bit for bit whatever rows share
+    the batch; across the forms it agrees to rounding.
 
     Returns ``(y [N, d] in x's dtype, touched)``: ``touched`` is the int32
-    count of distinct experts the live rows routed to.
+    count of distinct experts the live rows routed to, in both forms.
     """
     n_tok, n_exp = x.shape[0], router.shape[-1]
     with annotate("moe/route"):
@@ -305,9 +381,12 @@ def dropless_moe(
         gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
         if live is not None:
-            # A padding row's claims go past the last group: no expert
+            # A padding row's claims go past the last expert: no expert
             # computes them, none is read or counted for them.
             experts = jnp.where(live[:, None], experts, n_exp)
+    if dropless_form(n_tok, top_k, n_exp) == "batched":
+        return _batched_experts(x, gates, experts, w_gate, w_up, w_down, dtype)
+    with annotate("moe/route"):
         claim_expert = experts.reshape(-1)
         order = jnp.argsort(claim_expert, stable=True)  # claims by expert
         sizes = jnp.bincount(claim_expert, length=n_exp).astype(jnp.int32)

@@ -71,7 +71,11 @@ two GROUPS, each with its own pools, allocator and block table:
   output depends on the token alone. CAPACITY routing stays refused: there a
   token's output depends on which OTHER tokens share its batch (capacity
   contention), which would break the engine's request-independence contract
-  — co-batched strangers must never change your completion.
+  — co-batched strangers must never change your completion. The layer has
+  two forms that round differently, picked by the program's static rows
+  (``dropless_form``); the engine builds only the decode row buckets that
+  take the form of its ``max_slots``-row program, so the contract holds
+  across buckets too.
 """
 
 from __future__ import annotations
@@ -99,7 +103,7 @@ from deeplearning_mpi_tpu.ops.attention import (
     repeat_kv,
 )
 from deeplearning_mpi_tpu.analysis import sanitizer as _sanitizer
-from deeplearning_mpi_tpu.models.moe import dropless_moe
+from deeplearning_mpi_tpu.models.moe import dropless_form, dropless_moe
 from deeplearning_mpi_tpu.ops.quant import dequantize_kv, quantize_kv
 from deeplearning_mpi_tpu.ops.sparse_attention import (
     attend_masked,
@@ -1268,6 +1272,9 @@ class ServingEngine:
                 # step's rows routed to, and experts held.
                 registry.counter("serve_moe_experts_touched")
                 registry.counter("serve_moe_expert_slots")
+                # Decode (and verify) steps whose expert layers ran as
+                # batched products (models.moe.dropless_form).
+                registry.counter("serve_moe_batched_steps")
         self._fwd = PagedForward(
             config, engine, dtype,
             tick=lambda: self._inc("serve_compile_total"),
@@ -1294,6 +1301,18 @@ class ServingEngine:
             self._window_reach(engine, window)
             if window and not self._fwd.mixed else None,
         )
+        if config.moe_experts:
+            # Request independence: the dropless expert layer rounds its sum
+            # in another order in each of its two forms, and the form follows
+            # the program's static rows (models.moe.dropless_form). So every
+            # decode program takes the form of the ``max_slots``-row one: a
+            # row bucket that would take the other is not built, and its
+            # rows ride the next bucket up. How many strangers share a step
+            # then never moves a completion.
+            form = self._moe_form(engine.max_slots)
+            self._decode_shapes = tuple(
+                s for s in self._decode_shapes if self._moe_form(s[0]) == form
+            )
         # KV-cache donation, vetoed where unsafe (XLA:CPU + persistent
         # compile cache — compiler.cache.donation_safe, reached through the
         # compat shim): the engine restores weights from disk and then runs
@@ -1734,6 +1753,23 @@ class ServingEngine:
         transition."""
         return next(w for w in self._widths if w >= blocks)
 
+    def _moe_form(self, n_tokens: int) -> dict[str, str]:
+        """The ``moe`` label of a launch span: the form the dropless expert
+        layers of a program over ``n_tokens`` rows take
+        (``models.moe.dropless_form``, which the program itself asks: the
+        form is static per executable). Empty for a model without experts."""
+        cfg = self.config
+        if not cfg.moe_experts:
+            return {}
+        return {"moe": dropless_form(n_tokens, cfg.moe_top_k, cfg.moe_experts)}
+
+    def _count_decode_step(self, form: dict[str, str]) -> None:
+        """One decode (or verify) step launched, ``form`` its
+        :meth:`_moe_form`."""
+        self._inc("serve_decode_steps")
+        if form.get("moe") == "batched":
+            self._inc("serve_moe_batched_steps")
+
     def _decode_shape(self, rows: int, blocks: int) -> tuple[int, int]:
         """Static (rows, width) of this step's decode table: of the pairs
         in ``_decode_shapes`` (:func:`_table_shapes`) that hold the ``rows``
@@ -1785,10 +1821,11 @@ class ServingEngine:
             skipped = sum(first)
         reach = list(map(len, handed))
         rows, width = self._decode_shape(len(decoding), max(reach))
+        form = self._moe_form(rows)
         with span(
             "serve/decode_launch",
             rows=len(decoding), table_rows=rows, width=width,
-            skipped=skipped, topk=cfg.attention_topk, **labels,
+            skipped=skipped, topk=cfg.attention_topk, **labels, **form,
         ) as launch:
             tables = np.zeros((rows, width), np.int32)
             lengths = np.zeros((rows,), np.int32)
@@ -1812,7 +1849,7 @@ class ServingEngine:
             )
             at = [(req.length - 1) // BS for req in decoding]
             self._record_writes({req.blocks[b] for req, b in zip(decoding, at)})
-            self._inc("serve_decode_steps")
+            self._count_decode_step(form)
             self._inc("serve_gather_blocks", rows * width)
             self._inc("serve_live_blocks", sum(reach))
             self._inc("serve_window_skipped_blocks", skipped)
@@ -1916,10 +1953,12 @@ class ServingEngine:
                 tables, lengths, last, n_prop, active
             )
             self._inc("spec_draft_steps", draft_steps)
+        W = K + 1
+        form = self._moe_form(e.max_slots * W)
         with span(
-            "serve/verify_launch", rows=len(decoding), width=tables.shape[1]
+            "serve/verify_launch", rows=len(decoding), width=tables.shape[1],
+            **form,
         ):
-            W = K + 1
             tokens = np.zeros((e.max_slots, W), np.int32)
             tokens[:, 0] = last
             tokens[:, 1:] = props
@@ -1936,7 +1975,7 @@ class ServingEngine:
                 hi = min((req.length - 1 + n_fed - 1) // BS, len(req.blocks) - 1)
                 touched.update(req.blocks[lo : hi + 1])
             self._record_writes(touched)
-            self._inc("serve_decode_steps")
+            self._count_decode_step(form)
             self._inc("spec_verify_steps")
             # the target's verify gather; the draft's own are not counted
             self._inc("serve_gather_blocks", e.max_slots * tables.shape[1])
@@ -2099,6 +2138,7 @@ class ServingEngine:
             "serve/prefill_launch",
             rid=req.rid, start=start, n=n_valid, width=width,
             topk=self.config.attention_topk, **labels,
+            **self._moe_form(e.prefill_chunk),
         ) as launch:
             chunk = np.zeros((e.prefill_chunk,), np.int32)
             chunk[:n_valid] = req.prompt[start : start + n_valid]

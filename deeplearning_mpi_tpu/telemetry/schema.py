@@ -71,11 +71,14 @@ METRICS: dict[str, tuple[str, frozenset[str]]] = {
     "serve_window_released_blocks": ("counter", frozenset()),
     # a selecting model's decode steps: keys its rows hold, keys their queries
     # attend (min(length, topk)); an expert model's: distinct experts a step's
-    # rows routed to, summed over layers, against layers x experts held
+    # rows routed to, summed over layers, against layers x experts held; and
+    # the decode steps whose expert layers ran as batched products
+    # (models/moe.py:dropless_form says which, from the program's static rows)
     "serve_select_live_keys": ("counter", frozenset()),
     "serve_select_kept_keys": ("counter", frozenset()),
     "serve_moe_experts_touched": ("counter", frozenset()),
     "serve_moe_expert_slots": ("counter", frozenset()),
+    "serve_moe_batched_steps": ("counter", frozenset()),
     "serve_handoff_depth": ("gauge", frozenset()),
     "serve_handoff_stalls_total": ("counter", frozenset()),
     "serve_handoffs_total": ("counter", frozenset()),

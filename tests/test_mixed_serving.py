@@ -255,7 +255,9 @@ def test_zero_compiles_after_warmup_while_rows_cross_the_window(params):
     engine = _engine(params, registry=registry)
     programs = engine.warmup()
     widths, shapes = _table_shapes(ENGINE.max_slots, ENGINE.max_blocks_per_seq)
-    assert len(programs) == len(shapes) + len(widths)  # the window group's one width multiplies nothing
+    # the 1- and 2-row buckets would take the grouped form of the expert layer where 4 rows are batched: not built (PR 37)
+    assert set(engine._decode_shapes) == {s for s in shapes if s[0] == ENGINE.max_slots} and len(engine._decode_shapes) == 4
+    assert len(programs) == len(engine._decode_shapes) + len(widths)  # the window group's one width multiplies nothing
     assert engine._window_widths == (window_blocks(WINDOW, BS), window_blocks(WINDOW + CHUNK - 1, BS)) == (5, 7)
     compiled = registry.snapshot()["serve_compile_total"]
     served = _serve(engine, [_prompt(3), _prompt(14), _prompt(33), _prompt(50)], 40)  # 3 -> 43: across the window
@@ -289,8 +291,8 @@ def test_the_launch_spans_and_counters_count_both_groups(params, monkeypatch):
     _serve(engine, [_prompt(30), _prompt(7)], 20)
     decode = [s.labels for s in spans if s.name == "serve/decode_launch"]
     prefill = [s.labels for s in spans if s.name == "serve/prefill_launch"]
-    assert all({"rows", "table_rows", "width", "skipped", "topk", "live", "window_width", "window_live", "released"} == set(d) for d in decode)
-    assert all({"rid", "start", "n", "width", "topk", "window_width", "window_live", "released"} == set(p) for p in prefill)
+    assert all({"rows", "table_rows", "width", "skipped", "topk", "live", "window_width", "window_live", "released", "moe"} == set(d) for d in decode)
+    assert all({"rid", "start", "n", "width", "topk", "window_width", "window_live", "released", "moe"} == set(p) for p in prefill)
     assert {d["window_width"] for d in decode} == {5} and {p["window_width"] for p in prefill} == {7}
     assert all(d["skipped"] == 0 and d["window_live"] <= d["rows"] * 5 for d in decode)
     snap = registry.snapshot()
@@ -298,6 +300,53 @@ def test_the_launch_spans_and_counters_count_both_groups(params, monkeypatch):
     assert snap["serve_gather_blocks"] == sum(d["table_rows"] * (d["width"] + 5) for d in decode) + sum(p["width"] + 7 for p in prefill)
     assert snap["serve_live_blocks"] >= sum(d["live"] + d["window_live"] for d in decode)
     assert snap["serve_window_skipped_blocks"] == 0
+
+
+@pytest.mark.parametrize("slots, form", [(4, "batched"), (2, "grouped")], ids=["four-slots", "two-slots"])
+def test_the_launch_spans_say_which_form_the_expert_layer_took(params, monkeypatch, slots, form):
+    """``moe`` on every launch is the form the launched program HAS (its
+    jaxpr holds ``ragged_dot`` iff grouped), and the engine builds its
+    decode programs in one form, that of ``max_slots`` rows (8 experts at
+    top-2: 4 rows are batched, so their 1- and 2-row buckets are not built;
+    2 rows are grouped), so one row and four decode alike;
+    ``serve_moe_batched_steps`` counts the decode steps launched batched."""
+    from deeplearning_mpi_tpu.models.moe import dropless_form
+    from deeplearning_mpi_tpu.serving import engine as engine_mod
+
+    spans = []
+    real = engine_mod.span
+
+    def spy(name, **labels):
+        spans.append((name, labels))
+        return real(name, **labels)
+
+    monkeypatch.setattr(engine_mod, "span", spy)
+    registry = MetricsRegistry()
+    engine = _engine(params, engine=dataclasses.replace(ENGINE, max_slots=slots), registry=registry)
+    assert {dropless_form(rows, 2, 8) for rows, _ in engine._decode_shapes} == {form}
+    assert {rows for rows, _ in engine._decode_shapes} == ({4} if slots == 4 else {1, 2})
+    _serve(engine, [_prompt(30)], 6)  # one row decodes, then up to ``slots``
+    _serve(engine, [_prompt(n) for n in (30, 7, 19, 41)[:slots]], 12)
+    decode = [labels for name, labels in spans if name == "serve/decode_launch"]
+    prefill = [labels for name, labels in spans if name == "serve/prefill_launch"]
+    assert {d["rows"] for d in decode} >= {1, slots} and {d["moe"] for d in decode} == {form}
+    assert {p["moe"] for p in prefill} == {"batched"} == {dropless_form(CHUNK, 2, 8)}
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    for rows, width, window_width in {(d["table_rows"], d["width"], d["window_width"]) for d in decode}:
+        text = str(jax.make_jaxpr(engine._fwd.decode_step)(
+            engine.params, engine._kv, (i32(rows, width), i32(rows, window_width)), i32(rows), i32(rows),
+            jax.ShapeDtypeStruct((rows,), jnp.bool_),
+        ))
+        assert ("ragged_dot" in text) == (form == "grouped")
+    for width, window_width in {(p["width"], p["window_width"]) for p in prefill}:
+        text = str(jax.make_jaxpr(engine._fwd.prefill_chunk)(
+            engine.params, engine._kv, (i32(width), i32(window_width)), i32(CHUNK), i32(), i32(),
+        ))
+        assert "ragged_dot" not in text
+    snap = registry.snapshot()
+    assert snap["serve_moe_batched_steps"] == (len(decode) if form == "batched" else 0)
+    assert snap["serve_decode_steps"] == len(decode)
 
 
 # -- what is refused, by name ------------------------------------------------
@@ -342,8 +391,13 @@ PARENT_PROGRAMS = {
     },
     "mistral-like-unbound": {"decode@4x16": "c5be8de70bfea2f6", "decode@2x8": "14a6ee5fec2b6815", "prefill@8": "295f5130b01b5aab"},
     "keye-like": {
-        "decode@4x16": "2af1fed6c3f5b5f7", "decode@1x8": "14a23ef253408e9f", "decode@4x2": "b155488b74e2004b",
-        "prefill@4": "ac32eaaa633de1a0", "prefill@16": "35868f6ba2067bb5",
+        # four of the five moved on purpose in PR 37: 4 rows x top-2 = 8 claims over 8 experts crosses
+        # models/moe.py:dropless_form's rule, and so does the chunk of 128 (256 claims, under BATCHED_MAX_ROWS = 256
+        # rows, the bound the chip gave), so their expert layers are batched products; 1 row is the parent's. The
+        # REAL sizes' programs (keye-serve-long: 8 rows x top-8 over 128 experts, chunks of 1,024) are the parent's
+        # by text: PERF.md section 6, PR 37
+        "decode@4x16": "d7e1c1413992ebf4", "decode@1x8": "14a23ef253408e9f", "decode@4x2": "6db05cfe0b5e5498",
+        "prefill@4": "d40d22133fc4cb46", "prefill@16": "0b3a3f28c993a0ba",
     },
 }
 _TINY = dict(vocab_size=128, num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16, d_model=32, d_ff=64, tied_embeddings=False)

@@ -1,5 +1,7 @@
 """MoE layer + expert-parallel sharding tests (8 virtual CPU devices)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -254,3 +256,97 @@ class TestExpertParallelSharding:
         np.testing.assert_allclose(
             np.asarray(expected), np.asarray(got), atol=2e-4
         )
+
+
+# -- the two forms of the dropless layer (PR 37) ------------------------------
+
+def _dropless_args(n_exp, d=32, f=16, seed=37):
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape) * shape[-2] ** -0.5, jnp.float32)  # noqa: E731
+    return draw(d, n_exp), draw(n_exp, d, f), draw(n_exp, d, f), draw(n_exp, f, d)
+
+
+def _dropless_in_form(form, monkeypatch, *args, **kw):
+    from deeplearning_mpi_tpu.models import moe
+
+    monkeypatch.setattr(moe, "dropless_form", lambda *shape: form)
+    return moe.dropless_moe(*args, **kw)
+
+
+# (N, top_k, E) on both sides of the rule: claims under the experts, rows over the bound, and within both
+FORM_SHAPES = [(16, 8, 64), (8, 8, 64), (4, 8, 64), (8, 8, 128), (4, 2, 8), (3, 2, 8), (96, 2, 8), (300, 2, 8), (1, 1, 4)]
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all-live", "some-padding"])
+@pytest.mark.parametrize("n_tok, top_k, n_exp", FORM_SHAPES)
+def test_the_batched_and_the_grouped_form_agree_in_float32(n_tok, top_k, n_exp, masked, monkeypatch):
+    """``y`` to accumulation order and ``touched`` exactly, whichever form
+    the rule would pick at these shapes; a row that is not live yields zeros
+    and claims nothing in both."""
+    args = _dropless_args(n_exp)
+    x = jnp.asarray(np.random.default_rng(n_tok).standard_normal((n_tok, 32)), jnp.float32)
+    live = jnp.arange(n_tok) % 3 != 1 if masked else None
+    with jax.default_matmul_precision("highest"):
+        got = {
+            form: _dropless_in_form(form, monkeypatch, x, *args, top_k=top_k, dtype=jnp.float32, live=live)
+            for form in ("grouped", "batched")
+        }
+    (y_g, touched_g), (y_b, touched_b) = got["grouped"], got["batched"]
+    scale = float(jnp.abs(y_g).max())
+    np.testing.assert_allclose(np.asarray(y_b), np.asarray(y_g), rtol=1e-5, atol=1e-5 * scale)
+    assert int(touched_b) == int(touched_g) <= min(n_exp, n_tok * top_k)
+    if masked:
+        assert not np.asarray(y_b)[1::3].any() and not np.asarray(y_g)[1::3].any()
+
+
+@pytest.mark.parametrize("product", ["gate", "up", "down"])
+def test_an_unchosen_expert_that_overflows_stays_out_of_the_batched_sum(product, monkeypatch):
+    """One expert's matrix is all inf and no row chooses it (its router
+    logit is -1e4): the batched form computes it all the same, and masks it
+    before the weighted sum, so ``y`` is bit for bit the sound layer's."""
+    router, *mats = _dropless_args(8)
+    bad = 5
+    x = jnp.asarray(np.random.default_rng(5).standard_normal((6, 32)), jnp.float32).at[:, 0].set(1.0)
+    router = router.at[:, bad].set(0.0).at[0, bad].set(-1e4)
+    sound, touched = _dropless_in_form("batched", monkeypatch, x, router, *mats, top_k=2, dtype=jnp.float32)
+    which = ["gate", "up", "down"].index(product)
+    mats[which] = mats[which].at[bad].set(jnp.inf)
+    y, touched_bad = _dropless_in_form("batched", monkeypatch, x, router, *mats, top_k=2, dtype=jnp.float32)
+    assert np.isfinite(np.asarray(y)).all()
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(sound))
+    assert int(touched_bad) == int(touched) <= 7
+
+
+@pytest.mark.parametrize("n_tok, top_k, n_exp, form", [
+    (16, 8, 64, "batched"),     # mellum-serve-mixed's decode step: 128 claims over 64 experts
+    (8, 8, 64, "batched"),      # the 8-row decode shape its lead may use
+    (4, 8, 64, "grouped"),      # 32 claims: under the experts
+    (1024, 8, 64, "grouped"),   # its prefill chunk
+    (8, 8, 128, "grouped"),     # keye-serve-long's decode step: 64 claims over 128 experts
+    (1, 8, 128, "grouped"),     # its probe
+    (1024, 8, 128, "grouped"),  # its prefill chunk
+    (4, 2, 8, "batched"),       # the toys of tests/test_mixed_serving.py: a decode table of 4 rows,
+    (2, 2, 8, "grouped"),       # one of 2,
+    (128, 2, 8, "batched"),     # and the keye-like toy's chunk
+    (256, 8, 64, "batched"),    # the bound the chip gave (BATCHED_MAX_ROWS), on both sides
+    (257, 8, 64, "grouped"),
+])
+def test_the_rule_picks_the_form_from_the_static_shapes(n_tok, top_k, n_exp, form):
+    from deeplearning_mpi_tpu.models.moe import dropless_form
+
+    assert dropless_form(n_tok, top_k, n_exp) == form
+
+
+@pytest.mark.parametrize("n_tok, form", [(16, "batched"), (4, "grouped")])
+def test_the_batched_form_traces_no_sort_and_no_grouped_product(n_tok, form):
+    """The jaxpr under ``moe/`` of a layer over 16 rows (64 experts at top-8)
+    holds no ``sort``, ``ragged_dot`` or ``cumsum``; the grouped form over 4
+    rows holds the sorts and the three grouped products."""
+    from deeplearning_mpi_tpu.models.moe import dropless_moe
+
+    args = _dropless_args(64)
+    text = str(jax.make_jaxpr(lambda x: dropless_moe(x, *args, top_k=8, dtype=jnp.float32))(jnp.zeros((n_tok, 32))))
+    grouped_ops = set(re.findall(r"\b(sort|ragged_dot\w*|cumsum)\b", text))
+    assert (not grouped_ops) == (form == "batched"), grouped_ops
+    if form == "batched":
+        assert text.count("dot_general") == 4  # the router and the three batched products

@@ -131,14 +131,50 @@ def test_alone_and_among_strangers_the_stream_is_the_same(params):
     assert _reference_gap(params, mine, alone) <= 1e-4
 
 
+@pytest.mark.parametrize("slots, top_k, experts, rows", [
+    (4, 2, 8, {4}),           # this file's engine: 1 and 2 rows would be grouped, 4 are batched
+    (16, 8, 64, {8, 16}),     # mellum-serve-mixed: the 4-row bucket (32 claims over 64) is not built
+    (8, 8, 128, {2, 4, 8}),   # keye-serve-long: grouped at every bucket, nothing goes
+    (2, 2, 8, {1, 2}),
+    (512, 8, 64, {512}),      # over BATCHED_MAX_ROWS the step is grouped: its 128- and 256-row buckets go
+], ids=["toy", "mellum", "keye", "two-slots", "many-slots"])
+def test_every_decode_program_of_an_engine_takes_one_form_of_the_expert_layer(slots, top_k, experts, rows):
+    """The two forms round differently, so how many strangers share a step
+    must not pick the form: the engine keeps the row buckets that take the
+    form of ``max_slots`` rows, at every width it had them."""
+    from deeplearning_mpi_tpu.models.moe import dropless_form
+    from deeplearning_mpi_tpu.serving.engine import _table_shapes
+
+    def abstract(model):
+        return jax.eval_shape(lambda: TransformerLM(model, dtype=jnp.float32).init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+
+    model = dataclasses.replace(MODEL, moe_experts=experts, moe_top_k=top_k)
+    engine = _engine(abstract(model), model, dataclasses.replace(ENGINE, max_slots=slots, num_blocks=slots * 16 + 1))
+    built = engine._decode_shapes
+    assert {r for r, _ in built} == rows
+    assert len({dropless_form(r, top_k, experts) for r, _ in built}) == 1
+    assert set(built) == {s for s in _table_shapes(slots, ENGINE.max_blocks_per_seq)[1] if s[0] in rows}
+    dense = dataclasses.replace(MODEL, moe_experts=0, attention_topk=0, indexer_heads=0, indexer_head_dim=0)
+    assert _engine(abstract(dense), dense)._decode_shapes == _table_shapes(ENGINE.max_slots, ENGINE.max_blocks_per_seq)[1]
+
+
 def test_the_dropless_layer_serves_a_token_by_itself(params):
     mlp = params["layer_0"]["mlp"]
-    x = jnp.asarray(RNG.standard_normal((5, 32)), jnp.float32)
+    x = jnp.asarray(RNG.standard_normal((7, 32)), jnp.float32)
     args = (mlp["router"]["kernel"], mlp["experts_gate"], mlp["experts_up"], mlp["experts_down"])
-    alone, _ = dropless_moe(x[:1], *args, top_k=2, dtype=jnp.float32)
-    among, touched = dropless_moe(x, *args, top_k=2, dtype=jnp.float32)
+    serve = lambda rows, live=None: dropless_moe(rows, *args, top_k=2, dtype=jnp.float32, live=live)  # noqa: E731
+    # bit for bit inside each form of the three products (models/moe.py:dropless_form): grouped under 8 claims (2 rows and
+    # 3: XLA:CPU rounds the router's product of ONE row, a matrix-vector product, in another order than any other count) ...
+    np.testing.assert_array_equal(np.asarray(serve(x[:2])[0]), np.asarray(serve(x[:3])[0][:2]))
+    # ... and batched from 4 rows on: alone in the table (the engine's padding beside it), among five, among seven
+    alone, one = serve(x[:5], jnp.arange(5) < 1)
+    among, touched = serve(x[:5])
     np.testing.assert_array_equal(np.asarray(alone[0]), np.asarray(among[0]))
-    padded, fewer = dropless_moe(x, *args, top_k=2, dtype=jnp.float32, live=jnp.arange(5) < 2)
+    np.testing.assert_array_equal(np.asarray(among), np.asarray(serve(x)[0][:5]))
+    assert not np.asarray(alone[1:]).any() and int(one) == 2
+    # across the forms the same sums run in another order: the engine builds its decode programs in ONE form
+    np.testing.assert_allclose(np.asarray(among[0]), np.asarray(serve(x[:1])[0][0]), rtol=1e-5, atol=1e-6)
+    padded, fewer = serve(x[:5], jnp.arange(5) < 2)
     np.testing.assert_array_equal(np.asarray(padded[:2]), np.asarray(among[:2]))
     assert not np.asarray(padded[2:]).any() and int(fewer) <= 4 <= int(touched) + 2
 
