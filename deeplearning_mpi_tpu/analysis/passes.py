@@ -238,7 +238,8 @@ def _check_retrace(src: SourceFile) -> list[Finding]:
 #
 # The decode loop and the train step drive the device; a host sync there
 # (.item(), np.asarray on a device value, jax.device_get,
-# block_until_ready) stalls the pipeline once per step. The audited syncs —
+# block_until_ready, the serving engine's ``fetch``) stalls the pipeline
+# once per step. The audited syncs —
 # the one sampled-token fetch per decode step, the one finite-count fetch
 # per epoch — carry inline disables with their justification; everything
 # else is a regression. Hot scopes are configured by path below; any
@@ -270,7 +271,8 @@ def _check_host_sync(src: SourceFile) -> list[Finding]:
             np_computed = name in (
                 "np.asarray", "np.array", "numpy.asarray"
             ) and node.args and isinstance(node.args[0], ast.Call)
-            if name in ("jax.device_get", "jax.block_until_ready") or np_computed:
+            # serving/launch.py:fetch waits and copies back in one call
+            if name in ("jax.device_get", "jax.block_until_ready", "fetch") or np_computed:
                 findings.append(Finding(
                     "DMT003", src.rel, node.lineno,
                     f"`{name}` in hot loop `{func.name}`: host-device sync "

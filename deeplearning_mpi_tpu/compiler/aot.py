@@ -156,7 +156,9 @@ class WarmProgram:
     compiled for (AOT never retraces); the fallback keeps a signature drift
     — a config change, an unexpected dtype — a silent recompile instead of
     a crash. ``fallback_calls`` counts how often the net was needed (zero
-    in a correctly-warmed engine).
+    in a correctly-warmed engine), ``on_fallback`` is called each time (the
+    serving engine's ``serve_program_fallbacks`` counter), and ``last``
+    names the executable the latest call ran (None where the net ran it).
 
     A function warmed at several static shapes hands in a dict of programs
     keyed by the shape of its ``shape_arg``-th argument (of the first array,
@@ -170,11 +172,14 @@ class WarmProgram:
         fallback: Callable[..., Any],
         *,
         shape_arg: int | None = None,
+        on_fallback: Callable[[], None] | None = None,
     ):
         self.program = program
         self.fallback = fallback
         self.shape_arg = shape_arg
+        self.on_fallback = on_fallback
         self.fallback_calls = 0
+        self.last: str | None = None
 
     def __call__(self, *args: Any) -> Any:
         program = self.program
@@ -185,10 +190,16 @@ class WarmProgram:
             program = program.get(key.shape)
         if program is not None:
             try:
-                return program.compiled(*args)
+                out = program.compiled(*args)
             except TypeError:
                 pass
+            else:
+                self.last = program.name
+                return out
         self.fallback_calls += 1
+        self.last = None
+        if self.on_fallback is not None:
+            self.on_fallback()
         return self.fallback(*args)
 
 

@@ -112,6 +112,7 @@ from deeplearning_mpi_tpu.ops.sparse_attention import (
     select_mask,
     select_topk,
 )
+from deeplearning_mpi_tpu.serving.launch import dispatch, fetch, h2d
 from deeplearning_mpi_tpu.serving.kv_pool import (
     SCRATCH_BLOCK,
     PagedKVPool,
@@ -1218,7 +1219,8 @@ class ServingEngine:
                 "serve_requests_submitted", "serve_requests_admitted",
                 "serve_requests_completed", "serve_requests_shed",
                 "serve_tokens_generated", "serve_prefill_chunks",
-                "serve_decode_steps", "serve_requeued_total",
+                "serve_decode_steps", "serve_program_fallbacks",
+                "serve_requeued_total",
                 "serve_tokens_discarded_total",
                 "serve_gather_blocks", "serve_live_blocks",
                 "serve_window_skipped_blocks", "serve_window_released_blocks",
@@ -1272,9 +1274,6 @@ class ServingEngine:
                 # step's rows routed to, and experts held.
                 registry.counter("serve_moe_experts_touched")
                 registry.counter("serve_moe_expert_slots")
-                # Decode (and verify) steps whose expert layers ran as
-                # batched products (models.moe.dropless_form).
-                registry.counter("serve_moe_batched_steps")
         self._fwd = PagedForward(
             config, engine, dtype,
             tick=lambda: self._inc("serve_compile_total"),
@@ -1410,6 +1409,7 @@ class ServingEngine:
                 )
             return out
 
+        call.__name__ = jitted.__name__  # launch/dispatch's program label: jit_<name>
         return call
 
     def warmup(self, *, cache: Any = None) -> dict[str, Any]:
@@ -1502,22 +1502,27 @@ class ServingEngine:
                 )
         # The table is argument 2 of both programs: its shape picks the
         # executable, so a listed shape never falls through to the jit.
+        def fell() -> None:
+            self._inc("serve_program_fallbacks")
+
         self._decode_fn = aot.WarmProgram(
             {s: programs[n] for s, n in decode_names.items()},
-            self._decode_jit, shape_arg=2,
+            self._decode_jit, shape_arg=2, on_fallback=fell,
         )
         self._prefill_fn = aot.WarmProgram(
             {s: programs[n] for s, n in prefill_names.items()},
-            self._prefill_jit, shape_arg=2,
+            self._prefill_jit, shape_arg=2, on_fallback=fell,
         )
         if self._spec is not None:
             self._verify_fn = aot.WarmProgram(
-                programs["serve_verify_step"], self._verify_jit
+                programs["serve_verify_step"], self._verify_jit,
+                on_fallback=fell,
             )
-            self._spec.adopt_warmup(programs)
+            self._spec.adopt_warmup(programs, on_fallback=fell)
         if self.prefix_cache is not None:
             self._copy_fn = aot.WarmProgram(
-                programs["serve_kv_copy_block"], self._copy_jit
+                programs["serve_kv_copy_block"], self._copy_jit,
+                on_fallback=fell,
             )
         # The verify step and the draft's decode keep max_slots rows and
         # one executable each, at the full table: their narrower widths are
@@ -1672,9 +1677,7 @@ class ServingEngine:
         with span("serve/cow"):
             for src, dst, req in pending:
                 if req.state is RequestState.PREFILL:
-                    self._kv = self._copy_fn(
-                        self._kv, jnp.int32(src), jnp.int32(dst)
-                    )
+                    self._kv = dispatch(self._copy_fn, self._kv, *h2d(src, dst))
                     if self._spec is not None:
                         # The draft's pools ride the same block tables, so
                         # the adopted prefix must exist there too — mirror
@@ -1763,13 +1766,6 @@ class ServingEngine:
             return {}
         return {"moe": dropless_form(n_tokens, cfg.moe_top_k, cfg.moe_experts)}
 
-    def _count_decode_step(self, form: dict[str, str]) -> None:
-        """One decode (or verify) step launched, ``form`` its
-        :meth:`_moe_form`."""
-        self._inc("serve_decode_steps")
-        if form.get("moe") == "batched":
-            self._inc("serve_moe_batched_steps")
-
     def _decode_shape(self, rows: int, blocks: int) -> tuple[int, int]:
         """Static (rows, width) of this step's decode table: of the pairs
         in ``_decode_shapes`` (:func:`_table_shapes`) that hold the ``rows``
@@ -1827,29 +1823,28 @@ class ServingEngine:
             rows=len(decoding), table_rows=rows, width=width,
             skipped=skipped, topk=cfg.attention_topk, **labels, **form,
         ) as launch:
-            tables = np.zeros((rows, width), np.int32)
-            lengths = np.zeros((rows,), np.int32)
-            tokens = np.zeros((rows,), np.int32)
-            active = np.zeros((rows,), bool)
-            for i, req in enumerate(decoding):
-                tables[i, : reach[i]] = handed[i]
-                lengths[i] = req.length
-                tokens[i] = req.generated[-1]
-                active[i] = True
-            tables = jnp.asarray(tables)
-            if self._fwd.mixed:
-                behind = np.zeros((rows, labels["window_width"]), np.int32)
-                for i, blocks in enumerate(held):
-                    behind[i, : len(blocks)] = blocks
-                tables = (tables, jnp.asarray(behind))
-            self._kv, next_tok, touched = self._decode_fn(
-                self.params, self._kv,
-                tables, jnp.asarray(lengths),
-                jnp.asarray(tokens), jnp.asarray(active),
+            with span("launch/prep"):
+                tables = np.zeros((rows, width), np.int32)
+                lengths = np.zeros((rows,), np.int32)
+                tokens = np.zeros((rows,), np.int32)
+                active = np.zeros((rows,), bool)
+                for i, req in enumerate(decoding):
+                    tables[i, : reach[i]] = handed[i]
+                    lengths[i] = req.length
+                    tokens[i] = req.generated[-1]
+                    active[i] = True
+                if self._fwd.mixed:
+                    behind = np.zeros((rows, labels["window_width"]), np.int32)
+                    for i, blocks in enumerate(held):
+                        behind[i, : len(blocks)] = blocks
+                    tables = (tables, behind)
+            self._kv, next_tok, touched = dispatch(
+                self._decode_fn, self.params, self._kv,
+                *h2d(tables, lengths, tokens, active),
             )
             at = [(req.length - 1) // BS for req in decoding]
             self._record_writes({req.blocks[b] for req, b in zip(decoding, at)})
-            self._count_decode_step(form)
+            self._inc("serve_decode_steps")
             self._inc("serve_gather_blocks", rows * width)
             self._inc("serve_live_blocks", sum(reach))
             self._inc("serve_window_skipped_blocks", skipped)
@@ -1879,9 +1874,9 @@ class ServingEngine:
             # in the same fetch costs ~0.1 ms of host time a step on the
             # v5e, so a dense model fetches the tokens alone).
             if cfg.moe_experts:
-                next_np, touched_np = jax.device_get((next_tok, touched))  # dmt-lint: disable=DMT003 — the audited sync
+                next_np, touched_np = fetch((next_tok, touched))  # dmt-lint: disable=DMT003 — the audited sync
             else:
-                next_np = np.asarray(jax.device_get(next_tok))  # dmt-lint: disable=DMT003 — the audited sync
+                next_np = fetch(next_tok)  # dmt-lint: disable=DMT003 — the audited sync
         if cfg.moe_experts:
             self._inc("serve_moe_experts_touched", int(touched_np.sum()))
             self._inc("serve_moe_expert_slots", cfg.num_layers * cfg.moe_experts)
@@ -1908,47 +1903,48 @@ class ServingEngine:
         e = self.engine
         K, BS = e.spec_k, e.block_size
         with span("serve/draft_launch", rows=len(decoding)):
-            tables = np.zeros((e.max_slots, e.max_blocks_per_seq), np.int32)
-            lengths = np.zeros((e.max_slots,), np.int32)
-            last = np.zeros((e.max_slots,), np.int32)
-            n_prop = np.zeros((e.max_slots,), np.int32)
-            active = np.zeros((e.max_slots,), bool)
-            for req in decoding:
-                s = req.slot
-                # Budget: the step emits up to n+1 tokens; never propose past
-                # the request's remaining generation budget (admission already
-                # bounds prompt + max_new to max_seq_len, so the position
-                # ceiling is subsumed).
-                n = min(K, req.max_new_tokens - len(req.generated) - 1)
-                if n > 0:
-                    # Verify writes K/V at positions length-1 .. length-1+n:
-                    # take the extra blocks all-or-nothing from the FREE list
-                    # only. A speculative tail must never evict a peer (the
-                    # mandatory-growth path above handles real pressure);
-                    # on a dry pool the budget degrades to what the already-
-                    # owned blocks cover.
-                    need = self.pool.blocks_for(req.length + n) - len(req.blocks)
-                    if need > 0:
-                        got = self.pool.alloc(need)
-                        if got is None and self.prefix_cache is not None:
-                            # Unreferenced cache branches are cheaper than a
-                            # degraded proposal budget — evict before giving up
-                            # (still never evicting a live peer).
-                            if self.prefix_cache.evict(need - self.pool.available):
-                                got = self.pool.alloc(need)
-                        if got is not None:
-                            req.blocks.extend(got)
-                        else:
-                            n = min(n, len(req.blocks) * BS - req.length)
-                            self._inc("spec_degraded_total")
-                tables[s, : len(req.blocks)] = req.blocks
-                lengths[s] = req.length
-                last[s] = req.generated[-1]
-                n_prop[s] = max(n, 0)
-                active[s] = True
-            tables = tables[
-                :, : self._gather_width(max(len(r.blocks) for r in decoding))
-            ]
+            with span("launch/prep"):
+                tables = np.zeros((e.max_slots, e.max_blocks_per_seq), np.int32)
+                lengths = np.zeros((e.max_slots,), np.int32)
+                last = np.zeros((e.max_slots,), np.int32)
+                n_prop = np.zeros((e.max_slots,), np.int32)
+                active = np.zeros((e.max_slots,), bool)
+                for req in decoding:
+                    s = req.slot
+                    # Budget: the step emits up to n+1 tokens; never propose past
+                    # the request's remaining generation budget (admission already
+                    # bounds prompt + max_new to max_seq_len, so the position
+                    # ceiling is subsumed).
+                    n = min(K, req.max_new_tokens - len(req.generated) - 1)
+                    if n > 0:
+                        # Verify writes K/V at positions length-1 .. length-1+n:
+                        # take the extra blocks all-or-nothing from the FREE list
+                        # only. A speculative tail must never evict a peer (the
+                        # mandatory-growth path above handles real pressure);
+                        # on a dry pool the budget degrades to what the already-
+                        # owned blocks cover.
+                        need = self.pool.blocks_for(req.length + n) - len(req.blocks)
+                        if need > 0:
+                            got = self.pool.alloc(need)
+                            if got is None and self.prefix_cache is not None:
+                                # Unreferenced cache branches are cheaper than a
+                                # degraded proposal budget — evict before giving up
+                                # (still never evicting a live peer).
+                                if self.prefix_cache.evict(need - self.pool.available):
+                                    got = self.pool.alloc(need)
+                            if got is not None:
+                                req.blocks.extend(got)
+                            else:
+                                n = min(n, len(req.blocks) * BS - req.length)
+                                self._inc("spec_degraded_total")
+                    tables[s, : len(req.blocks)] = req.blocks
+                    lengths[s] = req.length
+                    last[s] = req.generated[-1]
+                    n_prop[s] = max(n, 0)
+                    active[s] = True
+                tables = tables[
+                    :, : self._gather_width(max(len(r.blocks) for r in decoding))
+                ]
             props, draft_steps = self._spec.propose(
                 tables, lengths, last, n_prop, active
             )
@@ -1959,14 +1955,14 @@ class ServingEngine:
             "serve/verify_launch", rows=len(decoding), width=tables.shape[1],
             **form,
         ):
-            tokens = np.zeros((e.max_slots, W), np.int32)
-            tokens[:, 0] = last
-            tokens[:, 1:] = props
-            self._kv, greedy = self._verify_fn(
-                self.params, self._kv,
-                jnp.asarray(tables), jnp.asarray(lengths),
-                jnp.asarray(tokens), jnp.asarray(n_prop + 1),
-                jnp.asarray(active),
+            with span("launch/prep"):
+                tokens = np.zeros((e.max_slots, W), np.int32)
+                tokens[:, 0] = last
+                tokens[:, 1:] = props
+                fed = n_prop + 1
+            self._kv, greedy = dispatch(
+                self._verify_fn, self.params, self._kv,
+                *h2d(tables, lengths, tokens, fed, active),
             )
             touched: set[int] = set()
             for req in decoding:
@@ -1975,7 +1971,7 @@ class ServingEngine:
                 hi = min((req.length - 1 + n_fed - 1) // BS, len(req.blocks) - 1)
                 touched.update(req.blocks[lo : hi + 1])
             self._record_writes(touched)
-            self._count_decode_step(form)
+            self._inc("serve_decode_steps")
             self._inc("spec_verify_steps")
             # the target's verify gather; the draft's own are not counted
             self._inc("serve_gather_blocks", e.max_slots * tables.shape[1])
@@ -1983,7 +1979,7 @@ class ServingEngine:
                 "serve_live_blocks", sum(len(r.blocks) for r in decoding)
             )
         with span("serve/verify_fetch"):
-            greedy_np = np.asarray(jax.device_get(greedy))  # [S, W]  # dmt-lint: disable=DMT003 — the audited verify fetch: exact-match acceptance runs on host
+            greedy_np = fetch(greedy)  # [S, W]  # dmt-lint: disable=DMT003 — the audited verify fetch: exact-match acceptance runs on host
         with span("serve/retire") as sp:
             before = len(finished)
             now = self._clock()
@@ -2140,19 +2136,19 @@ class ServingEngine:
             topk=self.config.attention_topk, **labels,
             **self._moe_form(e.prefill_chunk),
         ) as launch:
-            chunk = np.zeros((e.prefill_chunk,), np.int32)
-            chunk[:n_valid] = req.prompt[start : start + n_valid]
-            table = np.zeros((e.max_blocks_per_seq,), np.int32)
-            table[: len(req.blocks)] = req.blocks
-            table = jnp.asarray(table[:width])
-            if self._fwd.mixed:
-                behind = np.zeros((labels["window_width"],), np.int32)
-                behind[: len(held)] = held
-                table = (table, jnp.asarray(behind))
-            self._kv, last_logits = self._prefill_fn(
-                self.params, self._kv,
-                table, jnp.asarray(chunk),
-                jnp.int32(start), jnp.int32(n_valid),
+            with span("launch/prep"):
+                chunk = np.zeros((e.prefill_chunk,), np.int32)
+                chunk[:n_valid] = req.prompt[start : start + n_valid]
+                table = np.zeros((e.max_blocks_per_seq,), np.int32)
+                table[: len(req.blocks)] = req.blocks
+                table = table[:width]
+                if self._fwd.mixed:
+                    behind = np.zeros((labels["window_width"],), np.int32)
+                    behind[: len(held)] = held
+                    table = (table, behind)
+            table, *args = h2d(table, chunk, start, n_valid)
+            self._kv, last_logits = dispatch(
+                self._prefill_fn, self.params, self._kv, table, *args
             )
             self._inc("serve_gather_blocks", width)
             self._inc("serve_live_blocks", reach)
@@ -2189,7 +2185,7 @@ class ServingEngine:
         # from the prefill's last-position logits (same seed-step split as
         # models.generate.first_token).
         with span("serve/first_token_fetch", rid=req.rid):
-            tok = int(jax.device_get(jnp.argmax(last_logits)))  # dmt-lint: disable=DMT003 — audited: the first token must reach the host to enter req.generated
+            tok = int(fetch(jnp.argmax(last_logits)))  # dmt-lint: disable=DMT003 — audited: the first token must reach the host to enter req.generated
         with span("serve/retire") as sp:
             before = len(finished)
             req.state = RequestState.DECODE
@@ -2203,7 +2199,7 @@ class ServingEngine:
                 # only writes positions >= prompt_len, which never land in a
                 # full prefix block, so those pages are frozen. (The partial
                 # tail block is still being written by decode; it is indexed at
-                # _finish.) The device_get above is the proof the writes
+                # _finish.) The fetch above is the proof the writes
                 # landed — insertion after it makes cached pages crash-safe.
                 n_full = req.prompt_len // e.block_size
                 if n_full:

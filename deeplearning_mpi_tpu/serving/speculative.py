@@ -53,6 +53,8 @@ import numpy as np
 
 from deeplearning_mpi_tpu.models.transformer import TransformerConfig
 from deeplearning_mpi_tpu.serving.kv_pool import init_kv_buffers
+from deeplearning_mpi_tpu.serving.launch import dispatch, fetch, h2d
+from deeplearning_mpi_tpu.telemetry.trace import span
 
 __all__ = ["SpeculativeDecoder"]
 
@@ -166,18 +168,24 @@ class SpeculativeDecoder:
                 self._kv, jnp.int32(0), jnp.int32(0),
             )
 
-    def adopt_warmup(self, programs: dict[str, Any]) -> None:
+    def adopt_warmup(
+        self, programs: dict[str, Any],
+        on_fallback: Callable[[], None] | None = None,
+    ) -> None:
         from deeplearning_mpi_tpu.compiler import aot
 
         self._decode_fn = aot.WarmProgram(
-            programs["serve_draft_decode_step"], self._decode_jit
+            programs["serve_draft_decode_step"], self._decode_jit,
+            on_fallback=on_fallback,
         )
         self._prefill_fn = aot.WarmProgram(
-            programs["serve_draft_prefill_chunk"], self._prefill_jit
+            programs["serve_draft_prefill_chunk"], self._prefill_jit,
+            on_fallback=on_fallback,
         )
         if self._copy_jit is not None:
             self._copy_fn = aot.WarmProgram(
-                programs["serve_draft_copy_block"], self._copy_jit
+                programs["serve_draft_copy_block"], self._copy_jit,
+                on_fallback=on_fallback,
             )
 
     def pretrace_width(
@@ -195,7 +203,7 @@ class SpeculativeDecoder:
         """Mirror the target pools' CoW copy in the draft pools (engine
         ``_phase_cow``; same physical block ids — the tables are shared)."""
         assert self._copy_fn is not None, "draft built without prefix_cache"
-        self._kv = self._copy_fn(self._kv, jnp.int32(src), jnp.int32(dst))
+        self._kv = dispatch(self._copy_fn, self._kv, *h2d(src, dst))
 
     def prefill_chunk(
         self,
@@ -207,10 +215,9 @@ class SpeculativeDecoder:
         """Ingest one prompt chunk into the draft's KV pools (same chunk,
         same block table, draft dims); the logits are discarded — the
         target's prefill owns the first generated token."""
-        self._kv, _ = self._prefill_fn(
-            self.params, self._kv,
-            jnp.asarray(table), jnp.asarray(chunk),
-            jnp.int32(start), jnp.int32(n_valid),
+        self._kv, _ = dispatch(
+            self._prefill_fn, self.params, self._kv,
+            *h2d(table, chunk, start, n_valid),
         )
 
     def propose(
@@ -242,15 +249,15 @@ class SpeculativeDecoder:
         last_j = int(budget[act_rows].max()) if act_rows.any() else 0
         steps = 0
         for j in range(min(last_j, K) + 1):
-            act = act_rows & (j <= budget)
-            self._kv, out, _ = self._decode_fn(
-                self.params, self._kv,
-                jnp.asarray(tables),
-                jnp.asarray(lengths + j, dtype=np.int32),
-                jnp.asarray(cur), jnp.asarray(act),
+            with span("launch/prep"):
+                act = act_rows & (j <= budget)
+                at = (lengths + j).astype(np.int32)
+            self._kv, out, _ = dispatch(
+                self._decode_fn, self.params, self._kv,
+                *h2d(tables, at, cur, act),
             )
             steps += 1
-            out_np = np.asarray(jax.device_get(out), np.int32)  # dmt-lint: disable=DMT003 — the draft's one audited fetch per propose step: proposals feed the host-side accept loop
+            out_np = fetch(out).astype(np.int32)  # dmt-lint: disable=DMT003 — the draft's one audited fetch per propose step: proposals feed the host-side accept loop
             if j < K:
                 take = act & (j < budget)
                 props[take, j] = out_np[take]

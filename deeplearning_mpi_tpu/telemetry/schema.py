@@ -54,6 +54,12 @@ METRICS: dict[str, tuple[str, frozenset[str]]] = {
     "serve_compile_total": ("counter", frozenset()),
     "serve_decode_held_steps": ("counter", frozenset()),
     "serve_decode_steps": ("counter", frozenset()),
+    # calls a warmed engine's programs handed to the jit net instead of their
+    # executable (compiler/aot.py:WarmProgram; 0 on a correctly warmed engine
+    # that does not speculate: the verify step and the draft's programs run
+    # their narrower widths pre-traced through the net; the launch/dispatch
+    # span's fallback label says which step fell off)
+    "serve_program_fallbacks": ("counter", frozenset()),
     # blocks the programs' tables gather (rows x width a decode or verify step,
     # width a chunk) against blocks the live rows hold / the chunk can see: their
     # ratio is the fill of the gather (docs/SERVING.md "The fixed-shape step");
@@ -71,14 +77,11 @@ METRICS: dict[str, tuple[str, frozenset[str]]] = {
     "serve_window_released_blocks": ("counter", frozenset()),
     # a selecting model's decode steps: keys its rows hold, keys their queries
     # attend (min(length, topk)); an expert model's: distinct experts a step's
-    # rows routed to, summed over layers, against layers x experts held; and
-    # the decode steps whose expert layers ran as batched products
-    # (models/moe.py:dropless_form says which, from the program's static rows)
+    # rows routed to, summed over layers, against layers x experts held
     "serve_select_live_keys": ("counter", frozenset()),
     "serve_select_kept_keys": ("counter", frozenset()),
     "serve_moe_experts_touched": ("counter", frozenset()),
     "serve_moe_expert_slots": ("counter", frozenset()),
-    "serve_moe_batched_steps": ("counter", frozenset()),
     "serve_handoff_depth": ("gauge", frozenset()),
     "serve_handoff_stalls_total": ("counter", frozenset()),
     "serve_handoffs_total": ("counter", frozenset()),

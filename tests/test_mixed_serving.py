@@ -302,14 +302,67 @@ def test_the_launch_spans_and_counters_count_both_groups(params, monkeypatch):
     assert snap["serve_window_skipped_blocks"] == 0
 
 
+def test_a_launch_hands_over_both_groups_tables_in_one_transfer_span(params, monkeypatch):
+    """Inside every launch of a two-group model (``serving/launch.py``):
+    ``launch/prep``, one ``launch/h2d`` whose ``bytes`` hold the window
+    group's table beside the full group's, and one ``launch/dispatch`` of a
+    warmed executable; inside every fetch ``fetch/ready`` then ``fetch/d2h``
+    (the tokens and the touched-experts count)."""
+    from deeplearning_mpi_tpu.serving import engine as engine_mod
+    from deeplearning_mpi_tpu.serving import launch as launch_mod
+
+    spans = []
+
+    class Span:
+        def __init__(self, name, **labels):
+            self.name, self.labels = name, dict(labels)
+            spans.append(self)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def set_metadata(self, **labels):
+            self.labels.update(labels)
+
+    monkeypatch.setattr(engine_mod, "span", Span)
+    monkeypatch.setattr(launch_mod, "span", Span)
+    engine = _engine(params)
+    programs = set(engine.warmup())
+    _serve(engine, [_prompt(30), _prompt(7)], 6)
+    names = [s.name for s in spans]
+    launches = [i for i, n in enumerate(names) if n in ("serve/decode_launch", "serve/prefill_launch")]
+    assert launches and {names[i] for i in launches} == {"serve/decode_launch", "serve/prefill_launch"}
+    for i in launches:
+        launch, prep, h2d, dispatch = spans[i : i + 4]
+        assert (prep.name, h2d.name, dispatch.name) == ("launch/prep", "launch/h2d", "launch/dispatch")
+        assert dispatch.labels["fallback"] == 0 and dispatch.labels["program"] in programs
+        ww = launch.labels["window_width"]
+        if launch.name == "serve/decode_launch":  # int32 tables, lengths, tokens; bool active
+            rows, width = launch.labels["table_rows"], launch.labels["width"]
+            assert h2d.labels["bytes"] == 4 * rows * (width + ww + 2) + rows
+        else:  # int32 tables, chunk, start, n_valid
+            assert h2d.labels["bytes"] == 4 * (launch.labels["width"] + ww + CHUNK + 2)
+    fetches = [i for i, n in enumerate(names) if n in ("serve/token_fetch", "serve/first_token_fetch")]
+    assert fetches and all(names[i + 1 : i + 3] == ["fetch/ready", "fetch/d2h"] for i in fetches)
+    rows = {i: spans[i].labels["table_rows"] for i in launches if names[i] == "serve/decode_launch"}
+    for i in fetches:  # the tokens; a decode step's touched-experts count per layer beside them
+        if names[i] == "serve/token_fetch":
+            decode = max(j for j in rows if j < i)
+            assert spans[i + 2].labels["bytes"] == 4 * rows[decode] + 4 * engine.config.num_layers
+        else:
+            assert spans[i + 2].labels["bytes"] == 4
+
+
 @pytest.mark.parametrize("slots, form", [(4, "batched"), (2, "grouped")], ids=["four-slots", "two-slots"])
 def test_the_launch_spans_say_which_form_the_expert_layer_took(params, monkeypatch, slots, form):
     """``moe`` on every launch is the form the launched program HAS (its
     jaxpr holds ``ragged_dot`` iff grouped), and the engine builds its
     decode programs in one form, that of ``max_slots`` rows (8 experts at
     top-2: 4 rows are batched, so their 1- and 2-row buckets are not built;
-    2 rows are grouped), so one row and four decode alike;
-    ``serve_moe_batched_steps`` counts the decode steps launched batched."""
+    2 rows are grouped), so one row and four decode alike."""
     from deeplearning_mpi_tpu.models.moe import dropless_form
     from deeplearning_mpi_tpu.serving import engine as engine_mod
 
@@ -344,9 +397,7 @@ def test_the_launch_spans_say_which_form_the_expert_layer_took(params, monkeypat
             engine.params, engine._kv, (i32(width), i32(window_width)), i32(CHUNK), i32(), i32(),
         ))
         assert "ragged_dot" not in text
-    snap = registry.snapshot()
-    assert snap["serve_moe_batched_steps"] == (len(decode) if form == "batched" else 0)
-    assert snap["serve_decode_steps"] == len(decode)
+    assert registry.snapshot()["serve_decode_steps"] == len(decode)
 
 
 # -- what is refused, by name ------------------------------------------------

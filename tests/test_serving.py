@@ -1449,9 +1449,10 @@ _SCOPES = (
 )
 
 
-def _host_spans(trace_dir):
-    """(name, start s, duration s, labels) of every ``serve/`` and ``test/``
-    host event in the newest trace under ``trace_dir``, by start."""
+def _host_spans(trace_dir, prefixes=("serve/", "test/")):
+    """(name, start s, duration s, labels) of every host event in the newest
+    trace under ``trace_dir`` whose name starts with one of ``prefixes``, by
+    start."""
     from jax.profiler import ProfileData
 
     path = max(trace_dir.rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime)
@@ -1461,7 +1462,7 @@ def _host_spans(trace_dir):
             continue
         for line in plane.lines:
             for e in line.events:
-                if e.name.startswith(("serve/", "test/")):
+                if e.name.startswith(prefixes):
                     labels = {k: v for k, v in e.stats}
                     spans.append((e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9, labels))
     return sorted(spans, key=lambda s: s[1])
@@ -1508,7 +1509,7 @@ def step_trace(tiny_lm, tmp_path_factory):
         cfg, params, ENGINE_CFG, dtype=jnp.float32, clock=_time.monotonic,
         registry=registry,
     )
-    engine.warmup()
+    programs = set(engine.warmup())
     engine.submit(np.arange(1, 6, dtype=np.int32), 2)
     engine.run_until_idle()  # the small programs around the engine's own
     compiles = registry.snapshot()["serve_compile_total"]
@@ -1533,6 +1534,8 @@ def step_trace(tiny_lm, tmp_path_factory):
         "spans": spans, "steps": steps, "children": children, "reqs": reqs,
         "t_mark": t_mark, "compiles_before": compiles,
         "compiles_after": registry.snapshot()["serve_compile_total"],
+        "inner": _host_spans(trace_dir, ("launch/", "fetch/")), "programs": programs,
+        "fallbacks": registry.snapshot()["serve_program_fallbacks"], "trace_dir": trace_dir,
     }
 
 
@@ -1634,9 +1637,157 @@ class TestStepSpans:
         finally:
             trace.set_enabled(old)
         assert registry.snapshot()["serve_compile_total"] == warmed
+        assert registry.snapshot()["serve_program_fallbacks"] == 0
         assert [r.generated for r in reqs] == [r.generated for r in step_trace["reqs"]]
         for req, prompt in zip(reqs, _span_prompts()):
             assert req.generated == _offline_greedy(model, params, prompt, req.max_new_tokens)
+
+    # -- inside the launch and the fetch (launch/, fetch/: serving/launch.py) --
+    def test_every_launch_builds_then_hands_over_then_dispatches(self, step_trace):
+        """Inside every decode and prefill launch, one after another:
+        ``launch/prep``, ``launch/h2d`` (``bytes``: the arrays handed over),
+        ``launch/dispatch`` (``program``: the warmed executable of the
+        launch's table; ``fallback`` 0)."""
+        spans, inner = step_trace["spans"], step_trace["inner"]
+        launches = [s for s in spans if s[0] in ("serve/decode_launch", "serve/prefill_launch")]
+        assert {s[0] for s in launches} == {"serve/decode_launch", "serve/prefill_launch"}
+        assert all(any(_inside(e, s) for s in spans if s[0] != "serve/step") for e in inner)
+        for launch in launches:
+            kids = [e for e in inner if _inside(e, launch)]
+            assert [k[0] for k in kids] == ["launch/prep", "launch/h2d", "launch/dispatch"], launch
+            for a, b in zip(kids, kids[1:]):
+                assert a[1] + a[2] <= b[1]
+            prep, h2d, dispatch = (k[3] for k in kids)
+            labels = launch[3]
+            assert prep == {} and dispatch["fallback"] == 0 and dispatch["program"] in step_trace["programs"]
+            if launch[0] == "serve/decode_launch":  # int32 tables, lengths and tokens, bool active
+                rows, width = labels["table_rows"], labels["width"]
+                assert h2d["bytes"] == 4 * rows * (width + 2) + rows
+                assert dispatch["program"] in (f"serve_decode_step@{rows}x{width}", "serve_decode_step")
+            else:  # int32 table, chunk, start and n_valid
+                assert h2d["bytes"] == 4 * (labels["width"] + ENGINE_CFG.prefill_chunk + 2)
+                assert dispatch["program"] in (f"serve_prefill_chunk@{labels['width']}", "serve_prefill_chunk")
+
+    def test_every_fetch_waits_then_copies_back(self, step_trace):
+        """Inside every token fetch: ``fetch/ready`` then ``fetch/d2h``, whose
+        ``bytes`` is the int32 tokens of the launch's table rows (one for a
+        first token)."""
+        spans, inner = step_trace["spans"], step_trace["inner"]
+        decode = [s for s in spans if s[0] == "serve/decode_launch"]
+        fetches = [s for s in spans if s[0] in ("serve/token_fetch", "serve/first_token_fetch")]
+        assert len([f for f in fetches if f[0] == "serve/token_fetch"]) == len(decode)
+        assert len([f for f in fetches if f[0] == "serve/first_token_fetch"]) == len(step_trace["reqs"])
+        rows = iter(s[3]["table_rows"] for s in decode)
+        for fetch in fetches:
+            kids = [e for e in inner if _inside(e, fetch)]
+            assert [k[0] for k in kids] == ["fetch/ready", "fetch/d2h"], fetch
+            assert kids[0][1] + kids[0][2] <= kids[1][1]
+            assert kids[1][3]["bytes"] == 4 * (next(rows) if fetch[0] == "serve/token_fetch" else 1)
+
+    def test_the_idle_reader_nests_the_engines_own_trace(self, step_trace):
+        """``benchmark/readers/serve_idle.py`` on the same trace: every step,
+        with the launch's three children and the fetch's two under them."""
+        from benchmark.readers import serve_idle
+
+        host = serve_idle.host_side(step_trace["trace_dir"])
+        assert [st.start for st in host.steps] == pytest.approx([st[1] for st in step_trace["steps"]])
+        assert host.has("launch/") and host.has("fetch/")
+        children = {
+            "serve/decode_launch": ["launch/prep", "launch/h2d", "launch/dispatch"],
+            "serve/prefill_launch": ["launch/prep", "launch/h2d", "launch/dispatch"],
+            "serve/token_fetch": ["fetch/ready", "fetch/d2h"],
+            "serve/first_token_fetch": ["fetch/ready", "fetch/d2h"],
+        }
+        for st in host.steps:
+            for path, node in st.walk():
+                if len(path) == 2:  # a phase: the launches and the fetches hold the new spans, no other phase any
+                    assert [c.name for c in node.children] == children.get(node.name, [])
+                assert len(path) < 3 or node.children == []
+
+    def test_a_program_off_its_executable_is_counted_and_labelled(self, tiny_lm, tmp_path):
+        """``serve_program_fallbacks`` counts every call ``WarmProgram`` hands
+        to its jit net, and that launch's ``launch/dispatch`` says so."""
+        cfg, _, params = tiny_lm
+        registry = MetricsRegistry()
+        engine = ServingEngine(cfg, params, ENGINE_CFG, dtype=jnp.float32, registry=registry)
+        engine.warmup()
+        engine._prefill_fn.program = {}  # no executable for any table: every chunk takes the net
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            engine.submit(np.arange(1, 8, dtype=np.int32), 2)  # two chunks of 4
+            engine.run_until_idle()
+        finally:
+            jax.profiler.stop_trace()
+        snap = registry.snapshot()
+        assert snap["serve_prefill_chunks"] == 2 == snap["serve_program_fallbacks"]
+        dispatched = [(e[3]["program"], e[3]["fallback"]) for e in _host_spans(tmp_path, ("launch/dispatch",))]
+        assert [d for d in dispatched if d[1]] == [("jit_prefill_chunk", 1)] * 2
+        assert len(dispatched) > 2 and all(p.startswith("serve_decode_step") for p, f in dispatched if not f)
+
+    def test_the_speculative_and_copy_on_write_launches_hold_the_same_children(self, tiny_lm, tmp_path):
+        """A speculating engine with a prefix cache: every ``serve/cow``
+        holds a transfer and a dispatch for the target's pools and again for
+        the draft's; every ``serve/verify_launch`` and every propose step of
+        ``serve/draft_launch`` prep, h2d and dispatch in order, each
+        propose step's fetch inside the draft launch; every
+        ``serve/verify_fetch`` ``fetch/ready`` then ``fetch/d2h``. The
+        verify step and the draft's programs have one executable each, at
+        the full table: their narrower widths run pre-traced through the jit
+        net, so those dispatches say ``fallback`` 1 and are what
+        ``serve_program_fallbacks`` counts; the tokens are the untraced
+        engine's."""
+        from deeplearning_mpi_tpu.telemetry import trace
+
+        rng = np.random.default_rng(38)
+        preamble = rng.integers(1, 255, size=SHARED_PREAMBLE_LEN).astype(np.int32)
+        prompts = [np.concatenate([preamble, rng.integers(1, 255, size=3).astype(np.int32)]) for _ in range(3)]
+
+        def serve(traced):
+            registry = MetricsRegistry()
+            engine = _spec_engine(
+                tiny_lm, spec_k=2, base_cfg=dataclasses.replace(ENGINE_CFG, prefix_cache=True), registry=registry,
+            )
+            programs = set(engine.warmup())
+            old = trace.set_enabled(traced)
+            try:
+                reqs = [engine.submit(prompts[0], MAX_NEW)]
+                engine.run_until_idle()  # the preamble is cached from here on: the next two adopt it and copy on write
+                reqs += [engine.submit(p, MAX_NEW) for p in prompts[1:]]
+                engine.run_until_idle()
+            finally:
+                trace.set_enabled(old)
+            return [r.generated for r in reqs], registry.snapshot(), programs
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            tokens, snap, programs = serve(True)
+        finally:
+            jax.profiler.stop_trace()
+        assert tokens == serve(False)[0]
+        spans, inner = _host_spans(tmp_path), _host_spans(tmp_path, ("launch/", "fetch/"))
+
+        def kids(name):
+            out = [[e for e in inner if _inside(e, s)] for s in spans if s[0] == name]
+            assert out, name
+            return out
+
+        launch = ["launch/prep", "launch/h2d", "launch/dispatch"]
+        for ks in kids("serve/cow"):  # two copies a copy-on-write (target, draft), both adopters in one phase
+            assert [k[0] for k in ks] == ["launch/h2d", "launch/dispatch"] * 2 * (len(ks) // 4) and ks
+        assert all([k[0] for k in ks] == launch for ks in kids("serve/verify_launch"))
+        assert all([k[0] for k in ks] == ["fetch/ready", "fetch/d2h"] for ks in kids("serve/verify_fetch"))
+        for ks in kids("serve/draft_launch"):  # the budget's own prep, then one launch and one fetch a propose step
+            names = [k[0] for k in ks]
+            assert names[0] == "launch/prep" and len(names) > 1
+            assert names[1:] == (launch + ["fetch/ready", "fetch/d2h"]) * ((len(names) - 1) // 5)
+        dispatched = [e[3] for e in inner if e[0] == "launch/dispatch"]
+        assert {d["program"] for d in dispatched if not d["fallback"]} <= programs
+        assert {d["program"] for d in dispatched if d["fallback"]} <= {"jit_verify_step", "jit_decode_step", "jit_prefill_chunk"}
+        assert sum(d["fallback"] for d in dispatched) == snap["serve_program_fallbacks"] > 0
 
     @staticmethod
     def _program(engine, program):
