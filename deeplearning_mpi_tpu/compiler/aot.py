@@ -36,6 +36,7 @@ policy via ``runtime/compat.buffer_donation_supported``).
 from __future__ import annotations
 
 import dataclasses
+import re
 import time
 from typing import Any, Callable
 
@@ -49,6 +50,7 @@ __all__ = [
     "WarmProgram",
     "WarmupRegistry",
     "abstractify",
+    "collective_counts",
     "compile_program",
     "mosaic_call_count",
 ]
@@ -60,6 +62,55 @@ def mosaic_call_count(compiled: Any) -> int:
     entry points return their XLA references without a word when a block
     does not tile, and the interpreter lowers to plain HLO (count 0)."""
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+_COLLECTIVES = (
+    "all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+    "collective-permute",
+)
+#: the opcode of an HLO instruction line: the first lowercase word after the
+#: ``=`` that opens a parenthesis (layouts' ``T(8,128)`` are not preceded by
+#: a space, tuple types' element types open brackets).
+_OPCODE = re.compile(r"=\s.*?\s([a-z][a-z0-9-]*)\(")
+
+
+def collective_counts(compiled: Any) -> tuple[int, int]:
+    """``(asynchronous, synchronous)`` collectives of a compiled executable's
+    scheduled HLO — the evidence that the train step's compile options
+    engaged (``train.trainer.step_compiler_options``).
+
+    Asynchronous: the starts of the TPU compiler's async collective fusions
+    (``async-collective-start``: a collective split into steps that run
+    inside the computations scheduled between its start and its done) and of
+    plain async collectives (``all-reduce-start`` and kin). Synchronous: a
+    collective instruction outside any fused computation, which holds the
+    device until it ends. The collectives inside fused computations are the
+    steps of an async fusion, so they count once, at its start."""
+    fused: set[str] = set()
+    instructions: list[tuple[str, str, str]] = []  # computation, name, opcode
+    computation = None
+    for line in compiled.as_text().splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            words = line.split()
+            computation = words[1 if words[0] == "ENTRY" else 0].lstrip("%")
+        elif line.startswith(" ") and computation is not None:
+            m = _OPCODE.search(line)
+            if m is None:
+                continue
+            name = line.split("=", 1)[0].split()[-1].lstrip("%")
+            instructions.append((computation, name, m.group(1)))
+            if m.group(1) == "fusion":
+                fused.update(re.findall(r"calls=%([\w.\-]+)", line))
+    n_async = n_sync = 0
+    for computation, name, opcode in instructions:
+        if computation in fused:
+            continue
+        if opcode in _COLLECTIVES:
+            n_sync += 1
+        elif (opcode.removesuffix("-start") in _COLLECTIVES
+              or name.startswith("async-collective-start")):
+            n_async += 1
+    return n_async, n_sync
 
 
 def abstractify(tree: Any) -> Any:

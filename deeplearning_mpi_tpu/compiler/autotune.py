@@ -804,7 +804,7 @@ def tune_step_schedule(
         return make_train_step(
             model, donate=cand.get("donate", True),
             grad_accum=cand.get("grad_accum", 1),
-            state_shardings=shardings,
+            state_shardings=shardings, mesh=mesh,
         )
 
     def run(cand: dict[str, Any]) -> list[float]:

@@ -47,6 +47,11 @@ METRICS: dict[str, tuple[str, frozenset[str]]] = {
     "train_batch_devices": ("gauge", frozenset()),
     "train_state_devices": ("gauge", frozenset()),
     "train_step_mosaic_calls": ("gauge", frozenset()),
+    # collectives of the compiled train step (compiler/aot.py:
+    # collective_counts): async collective starts, and collectives that hold
+    # the device until they end
+    "train_step_async_collectives": ("gauge", frozenset()),
+    "train_step_sync_collectives": ("gauge", frozenset()),
     "xla_bytes_per_step": ("gauge", frozenset()),
     "xla_flops_per_step": ("gauge", frozenset()),
     # -- serving engine (PR 2/7/9, serving/) --------------------------------

@@ -6,6 +6,10 @@ shifted. The spelling it replaced, ``-take_along_axis(log_softmax(f32(logits
 [:, :-1])), labels)``, stays here as the reference: same values and
 gradients, but it wrote a float32 log-prob tensor forward, scattered into a
 zero-filled logits-sized buffer backward and copied an S-1-row slice.
+
+The file also holds the suite's compiles for a described v5e (the head and
+loss at ``lm-train-8k``'s shape; the train step's gradient reductions on a
+2x2 data mesh), which must stay in one file.
 """
 
 import contextlib
@@ -194,17 +198,22 @@ def test_gradient_has_no_scatter_or_gather_and_saves_no_f32_logits():
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A described (not attached) v5e chip to compile for; see the
-    ``on-chip-measurement`` guide for why this is a fixture."""
+def v5e_2x2():
+    """A described (not attached) v5e host of four chips to compile for.
+    Describing it loads libtpu into the test process, so it is built once per
+    module, and every described compile of the suite lives in this one file."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from describing it
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return jax.sharding.SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @contextlib.contextmanager
@@ -248,3 +257,101 @@ def test_head_and_loss_compile_for_v5e_without_a_logits_sized_detour(one_chip):
         assert shape not in text, shape
     assert compiled.cost_analysis()["bytes accessed"] < 4.6e9
     assert compiled.memory_analysis().temp_size_in_bytes < 1.1e9
+
+
+# A small LM whose step compiles for the chip in seconds: 2 layers, published
+# Mistral head width, the sharded flash kernel at a sequence it tiles.
+_LM = dict(vocab_size=2048, num_layers=2, num_heads=4, num_kv_heads=2, head_dim=128,
+           d_model=512, d_ff=2048, attention_window=512)
+_SEQ = 1024
+
+
+def _described_step_args(mesh, monkeypatch):
+    """The abstract train state and batch of the small LM, placed on ``mesh``
+    (described devices) as ``Trainer.place_state`` would: replicated state,
+    one row a device on ``data``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deeplearning_mpi_tpu.models import TransformerConfig, TransformerLM
+    from deeplearning_mpi_tpu.parallel import infer_state_sharding, make_flash_attention_fn
+    from deeplearning_mpi_tpu.train import TrainState
+    from deeplearning_mpi_tpu.train.trainer import build_optimizer
+
+    # The flash kernel picks Mosaic by the default backend, which is the CPU here.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = TransformerLM(config=TransformerConfig(**_LM), dtype=jnp.bfloat16,
+                          attention_fn=make_flash_attention_fn(mesh))
+    tx = build_optimizer("adam", 3e-4, clip_norm=1.0)
+
+    def make():
+        params = model.init(jax.random.key(0), jnp.zeros((1, _SEQ), jnp.int32))["params"]
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params, batch_stats={},
+                          opt_state=tx.init(params), apply_fn=model.apply, tx=tx)
+
+    abstract = jax.eval_shape(make)
+    shardings = infer_state_sharding(abstract, mesh, zero=False)
+    state = jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s), abstract, shardings)
+    rows = mesh.devices.size
+    batch = {"tokens": jax.ShapeDtypeStruct((rows, _SEQ), jnp.int32, sharding=NamedSharding(mesh, P("data")))}
+    return state, batch
+
+
+def _entry(text):
+    start = text.index("\nENTRY ")
+    return text[start:text.index("\n}\n", start)]
+
+
+def test_dp_train_step_reduces_every_gradient_inside_an_async_fusion_on_v5e(v5e_2x2, monkeypatch):
+    """On four described chips with data 4, ``Trainer.warmup`` compiles the
+    step with the TPU options: every weight gradient's all-reduce is a step
+    of an async collective fusion (inside a fused computation), none is a
+    bare ``all-reduce`` in the entry computation, and the gauges read what
+    the HLO holds."""
+    from deeplearning_mpi_tpu.compiler import aot
+    from deeplearning_mpi_tpu.runtime.mesh import MeshSpec, create_mesh
+    from deeplearning_mpi_tpu.telemetry.registry import MetricsRegistry
+    from deeplearning_mpi_tpu.train import Trainer
+
+    mesh = create_mesh(MeshSpec(data=4), devices=list(v5e_2x2.devices))
+    state, batch = _described_step_args(mesh, monkeypatch)
+    trainer = Trainer(state, "lm", mesh, metrics=MetricsRegistry(), logger=None)
+    with _no_compile_cache():
+        prog = trainer.warmup(batch)
+    text = prog.compiled.as_text()
+    entry = _entry(text)
+    assert "tpu_custom_call" in text  # the flash kernel, as on the chip
+    for layer in range(_LM["num_layers"]):
+        for proj in ("gate_proj", "up_proj", "down_proj"):
+            op = f"layer_{layer}/mlp/{proj}/dot_general"
+            reductions = [l for l in text.splitlines() if " all-reduce(" in l and op in l]
+            assert reductions, op
+            assert not [l for l in entry.splitlines() if " all-reduce(" in l and op in l], op
+    n_async, n_sync = aot.collective_counts(prog.compiled)
+    starts = [l for l in entry.splitlines() if l.lstrip().startswith(("%async-collective-start", "ROOT %async-collective-start"))]
+    bare = [l for l in entry.splitlines() if " all-reduce(" in l or " all-gather(" in l or " reduce-scatter(" in l]
+    assert n_async == len(starts) >= 2 * 3 * _LM["num_layers"]
+    assert n_sync == len(bare)
+    assert trainer.metrics.gauge("train_step_async_collectives").value == n_async
+    assert trainer.metrics.gauge("train_step_sync_collectives").value == n_sync
+
+
+def test_one_chip_train_step_hlo_is_unchanged_by_the_tpu_options(v5e_2x2, monkeypatch):
+    """On one described chip the step holds no collective, and the options
+    change nothing: the optimized HLO with them equals the HLO without,
+    metadata stripped."""
+    import re
+
+    from deeplearning_mpi_tpu.runtime.mesh import MeshSpec, create_mesh
+    from deeplearning_mpi_tpu.train.trainer import make_train_step, step_compiler_options
+
+    mesh = create_mesh(MeshSpec(data=1), devices=[v5e_2x2.devices[0]])
+    assert step_compiler_options(mesh.devices.flat)
+    state, batch = _described_step_args(mesh, monkeypatch)
+    strip = lambda t: re.sub(r", metadata=\{[^}]*\}", "", t)  # noqa: E731
+    with _no_compile_cache():
+        texts = [
+            strip(make_train_step("lm", donate=False, **kw).lower(state, batch).compile().as_text())
+            for kw in ({"mesh": mesh}, {})
+        ]
+    assert "tpu_custom_call" in texts[0] and " all-reduce(" not in texts[0]
+    assert texts[0] == texts[1]

@@ -686,3 +686,77 @@ class TestSegLossSelector:
             state, metrics = step(state, batch)
             losses.append(float(metrics["loss"]))
         assert losses[-1] < losses[0]
+
+
+class TestStepCompilerOptions:
+    """The TPU compile options of the train step are chosen from the devices
+    the step is placed on; XLA:CPU refuses them, so a CPU step gets none."""
+
+    @pytest.mark.parametrize(
+        "platforms, tpu",
+        [(("tpu",) * 4, True), (("tpu",), True), (("cpu",) * 8, False),
+         (("tpu", "cpu"), False), ((), False)],
+        ids=["four_tpus", "one_tpu", "cpus", "mixed", "no_devices"],
+    )
+    def test_options_only_when_every_device_is_a_tpu(self, platforms, tpu):
+        from types import SimpleNamespace
+
+        from deeplearning_mpi_tpu.train.trainer import (
+            TPU_STEP_COMPILER_OPTIONS,
+            step_compiler_options,
+        )
+
+        devices = [SimpleNamespace(platform=p) for p in platforms]
+        assert step_compiler_options(devices) == (
+            TPU_STEP_COMPILER_OPTIONS if tpu else None
+        )
+
+    @pytest.mark.parametrize("zero", [False, True], ids=["replicated", "zero1"])
+    def test_cpu_step_compiles_runs_and_holds_no_async_collective(self, mesh, zero):
+        """On the 8-device CPU mesh the LM step, told its mesh, compiles
+        without a TPU option (XLA:CPU would refuse one), runs, and its
+        compiled HLO holds the gradient reductions as plain (synchronous)
+        collectives; with ZeRO-1 state as well."""
+        from deeplearning_mpi_tpu.compiler import aot
+        from deeplearning_mpi_tpu.models import TransformerConfig, TransformerLM
+        from deeplearning_mpi_tpu.parallel import infer_state_sharding, shard_state
+
+        model = TransformerLM(config=TransformerConfig.tiny(), dtype=jnp.float32)
+        state = shard_state(create_train_state(
+            model, jax.random.key(0), jnp.zeros((1, 16), jnp.int32), optax.adam(1e-2),
+        ), mesh, zero=zero)
+        kw = {"mesh": mesh}
+        if zero:
+            kw["state_shardings"] = infer_state_sharding(state, mesh, zero=True)
+        batch = {"tokens": jax.device_put(
+            jnp.asarray(np.random.default_rng(0).integers(0, 256, (8, 16)), jnp.int32),
+            batch_sharding(mesh, 2),
+        )}
+        compiled = make_train_step("lm", donate=False, **kw).lower(state, batch).compile()
+        n_async, n_sync = aot.collective_counts(compiled)
+        assert n_async == 0 and n_sync > 0
+        new_state, metrics = compiled(state, batch)
+        assert np.isfinite(float(metrics["loss"]))
+        assert int(new_state.step) == 1
+
+    def test_warmup_gauges_read_the_cpu_step(self, mesh):
+        from deeplearning_mpi_tpu.compiler import aot
+        from deeplearning_mpi_tpu.models import TransformerConfig, TransformerLM
+
+        model = TransformerLM(config=TransformerConfig.tiny(), dtype=jnp.float32)
+        state = create_train_state(
+            model, jax.random.key(0), jnp.zeros((1, 16), jnp.int32), optax.sgd(1e-2),
+        )
+        trainer = Trainer(state, "lm", mesh)
+        trainer.place_state()
+        batch = {"tokens": jax.device_put(
+            jnp.asarray(np.random.default_rng(0).integers(0, 256, (8, 16)), jnp.int32),
+            batch_sharding(mesh, 2),
+        )}
+        prog = trainer.warmup(batch)
+        assert trainer.metrics.gauge("train_step_async_collectives").value == 0
+        assert (trainer.metrics.gauge("train_step_sync_collectives").value
+                == aot.collective_counts(prog.compiled)[1] > 0)
+        _, metrics = trainer.train_step(trainer.state, batch)
+        assert np.isfinite(float(metrics["loss"]))
+        assert trainer.train_step.fallback_calls == 0
