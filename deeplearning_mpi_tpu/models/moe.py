@@ -43,6 +43,13 @@ and the serving engine builds its decode programs in one form
 Both: f32 router. Routing decisions (softmax + top-k) are computed in
 float32; bf16 router logits flip top-k order at scale.
 
+:func:`dropless_moe` also routes as DeepSeek-V3 does (:class:`Routing`):
+sigmoid scores, a correction bias that picks the top-k but does not weigh
+them, a routed scaling factor, a shared expert every token runs; and it
+holds a SHARE of the layer's experts, as expert parallelism places them: the
+router scores all of them, the claims on experts held elsewhere are not
+computed here, and the result is this share's part of the layer.
+
 The layers slot into :class:`~deeplearning_mpi_tpu.models.transformer.Block`
 via its ``mlp_cls`` injection point (same positional ``(d_ff, dtype)``
 signature as ``SwiGLU``), so a dense LM becomes an MoE LM by configuration.
@@ -50,6 +57,7 @@ signature as ``SwiGLU``), so a dense LM becomes an MoE LM by configuration.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import Any
@@ -88,7 +96,9 @@ def mlp_cls_from_config(config: Any) -> Any:
         return None
     if getattr(config, "moe_routing", "token_choice") == "dropless":
         return functools.partial(
-            DroplessMoE, num_experts=config.moe_experts, top_k=config.moe_top_k
+            DroplessMoE, num_experts=config.moe_experts, top_k=config.moe_top_k,
+            routing=routing_from_config(config),
+            shared_width=config.moe_shared_experts * config.mlp_width,
         )
     return functools.partial(
         MoEMLP,
@@ -285,6 +295,33 @@ class MoEMLP(nn.Module):
             )
 
 
+@dataclasses.dataclass(frozen=True)
+class Routing:
+    """How :func:`dropless_moe` routes, beyond the top-k. The default is
+    softmax over the experts it holds, the top-k renormalised."""
+
+    #: ``'softmax'``: ``p = softmax(logits)``, the top-k of ``p`` weighted by
+    #: ``p`` renormalised. ``'sigmoid'`` (DeepSeek-V3, ``noaux_tc`` with one
+    #: group): ``s = sigmoid(logits)``, the top-k of ``s + bias`` chosen and
+    #: weighted by ``s`` renormalised: the bias picks, never weighs.
+    scoring: str = "softmax"
+    #: factor on the renormalised gates (``routed_scaling_factor``)
+    scale: float = 1.0
+    #: experts the router scores (0: those held, all of the layer's)
+    experts: int = 0
+    #: the first expert held here: experts ``first .. first + held - 1`` of
+    #: the router's are the ``[E, ...]`` weights handed in
+    first: int = 0
+
+
+def routing_from_config(config: Any) -> Routing:
+    """The :class:`Routing` of a ``TransformerConfig``."""
+    return Routing(
+        config.moe_scoring, config.moe_routed_scale,
+        config.moe_router_experts, config.moe_first_expert,
+    )
+
+
 #: The most rows the batched form of :func:`dropless_moe` takes: a bound under
 #: which its three products stay ahead of the grouped ones. Measured on a TPU
 #: v5e in bf16, one whole layer, ms (PERF.md section 6, PR 37), grouped /
@@ -306,8 +343,10 @@ def dropless_form(n_tokens: int, top_k: int, n_experts: int) -> str:
     from the static shapes alone: ``'batched'`` iff the rows' claims are at
     least as many as the experts (nearly every expert is read whatever is
     done) and the rows are at most :data:`BATCHED_MAX_ROWS`; else
-    ``'grouped'``. One function for the program and for the host that
-    labels its launches."""
+    ``'grouped'``. ``n_experts`` is the ROUTER's width: of a share, the
+    claims spread over every expert of the layer, and only the share of them
+    that lands here touches a held one. One function for the program and for
+    the host that labels its launches."""
     if n_tokens * top_k >= n_experts and n_tokens <= BATCHED_MAX_ROWS:
         return "batched"
     return "grouped"
@@ -340,9 +379,15 @@ def _batched_experts(
     return y, jnp.sum(jnp.any(chosen, axis=1)).astype(jnp.int32)
 
 
+def _swiglu(x: jax.Array, mlp: Any, dtype: Any) -> jax.Array:
+    """``down(silu(gate x) * up x)`` over a ``{gate,up,down}_proj/kernel`` tree."""
+    lin = lambda a, name: a.astype(dtype) @ mlp[name]["kernel"].astype(dtype)  # noqa: E731
+    return lin(jax.nn.silu(lin(x, "gate_proj")) * lin(x, "up_proj"), "down_proj")
+
+
 def dropless_moe(
     x: jax.Array,        # [N, d] tokens
-    router: jax.Array,   # [d, E]
+    router: jax.Array,   # [d, R]: R = routing.experts, else E
     w_gate: jax.Array,   # [E, d, f]
     w_up: jax.Array,     # [E, d, f]
     w_down: jax.Array,   # [E, f, d]
@@ -350,14 +395,26 @@ def dropless_moe(
     top_k: int,
     dtype: Any,
     live: jax.Array | None = None,  # [N] bool: rows that are real tokens
+    routing: Routing = Routing(),
+    bias: jax.Array | None = None,  # [R]: the sigmoid router's correction
+    shared: Any = None,  # {gate,up,down}_proj/kernel of the shared expert
+    count_claims: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Dropless top-k mixture of SwiGLU experts over a flat batch of tokens.
 
     ``p = softmax_f32(x @ router)``; the ``top_k`` largest renormalised to sum
     1; ``y = sum_e g_e * down_e(silu(gate_e x) * up_e x)``. Rows with ``live``
-    false (padding) claim no expert and yield zeros. Each expert's output is
+    false (padding) claim no expert and yield zeros. ``routing`` may route as
+    DeepSeek-V3 does instead (:class:`Routing`: sigmoid scores, ``bias`` to
+    pick by, a factor on the gates) and over a router wider than the ``E``
+    experts held here: a claim on an expert held elsewhere goes where a
+    padding row's claims go, past the last held expert, so that nothing
+    computes, reads or counts it; ``y`` is then this share's part of the
+    layer. ``shared`` adds the shared expert, which every row runs (a
+    padding row too: its output is never read). Each expert's output is
     rounded to ``dtype`` and the weighted sum runs in float32, in either of
-    two forms chosen by :func:`dropless_form` from ``N``, ``top_k`` and ``E``:
+    two forms chosen by :func:`dropless_form` from ``N``, ``top_k`` and the
+    router's width:
 
     - *grouped*: the ``N * top_k`` claims are sorted by expert (stable: by
       token within an expert) and each of the three matrix products is ONE
@@ -373,18 +430,51 @@ def dropless_moe(
     the batch; across the forms it agrees to rounding.
 
     Returns ``(y [N, d] in x's dtype, touched)``: ``touched`` is the int32
-    count of distinct experts the live rows routed to, in both forms.
+    count of distinct held experts the live rows routed to, in both forms;
+    with ``count_claims`` it is ``[touched, claims on held experts]``.
     """
-    n_tok, n_exp = x.shape[0], router.shape[-1]
+    n_tok, n_exp, n_held = x.shape[0], router.shape[-1], w_gate.shape[0]
+    if n_exp != (routing.experts or n_held):
+        raise ValueError(f"a router of {n_exp} experts for routing over {routing.experts or n_held}")
     with annotate("moe/route"):
         logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
-        gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        if routing.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+            gates = jnp.take_along_axis(scores, experts, axis=-1)
+        else:
+            gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        if routing.scale != 1.0:
+            gates = gates * routing.scale
+        if n_exp != n_held:
+            # the share: a claim on an expert held elsewhere is not this
+            # chip's to serve
+            held = experts - routing.first
+            experts = jnp.where((held >= 0) & (held < n_held), held, n_held)
         if live is not None:
             # A padding row's claims go past the last expert: no expert
             # computes them, none is read or counted for them.
-            experts = jnp.where(live[:, None], experts, n_exp)
-    if dropless_form(n_tok, top_k, n_exp) == "batched":
+            experts = jnp.where(live[:, None], experts, n_held)
+    y, touched = _experts(x, gates, experts, w_gate, w_up, w_down, dtype, top_k, n_exp)
+    if shared is not None:
+        with annotate("moe/shared"):
+            y = (y.astype(jnp.float32) + _swiglu(x, shared, dtype).astype(jnp.float32)).astype(x.dtype)
+    if count_claims:
+        touched = jnp.stack([touched, jnp.sum(experts < n_held).astype(jnp.int32)])
+    return y, touched
+
+
+def _experts(
+    x: jax.Array, gates: jax.Array, experts: jax.Array,
+    w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+    dtype: Any, top_k: int, n_router: int,
+) -> tuple[jax.Array, jax.Array]:
+    """The routed experts' products and weighted sum in the form
+    :func:`dropless_form` gives; ``experts`` holds held ids, ``E`` where a
+    claim is not served here."""
+    n_tok, n_exp = x.shape[0], w_gate.shape[0]
+    if dropless_form(n_tok, top_k, n_router) == "batched":
         return _batched_experts(x, gates, experts, w_gate, w_up, w_down, dtype)
     with annotate("moe/route"):
         claim_expert = experts.reshape(-1)
@@ -420,17 +510,37 @@ class DroplessMoE(nn.Module):
     dtype: Any = jnp.bfloat16
     num_experts: int = 8
     top_k: int = 2
+    routing: Routing = Routing()
+    #: width of the shared expert (0: none), ``shared/{gate,up,down}_proj``
+    shared_width: int = 0
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         batch, seq, d_model = x.shape
         init = nn.initializers.lecun_normal()
-        # a one-leaf tree, so the kernel sits at ``router/kernel`` as MoEMLP's
-        # ``nn.Dense(name="router")`` puts it; dropless_moe applies it itself
+        sigmoid = self.routing.scoring == "sigmoid"
+
+        def router_init(key: jax.Array, shape: tuple[int, int]) -> dict[str, jax.Array]:
+            # the kernel sits at ``router/kernel`` as MoEMLP's
+            # ``nn.Dense(name="router")`` puts it, the sigmoid router's
+            # correction beside it; dropless_moe applies them itself
+            tree = {"kernel": init(key, shape, jnp.float32)}
+            if sigmoid:
+                tree["bias"] = jnp.zeros(shape[-1:], jnp.float32)
+            return tree
+
         router = self.param(
-            "router", lambda key, shape: {"kernel": init(key, shape, jnp.float32)},
-            (d_model, self.num_experts),
-        )["kernel"]
+            "router", router_init, (d_model, self.routing.experts or self.num_experts)
+        )
+        shared = None
+        if self.shared_width:
+            def shared_init(key: jax.Array) -> dict[str, Any]:
+                keys = jax.random.split(key, 3)
+                shapes = {"gate_proj": (d_model, self.shared_width), "up_proj": (d_model, self.shared_width),
+                          "down_proj": (self.shared_width, d_model)}
+                return {n: {"kernel": init(k, s, jnp.float32)} for k, (n, s) in zip(keys, shapes.items())}
+
+            shared = self.param("shared", shared_init)
         shape_in = (self.num_experts, d_model, self.d_ff)
         w_gate = self.param("experts_gate", init, shape_in, jnp.float32)
         w_up = self.param("experts_up", init, shape_in, jnp.float32)
@@ -438,7 +548,8 @@ class DroplessMoE(nn.Module):
             "experts_down", init, (self.num_experts, self.d_ff, d_model), jnp.float32
         )
         y, _ = dropless_moe(
-            x.reshape(batch * seq, d_model), router, w_gate, w_up, w_down,
-            top_k=self.top_k, dtype=self.dtype,
+            x.reshape(batch * seq, d_model), router["kernel"], w_gate, w_up, w_down,
+            top_k=self.top_k, dtype=self.dtype, routing=self.routing,
+            bias=router.get("bias"), shared=shared,
         )
         return y.reshape(batch, seq, d_model)

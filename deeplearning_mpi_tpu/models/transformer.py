@@ -38,6 +38,7 @@ from deeplearning_mpi_tpu.ops.attention import (
     dense_attention,
     repeat_kv,
 )
+from deeplearning_mpi_tpu.ops.latent_attention import expanded_attention
 from deeplearning_mpi_tpu.ops.sparse_attention import sparse_attention
 
 # (q, k, v [B,S,H,D], causal=...) -> context [B,S,H,D]
@@ -229,6 +230,7 @@ class Indexer(nn.Module):
     head_dim: int
     rope_theta: float = 10_000.0
     dtype: Any = jnp.bfloat16
+    norm_eps: float = 1e-6
 
     @nn.compact
     def __call__(
@@ -239,7 +241,7 @@ class Indexer(nn.Module):
         q = dense(self.num_heads * self.head_dim, "q_proj")(x).reshape(
             batch, seq, self.num_heads, self.head_dim
         )
-        k = RMSNorm(name="k_norm")(dense(self.head_dim, "k_proj")(x))
+        k = RMSNorm(self.norm_eps, name="k_norm")(dense(self.head_dim, "k_proj")(x))
         w = dense(self.num_heads, "w_proj")(x)
         q = apply_rope(q, positions, base=self.rope_theta)
         k = apply_rope(k[:, :, None, :], positions, base=self.rope_theta)[:, :, 0]
@@ -311,6 +313,8 @@ class Attention(nn.Module):
     topk: int = 0
     indexer_heads: int = 0
     indexer_head_dim: int = 0
+    #: epsilon of the per-head q/k norms and the indexer's key norm
+    norm_eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array, *, causal: bool = True) -> jax.Array:
@@ -364,14 +368,14 @@ class Attention(nn.Module):
         k = dense(kv_heads * self.head_dim, "k_proj")(x).reshape(kv_shape)
         v = dense(kv_heads * self.head_dim, "v_proj")(x).reshape(kv_shape)
         if self.qk_norm:
-            q = RMSNorm(name="q_norm")(q)
-            k = RMSNorm(name="k_norm")(k)
+            q = RMSNorm(self.norm_eps, name="q_norm")(q)
+            k = RMSNorm(self.norm_eps, name="k_norm")(k)
         q = apply_rope(q, positions, **rope_kw)
         k = apply_rope(k, positions, **rope_kw)
         if self.topk:
             q_idx, w_idx, k_idx = Indexer(
                 self.indexer_heads, self.indexer_head_dim, self.rope_theta,
-                self.dtype, name="indexer",
+                self.dtype, self.norm_eps, name="indexer",
             )(x, positions)
             ctx = sparse_attention(q, k, v, q_idx, w_idx, k_idx, self.topk)
         elif self.decode:
@@ -463,6 +467,69 @@ class Attention(nn.Module):
         )
 
 
+@dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """The shapes of multi-head latent attention (DeepSeek-V3's MLA), from
+    :class:`TransformerConfig`: the query's low-rank bottleneck, the cached
+    latent's rank, each head's dims without and with RoPE and its value
+    dims, and the softmax scale."""
+
+    q_rank: int
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+    scale: float
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention, the uncached full-sequence forward:
+
+    ``q = W_qb RMSNorm(W_qa h)`` split per head into ``q_nope`` and ``q_pe``;
+    ``[c ; k_pe] = W_kva h`` with ``c = RMSNorm(c)``, ONE ``k_pe`` shared by
+    every head; ``[k_nope_i ; v_i] = W_kvb,i c``; RoPE on ``q_pe`` and
+    ``k_pe``; ``score_i = scale (q_nope_i . k_nope_i + q_pe_i . k_pe)``. What
+    a cache would hold is ``[c ; RoPE(k_pe)]`` a position, ``kv_rank + rope``
+    values (``serving.ServingEngine`` keeps it in a latent pool). Attention
+    is the expanded form, :func:`ops.latent_attention.expanded_attention`."""
+
+    num_heads: int
+    spec: LatentSpec
+    dtype: Any = jnp.bfloat16
+    rope_theta: float = 10_000.0
+    rope_yarn: tuple[float, int, float, float, float] | None = None
+    norm_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x: jax.Array, positions: jax.Array, *, causal: bool = True) -> jax.Array:
+        if not causal:
+            raise NotImplementedError("latent attention is causal")
+        sp, heads = self.spec, self.num_heads
+        batch, seq, _ = x.shape
+        dense = _dense_factory(False, self.dtype)
+        rope = functools.partial(
+            apply_rope, positions=positions,
+            **rope_kwargs(sp.rope, self.rope_theta, self.rope_yarn),
+        )
+        q = dense(heads * (sp.nope + sp.rope), "q_b_proj")(
+            RMSNorm(self.norm_eps, name="q_a_norm")(dense(sp.q_rank, "q_a_proj")(x))
+        ).reshape(batch, seq, heads, sp.nope + sp.rope)
+        c, k_pe = jnp.split(dense(sp.kv_rank + sp.rope, "kv_a_proj")(x), [sp.kv_rank], axis=-1)
+        c = RMSNorm(self.norm_eps, name="kv_a_norm")(c)
+        kv_b = self.param(
+            "kv_b_proj",
+            lambda key, shape: {"kernel": nn.initializers.lecun_normal()(key, shape, jnp.float32)},
+            (sp.kv_rank, heads * (sp.nope + sp.v)),
+        )["kernel"]
+        q_pos = jnp.arange(seq, dtype=jnp.int32)
+        ctx = expanded_attention(
+            q[..., : sp.nope], rope(q[..., sp.nope :]), c, rope(k_pe[:, :, None])[:, :, 0],
+            kv_b.astype(self.dtype).reshape(sp.kv_rank, heads, sp.nope + sp.v),
+            scale=sp.scale, valid=(q_pos[None, :] <= q_pos[:, None])[None],
+        )
+        return dense(x.shape[-1], "out_proj")(ctx.reshape(batch, seq, heads * sp.v))
+
+
 class SwiGLU(nn.Module):
     """Gated MLP: ``down(silu(gate(x)) * up(x))``."""
 
@@ -503,19 +570,39 @@ class Block(nn.Module):
     topk: int = 0
     indexer_heads: int = 0
     indexer_head_dim: int = 0
+    #: every norm's epsilon (:attr:`TransformerConfig.rms_norm_eps`)
+    norm_eps: float = 1e-6
+    #: multi-head latent attention in place of :class:`Attention`
+    latent: LatentSpec | None = None
 
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array) -> jax.Array:
-        x = x + Attention(
-            self.num_heads, self.head_dim, self.dtype,
-            attention_fn=self.attention_fn, decode=self.decode,
-            num_kv_heads=self.num_kv_heads, quantized=self.quantized,
-            window=self.window, rope_theta=self.rope_theta,
-            rope_yarn=self.rope_yarn,
-            qk_norm=self.qk_norm, topk=self.topk,
-            indexer_heads=self.indexer_heads,
-            indexer_head_dim=self.indexer_head_dim, name="attn",
-        )(RMSNorm(name="attn_norm")(x), positions, causal=self.causal)
+        if self.latent is not None:
+            if self.decode or self.quantized or self.attention_fn is not None:
+                raise NotImplementedError(
+                    "latent attention runs in the uncached forward and the "
+                    "serving engine (its paged latent pool) only: no flax "
+                    "KV cache, quantized projections or injected core"
+                )
+            attn = LatentAttention(
+                self.num_heads, self.latent, self.dtype, self.rope_theta,
+                self.rope_yarn, self.norm_eps, name="attn",
+            )
+        else:
+            attn = Attention(
+                self.num_heads, self.head_dim, self.dtype,
+                attention_fn=self.attention_fn, decode=self.decode,
+                num_kv_heads=self.num_kv_heads, quantized=self.quantized,
+                window=self.window, rope_theta=self.rope_theta,
+                rope_yarn=self.rope_yarn,
+                qk_norm=self.qk_norm, topk=self.topk,
+                indexer_heads=self.indexer_heads,
+                indexer_head_dim=self.indexer_head_dim,
+                norm_eps=self.norm_eps, name="attn",
+            )
+        x = x + attn(
+            RMSNorm(self.norm_eps, name="attn_norm")(x), positions, causal=self.causal
+        )
         if self.quantized:
             if self.mlp_cls is not None:
                 raise ValueError(
@@ -525,7 +612,7 @@ class Block(nn.Module):
             mlp = SwiGLU(self.d_ff, self.dtype, quantized=True, name="mlp")
         else:
             mlp = (self.mlp_cls or SwiGLU)(self.d_ff, self.dtype, name="mlp")
-        return x + mlp(RMSNorm(name="mlp_norm")(x))
+        return x + mlp(RMSNorm(self.norm_eps, name="mlp_norm")(x))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -563,10 +650,13 @@ def rope_kwargs(
 class TransformerConfig:
     """Size knobs for :class:`TransformerLM`; ``tiny()`` is the test config.
 
-    ``moe_experts > 0`` swaps every block's MLP for a routed
+    ``moe_experts > 0`` swaps every block's MLP (but the first
+    ``first_dense_layers``) for a routed
     :class:`~deeplearning_mpi_tpu.models.moe.MoEMLP` (top-k routing; fixed
     capacity with experts sharded over the mesh ``expert`` axis, or
     ``moe_routing='dropless'``: every claim served, no capacity).
+    ``kv_lora_rank > 0`` swaps every block's attention for multi-head latent
+    attention (:class:`LatentAttention`).
     """
 
     vocab_size: int = 32_000
@@ -592,8 +682,28 @@ class TransformerConfig:
     moe_routing: str = "token_choice"
     #: width of one expert's SwiGLU (0 = ``d_ff``). Models that publish an
     #: expert width apart from the dense width set it; ``d_ff`` then sizes
-    #: nothing in an all-expert stack.
+    #: the leading dense layers' MLP (nothing in an all-expert stack).
     moe_d_ff: int = 0
+    #: leading layers whose MLP is the dense SwiGLU of ``d_ff`` in a model
+    #: with experts (DeepSeek-V3's ``first_k_dense_replace``)
+    first_dense_layers: int = 0
+    #: shared experts: ONE SwiGLU of width ``moe_d_ff * moe_shared_experts``
+    #: that every token runs beside its routed experts (dropless only)
+    moe_shared_experts: int = 0
+    #: the router's scoring: ``'softmax'`` (the top-k renormalised) or
+    #: ``'sigmoid'`` (DeepSeek-V3's aux-free routing: the top-k of the
+    #: scores plus a learned correction bias ``router/bias`` are chosen, and
+    #: weighted by their scores alone, renormalised). Dropless only.
+    moe_scoring: str = "softmax"
+    #: factor on the routed experts' gates (``routed_scaling_factor``)
+    moe_routed_scale: float = 1.0
+    #: the expert share, as expert parallelism holds it: the router scores
+    #: ``moe_router_experts`` experts (0 = ``moe_experts``, all of them held
+    #: here) and this chip holds the ``moe_experts`` from
+    #: ``moe_first_expert`` on; a claim on an expert held elsewhere is not
+    #: computed here (``models.moe.dropless_moe``). Dropless only.
+    moe_router_experts: int = 0
+    moe_first_expert: int = 0
     #: rotary base. A MODEL property like the window: every forward (train,
     #: cached decode, the serving engine) rotates with it.
     rope_theta: float = 10_000.0
@@ -620,6 +730,25 @@ class TransformerConfig:
     #: not be set beside it. The uncached forward, the flax KV cache and the
     #: serving engine all read a layer's own through :meth:`layer_spec`.
     layers: tuple[LayerSpec, ...] = ()
+    #: every RMSNorm's epsilon (the published one; 1e-6 is the default
+    #: every earlier model ran with)
+    rms_norm_eps: float = 1e-6
+    #: multi-head latent attention (DeepSeek-V3's MLA) where
+    #: ``kv_lora_rank > 0``: the query through a rank-``q_lora_rank``
+    #: bottleneck, ONE cached latent of ``kv_lora_rank + qk_rope_head_dim``
+    #: values a position a layer, each head's key ``qk_nope_head_dim``
+    #: expanded from the latent plus ``qk_rope_head_dim`` rotated and shared,
+    #: values of ``v_head_dim``. ``head_dim`` is then the query's
+    #: ``qk_nope_head_dim + qk_rope_head_dim`` and RoPE (a layer's
+    #: :class:`LayerSpec`) turns the rope dims alone.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    #: the attention's softmax scale (0 = ``head_dim ** -0.5``); a YaRN
+    #: model whose scale carries ``mscale ** 2`` states it here
+    softmax_scale: float = 0.0
     #: embedding lookup as a one-hot matmul instead of a gather. Forward
     #: values are identical (rows of exact 0/1 select the same f32 bits),
     #: but the *gradient* becomes a dot-general instead of a scatter-add —
@@ -632,6 +761,41 @@ class TransformerConfig:
     onehot_embed: bool = False
 
     def __post_init__(self) -> None:
+        routed = (
+            self.first_dense_layers or self.moe_shared_experts
+            or self.moe_scoring != "softmax" or self.moe_routed_scale != 1.0
+            or self.moe_router_experts or self.moe_first_expert
+        )
+        if routed and self.moe_routing != "dropless":
+            raise NotImplementedError(
+                "leading dense layers, shared experts, sigmoid routing, a "
+                "routed scale and an expert share are dropless_moe's "
+                "(moe_routing='dropless')"
+            )
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown moe_scoring {self.moe_scoring!r}")
+        if self.moe_first_expert + self.moe_experts > self.moe_router_width:
+            raise ValueError(
+                f"experts {self.moe_first_expert}..{self.moe_first_expert + self.moe_experts - 1} "
+                f"held of a router of {self.moe_router_width}"
+            )
+        if self.latent:
+            if not self.q_lora_rank:
+                raise NotImplementedError(
+                    "latent attention without a query bottleneck "
+                    "(q_lora_rank 0) is not implemented"
+                )
+            if self.head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim:
+                raise ValueError(
+                    f"head_dim {self.head_dim} of latent attention is the "
+                    "query's qk_nope_head_dim + qk_rope_head_dim"
+                )
+            windows = {self.layer_spec(i).window for i in range(self.num_layers)}
+            if self.attention_topk or windows != {0}:
+                raise NotImplementedError(
+                    "latent attention with a sliding window or a learned "
+                    "selection is not implemented"
+                )
         if not self.layers:
             return
         if len(self.layers) != self.num_layers:
@@ -656,6 +820,40 @@ class TransformerConfig:
         if self.layers:
             return self.layers[i]
         return LayerSpec(self.attention_window, self.rope_theta)
+
+    def moe_layer(self, i: int) -> bool:
+        """Whether layer ``i``'s MLP is the expert layer."""
+        return self.moe_experts > 0 and i >= self.first_dense_layers
+
+    @property
+    def moe_layers(self) -> int:
+        return sum(map(self.moe_layer, range(self.num_layers)))
+
+    @property
+    def moe_router_width(self) -> int:
+        """Experts the router scores: all of the layer's, held here or not."""
+        return self.moe_router_experts or self.moe_experts
+
+    @property
+    def expert_share(self) -> bool:
+        """Whether the router scores experts held elsewhere too."""
+        return self.moe_experts > 0 and self.moe_router_width != self.moe_experts
+
+    @property
+    def latent(self) -> LatentSpec | None:
+        """Multi-head latent attention's shapes, or None for K/V attention."""
+        if not self.kv_lora_rank:
+            return None
+        return LatentSpec(
+            self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+            self.qk_rope_head_dim, self.v_head_dim,
+            self.softmax_scale or self.head_dim**-0.5,
+        )
+
+    @property
+    def rope_dim(self) -> int:
+        """The dims RoPE turns: a latent model's rope dims, else a head's."""
+        return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
 
     @property
     def mlp_width(self) -> int:
@@ -803,18 +1001,23 @@ class TransformerLM(nn.Module):
         block_cls = _remat_block(self.remat)
         for i in range(cfg.num_layers):
             spec = cfg.layer_spec(i)
+            # a leading dense layer of a model with experts: SwiGLU of d_ff
+            dense = self.mlp_cls is None and cfg.moe_experts and not cfg.moe_layer(i)
             x = block_cls(
-                cfg.num_heads, cfg.head_dim, cfg.mlp_width, self.dtype,
-                attention_fn=self.attention_fn, mlp_cls=mlp_cls,
+                cfg.num_heads, cfg.head_dim,
+                cfg.d_ff if dense else cfg.mlp_width, self.dtype,
+                attention_fn=self.attention_fn,
+                mlp_cls=None if dense else mlp_cls,
                 decode=self.decode, num_kv_heads=cfg.num_kv_heads,
                 quantized=self.quantized, window=spec.window,
                 rope_theta=spec.rope_theta, rope_yarn=spec.yarn,
                 qk_norm=cfg.qk_norm,
                 topk=cfg.attention_topk, indexer_heads=cfg.indexer_heads,
                 indexer_head_dim=cfg.indexer_head_dim,
+                norm_eps=cfg.rms_norm_eps, latent=cfg.latent,
                 name=f"layer_{i}",
             )(x, positions)
-        x = RMSNorm(name="final_norm")(x)
+        x = RMSNorm(cfg.rms_norm_eps, name="final_norm")(x)
         if self.return_prehead:
             if not cfg.tied_embeddings:
                 raise ValueError(

@@ -75,7 +75,20 @@ two GROUPS, each with its own pools, allocator and block table:
   two forms that round differently, picked by the program's static rows
   (``dropless_form``); the engine builds only the decode row buckets that
   take the form of its ``max_slots``-row program, so the contract holds
-  across buckets too.
+  across buckets too. Leading dense layers, a shared expert, sigmoid
+  routing and a SHARE of the layer's experts (the router scores all of
+  them, this engine computes the part its own give) are the config's too;
+- multi-head latent attention (``kv_lora_rank > 0``, DeepSeek-V3's MLA):
+  the pool holds ONE latent vector a position a layer, ``[c ; RoPE(k_pe)]``,
+  in two arrays where K and V would be, and no V pool
+  (``init_kv_buffers(latent_dims=...)``). The decode step
+  attends over the gathered latent in the *absorbed* form (the key
+  projection moved onto the query, the value projection past the weighted
+  sum: the latent is read once for every head), the prefill chunk in the
+  *expanded* form (every table position's keys and values expanded once
+  for the chunk's queries, which attend in a Pallas kernel that keeps
+  their scores on chip): ``ops/latent_attention.py``. Speculation, integer pools and a
+  disaggregated hand-off are refused for such a model.
 """
 
 from __future__ import annotations
@@ -103,7 +116,15 @@ from deeplearning_mpi_tpu.ops.attention import (
     repeat_kv,
 )
 from deeplearning_mpi_tpu.analysis import sanitizer as _sanitizer
-from deeplearning_mpi_tpu.models.moe import dropless_form, dropless_moe
+from deeplearning_mpi_tpu.models.moe import (
+    dropless_form,
+    dropless_moe,
+    routing_from_config,
+)
+from deeplearning_mpi_tpu.ops.latent_attention import (
+    absorbed_attention,
+    chunk_attention,
+)
 from deeplearning_mpi_tpu.ops.quant import dequantize_kv, quantize_kv
 from deeplearning_mpi_tpu.ops.sparse_attention import (
     attend_masked,
@@ -178,7 +199,7 @@ def _by_query_tiles(
 ) -> jax.Array:
     """``attend_tile`` over tiles of :data:`SELECT_TILE` queries, one tile
     at a time: ``per_query`` are ``[rows, seq, ...]`` arrays, the first the
-    queries ``[rows, seq, H, D]``, whose shape the result has."""
+    queries ``[rows, seq, H, D]``; the result is ``[rows, seq, H, Dv]``."""
     q = per_query[0]
     rows, seq = q.shape[:2]
     width = math.gcd(seq, SELECT_TILE)
@@ -189,7 +210,7 @@ def _by_query_tiles(
         a.reshape((rows, seq // width, width) + a.shape[2:]), 1, 0
     )
     out = jax.lax.map(attend_tile, tuple(map(split, per_query)))
-    return jnp.moveaxis(out, 0, 1).reshape(q.shape)
+    return jnp.moveaxis(out, 0, 1).reshape(q.shape[:2] + out.shape[3:])
 
 
 class GroupView(NamedTuple):
@@ -409,9 +430,13 @@ class PagedForward:
         }
         #: each layer's own RoPE (``apply_rope``'s keywords)
         self._rope_kw = [
-            rope_kwargs(config.head_dim, spec.rope_theta, spec.yarn)
+            rope_kwargs(config.rope_dim, spec.rope_theta, spec.yarn)
             for spec in map(config.layer_spec, range(config.num_layers))
         ]
+        #: multi-head latent attention's shapes (None: K/V attention): the
+        #: kv tuple is the latent pool ``(c, k_pe)``, scattered and gathered
+        #: where K and V would be
+        self.latent = config.latent
         #: the sliding window, where the caller of :meth:`decode_step` hands
         #: it tables that start at :func:`window_first_block`
         #: (``window_cut``: the engine's own decode launch does, the draft's
@@ -519,7 +544,7 @@ class PagedForward:
     def _rmsnorm(self, x: jax.Array, scale: jax.Array) -> jax.Array:
         x32 = x.astype(jnp.float32)
         normed = x32 * jax.lax.rsqrt(
-            jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6
+            jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.config.rms_norm_eps
         )
         return (normed * scale).astype(x.dtype)
 
@@ -581,6 +606,28 @@ class PagedForward:
                 )
             return rope(q), rope(k), v, index
 
+    def _latent_proj(
+        self, lp: Any, x: jax.Array, pos: jax.Array, i: int
+    ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+        """Latent attention's projections of layer ``i``
+        (``models.transformer.LatentAttention`` numerics): ``(q_nope [rows,
+        seq, H, nope], q_pe [rows, seq, H, rope] rotated, c [rows, seq,
+        kv_rank] normed, k_pe [rows, seq, rope] rotated)``, the last two
+        what the latent pool holds a position."""
+        cfg, sp, at = self.config, self.latent, lp["attn"]
+        rows, seq = x.shape[0], x.shape[1]
+        rope = functools.partial(apply_rope, positions=pos, **self._rope_kw[i])
+        with annotate("attn/latent_q"):
+            h = self._rmsnorm(x, lp["attn_norm"]["scale"])
+            q_a = self._rmsnorm(self._lin(h, at["q_a_proj"]["kernel"]), at["q_a_norm"]["scale"])
+            q = self._lin(q_a, at["q_b_proj"]["kernel"]).reshape(
+                rows, seq, cfg.num_heads, sp.nope + sp.rope
+            )
+            kv_a = self._lin(h, at["kv_a_proj"]["kernel"])
+            c = self._rmsnorm(kv_a[..., : sp.kv_rank], at["kv_a_norm"]["scale"])
+            k_pe = rope(kv_a[..., sp.kv_rank :][:, :, None])[:, :, 0]
+            return q[..., : sp.nope], rope(q[..., sp.nope :]), c, k_pe
+
     def _attn_out(self, lp: Any, x: jax.Array, ctx: jax.Array) -> jax.Array:
         """Residual add of the attention output projection; ``ctx`` is
         ``[rows, seq, H, D]``."""
@@ -597,14 +644,16 @@ class PagedForward:
         this step's rows as one flat batch; padding rows (``live`` false)
         claim no expert. Returns the residual sum and the count of experts
         the live rows touched."""
-        mp = lp["mlp"]
+        mp, cfg = lp["mlp"], self.config
         with annotate("mlp"):
             h = self._rmsnorm(x, lp["mlp_norm"]["scale"])
             y, touched = dropless_moe(
                 h.reshape(-1, h.shape[-1]), mp["router"]["kernel"],
                 mp["experts_gate"], mp["experts_up"], mp["experts_down"],
-                top_k=self.config.moe_top_k, dtype=self.dtype,
-                live=live.reshape(-1),
+                top_k=cfg.moe_top_k, dtype=self.dtype,
+                live=live.reshape(-1), routing=routing_from_config(cfg),
+                bias=mp["router"].get("bias"), shared=mp.get("shared"),
+                count_claims=cfg.expert_share,
             )
             return x + y.reshape(x.shape), touched
 
@@ -704,11 +753,19 @@ class PagedForward:
           the first kind (its indexer keys are still written: later, wider
           steps read them).
 
+        A latent model (:attr:`latent`) has a third: its layer scatters ONE
+        latent row a position, ``c`` and ``k_pe`` where K and V would go,
+        and ``attend(q_nope, q_pe, c, k_pe, w_kvb)`` runs over the gathered
+        latent pages (``[rows, L, kv_rank]`` and ``[rows, L, rope]``) in the
+        program's own form, absorbed or expanded.
+
         And two kinds of MLP: dense SwiGLU, or the dropless expert layer
         over the step's rows (a row that writes to the scratch block is
-        padding and claims no expert). Returns the pools, the normed
-        activations and the experts touched per layer (``[layers]`` int32;
-        empty for a dense model)."""
+        padding and claims no expert); a model's leading dense layers take
+        the first. Returns the pools, the normed activations and the experts
+        touched per expert layer (``[layers]`` int32, ``[layers, 2]`` with
+        the claims on held experts beside them for an expert share; empty
+        for a dense model)."""
         cfg = self.config
         head = (cfg.num_kv_heads or cfg.num_heads, cfg.head_dim)
         pools = list(kv) if self.mixed else [kv]
@@ -722,23 +779,40 @@ class PagedForward:
             seq = (x.shape[0], span) + head
             selecting = self.selecting and span > cfg.attention_topk
             lp = params[f"layer_{i}"]
-            q, k, v, index = self._attn_proj(lp, x, pos, i)
-            pools[g] = self._kv_scatter(
-                pools[g], j, bid, off, k.reshape(new), v.reshape(new),
-                index and index[2].reshape(bid.shape + index[2].shape[-1:]),
-            )
-            if selecting:
-                ctx = self._select_attend(
-                    pools[g], j, tables.reshape(x.shape[0], -1), q, *index[:2],
-                    jnp.where(live, pos, -1),
+            if self.latent:
+                q_nope, q_pe, c, k_pe = self._latent_proj(lp, x, pos, i)
+                pools[g] = self._kv_scatter(
+                    pools[g], j, bid, off, c.reshape(bid.shape + c.shape[-1:]),
+                    k_pe.reshape(bid.shape + k_pe.shape[-1:]),
+                )
+                pages = [
+                    a.reshape((x.shape[0], span) + a.shape[-1:])
+                    for a in self._kv_gather(pools[g], j, tables)
+                ]
+                ctx = attend(
+                    q_nope, q_pe, *pages,
+                    lp["attn"]["kv_b_proj"]["kernel"].reshape(
+                        self.latent.kv_rank, cfg.num_heads, -1
+                    ),
                 )
             else:
-                k_seq, v_seq = self._kv_gather(pools[g], j, tables)
-                k_seq, v_seq = k_seq.reshape(seq), v_seq.reshape(seq)
-                with annotate("attn/core"):
-                    ctx = attend(q, k_seq, v_seq)
+                q, k, v, index = self._attn_proj(lp, x, pos, i)
+                pools[g] = self._kv_scatter(
+                    pools[g], j, bid, off, k.reshape(new), v.reshape(new),
+                    index and index[2].reshape(bid.shape + index[2].shape[-1:]),
+                )
+                if selecting:
+                    ctx = self._select_attend(
+                        pools[g], j, tables.reshape(x.shape[0], -1), q, *index[:2],
+                        jnp.where(live, pos, -1),
+                    )
+                else:
+                    k_seq, v_seq = self._kv_gather(pools[g], j, tables)
+                    k_seq, v_seq = k_seq.reshape(seq), v_seq.reshape(seq)
+                    with annotate("attn/core"):
+                        ctx = attend(q, k_seq, v_seq)
             x = self._attn_out(lp, x, ctx)
-            if cfg.moe_experts:
+            if cfg.moe_layer(i):
                 x, count = self._moe(lp, x, live)
                 touched.append(count)
             else:
@@ -820,10 +894,22 @@ class PagedForward:
             # marks the row inactive (zero output).
             idx = jnp.where(active, in_table(lengths - 1, BS), -1)
 
-            def attend(q: jax.Array, k_seq: jax.Array, v_seq: jax.Array) -> jax.Array:
-                return batched_decode_attention(
-                    q, k_seq, v_seq, idx, window=window or None
-                )
+            if self.latent:
+                # one query a row: the absorbed form reads the latent once
+                # for every head
+                k_pos = jnp.arange(MB * BS, dtype=jnp.int32)
+                valid = (k_pos[None, :] <= idx[:, None])[:, None]  # [S, 1, L]
+
+                def attend(q_nope, q_pe, c, k_pe, w_kvb):
+                    return absorbed_attention(
+                        q_nope, q_pe, c, k_pe, w_kvb,
+                        scale=self.latent.scale, valid=valid,
+                    )
+            else:
+                def attend(q: jax.Array, k_seq: jax.Array, v_seq: jax.Array) -> jax.Array:
+                    return batched_decode_attention(
+                        q, k_seq, v_seq, idx, window=window or None
+                    )
 
             return GroupView(bid, p % BS, tables, attend)
 
@@ -895,14 +981,23 @@ class PagedForward:
                     causal=True, window=window or None, q_offset=first,
                 )
 
-            def attend(q: jax.Array, k_seq: jax.Array, v_seq: jax.Array) -> jax.Array:
-                k_seq, v_seq = repeat_kv(k_seq, rep), repeat_kv(v_seq, rep)
-                if not tiled:
-                    return attend_from(q, k_seq, v_seq, in_table(start))
-                return _by_query_tiles(
-                    lambda t: attend_from(t[0], k_seq, v_seq, t[1][0, 0]),
-                    (q, in_table(pos)),
-                )
+            if not self.latent:
+                def attend(q: jax.Array, k_seq: jax.Array, v_seq: jax.Array) -> jax.Array:
+                    k_seq, v_seq = repeat_kv(k_seq, rep), repeat_kv(v_seq, rep)
+                    if not tiled:
+                        return attend_from(q, k_seq, v_seq, in_table(start))
+                    return _by_query_tiles(
+                        lambda t: attend_from(t[0], k_seq, v_seq, t[1][0, 0]),
+                        (q, in_table(pos)),
+                    )
+            else:
+                # the chunk's queries share every table position's expanded
+                # keys and values: expand once, attend in a kernel that
+                # keeps its scores on chip (no [H, C, L] score tensor in HBM)
+                def attend(q_nope, q_pe, c, k_pe, w_kvb):
+                    return chunk_attention(
+                        q_nope, q_pe, c, k_pe, w_kvb, scale=self.latent.scale, start=start
+                    )
 
             return GroupView(bid, p % BS, table, attend)
 
@@ -1067,7 +1162,7 @@ class ServingEngine:
                 "request-independence contract (moe_routing='dropless' "
                 "serves every claim and is admitted)"
             )
-        if "kernel" not in params["layer_0"]["attn"]["q_proj"]:
+        if "kernel" not in params["layer_0"]["attn"]["q_a_proj" if config.latent else "q_proj"]:
             raise NotImplementedError(
                 "serving engine takes the raw f32 param tree (quantized "
                 "trees from ops.quant are not supported)"
@@ -1098,6 +1193,21 @@ class ServingEngine:
                     f"attention_topk > 0 with kv_dtype={engine.kv_dtype!r}: "
                     "an integer indexer-key pool is not implemented"
                 )
+        if config.latent:
+            # Latent attention: what this engine does not make work over a
+            # latent pool is refused here, by name.
+            refused = {
+                "spec_k > 0 (the verify step and the draft attend over K/V pools)": engine.spec_k > 0,
+                f"kv_dtype={engine.kv_dtype!r} (an integer latent pool)":
+                    storage is not None and jnp.issubdtype(storage, jnp.integer),
+                "a disaggregated hand-off (injected pool, kv_buffers or role)":
+                    pool is not None or kv_buffers is not None or role is not None,
+            }
+            for what, asked in refused.items():
+                if asked:
+                    raise NotImplementedError(
+                        f"a model with latent attention is not served with {what}"
+                    )
         groups = layer_groups(config)
         if len(groups) > 1:
             # Full and window layers side by side: what this engine does
@@ -1199,6 +1309,7 @@ class ServingEngine:
                     config.num_kv_heads or config.num_heads, config.head_dim,
                     storage if storage is not None else dtype,
                     index_dim=config.indexer_head_dim if config.attention_topk else 0,
+                    latent_dims=config.latent and (config.kv_lora_rank, config.qk_rope_head_dim),
                 )
                 for group, blocks in zip(
                     groups, (engine.num_blocks, engine.window_num_blocks)
@@ -1274,6 +1385,11 @@ class ServingEngine:
                 # step's rows routed to, and experts held.
                 registry.counter("serve_moe_experts_touched")
                 registry.counter("serve_moe_expert_slots")
+            if config.expert_share:
+                # Per decode step, summed over expert layers: the live rows'
+                # claims, and those that land on an expert held here.
+                registry.counter("serve_moe_claims")
+                registry.counter("serve_moe_claims_held")
         self._fwd = PagedForward(
             config, engine, dtype,
             tick=lambda: self._inc("serve_compile_total"),
@@ -1764,7 +1880,7 @@ class ServingEngine:
         cfg = self.config
         if not cfg.moe_experts:
             return {}
-        return {"moe": dropless_form(n_tokens, cfg.moe_top_k, cfg.moe_experts)}
+        return {"moe": dropless_form(n_tokens, cfg.moe_top_k, cfg.moe_router_width)}
 
     def _decode_shape(self, rows: int, blocks: int) -> tuple[int, int]:
         """Static (rows, width) of this step's decode table: of the pairs
@@ -1817,6 +1933,12 @@ class ServingEngine:
             skipped = sum(first)
         reach = list(map(len, handed))
         rows, width = self._decode_shape(len(decoding), max(reach))
+        if self._fwd.latent:
+            # the live latent positions, and what the table's rectangle gathers
+            labels = {
+                "attn": "latent_absorbed", "live": sum(r.length for r in decoding),
+                "gathered": rows * width * BS,
+            }
         form = self._moe_form(rows)
         with span(
             "serve/decode_launch",
@@ -1878,8 +2000,12 @@ class ServingEngine:
             else:
                 next_np = fetch(next_tok)  # dmt-lint: disable=DMT003 — the audited sync
         if cfg.moe_experts:
-            self._inc("serve_moe_experts_touched", int(touched_np.sum()))
-            self._inc("serve_moe_expert_slots", cfg.num_layers * cfg.moe_experts)
+            counts = touched_np.reshape(cfg.moe_layers, -1)
+            self._inc("serve_moe_experts_touched", int(counts[:, 0].sum()))
+            self._inc("serve_moe_expert_slots", cfg.moe_layers * cfg.moe_experts)
+            if cfg.expert_share:
+                self._inc("serve_moe_claims", len(decoding) * cfg.moe_top_k * cfg.moe_layers)
+                self._inc("serve_moe_claims_held", int(counts[:, 1].sum()))
         with span("serve/retire") as sp:
             before = len(finished)
             now = self._clock()
@@ -2127,6 +2253,8 @@ class ServingEngine:
             labels = {
                 "window_width": self._window_widths[1], "window_live": len(held),
             }
+        if self._fwd.latent:
+            labels = {"attn": "latent_expanded", "live": start + n_valid}
         written = slice(
             start // e.block_size, (start + n_valid - 1) // e.block_size + 1
         )

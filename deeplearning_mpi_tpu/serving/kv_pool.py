@@ -367,8 +367,24 @@ def init_kv_buffers(
     kv_dtype: Any,
     *,
     index_dim: int = 0,
+    latent_dims: tuple[int, int] | None = None,
 ) -> tuple[Any, ...]:
     """Zero-initialized device pools in the explicit storage ``kv_dtype``.
+
+    ``latent_dims = (kv_rank, rope)`` (a model with multi-head latent
+    attention, float storage only) is the latent pool: a position's ONE
+    cached vector ``[c ; k_pe]`` a layer, every head's keys and values
+    expanded from it, and no V pool; ``kv_heads`` and ``head_dim`` size
+    nothing. It is kept as two arrays, ``(c, k_pe)`` of ``[num_layers,
+    num_blocks, block_size, kv_rank]`` and ``[..., rope]``, and not as one of
+    ``kv_rank + rope``: the TPU tiles an array's last two dims by (8, 128),
+    and a last dim of 576 (DeepSeek-V3's 512 + 64) is not a multiple of 128,
+    so XLA keeps such a pool in another layout and copies the whole of it
+    back and forth around every layer's scatter and gather (two 3.5 GB copies
+    a layer in a described-v5e compile of the decode step at 4,801 blocks of
+    128); ``c`` of 512 and ``k_pe`` of 64 tile as they are. The two arrays
+    take the places of K and V: one block table, one scatter and one gather
+    serve both.
 
     ``index_dim > 0`` (a model with learned sparse attention, float storage
     only) adds a THIRD pool, the indexer keys: ``(k, v, k_index)`` with
@@ -391,6 +407,10 @@ def init_kv_buffers(
     """
     import jax.numpy as jnp
 
+    if latent_dims:
+        if jnp.issubdtype(jnp.dtype(kv_dtype), jnp.integer):
+            raise NotImplementedError("a latent pool in integer storage is not implemented")
+        return tuple(jnp.zeros((num_layers, num_blocks, block_size, n), kv_dtype) for n in latent_dims)
     shape = (num_layers, num_blocks, block_size, kv_heads, head_dim)
     k = jnp.zeros(shape, kv_dtype)
     v = jnp.zeros(shape, kv_dtype)

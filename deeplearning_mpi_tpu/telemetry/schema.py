@@ -87,6 +87,8 @@ METRICS: dict[str, tuple[str, frozenset[str]]] = {
     "serve_select_kept_keys": ("counter", frozenset()),
     "serve_moe_experts_touched": ("counter", frozenset()),
     "serve_moe_expert_slots": ("counter", frozenset()),
+    "serve_moe_claims": ("counter", frozenset()),
+    "serve_moe_claims_held": ("counter", frozenset()),
     "serve_handoff_depth": ("gauge", frozenset()),
     "serve_handoff_stalls_total": ("counter", frozenset()),
     "serve_handoffs_total": ("counter", frozenset()),

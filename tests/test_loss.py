@@ -13,6 +13,7 @@ loss at ``lm-train-8k``'s shape; the train step's gradient reductions on a
 """
 
 import contextlib
+import functools
 import os
 
 import jax
@@ -355,3 +356,57 @@ def test_one_chip_train_step_hlo_is_unchanged_by_the_tpu_options(v5e_2x2, monkey
         ]
     assert "tpu_custom_call" in texts[0] and " all-reduce(" not in texts[0]
     assert texts[0] == texts[1]
+
+
+def test_the_latent_decode_step_compiles_for_v5e_without_copying_its_pool(one_chip):
+    """The serving engine's decode step of a latent-attention model at
+    ``kimi-serve-long``'s widths, pool and table (2 of its layers): the
+    latent pool's two arrays, ``c`` of 512 and ``k_pe`` of 64, are scattered
+    into and gathered from in place. Kept as ONE array of 576 a position,
+    not a multiple of the chip's 128-wide tiles, the compiler held the pool
+    in another layout and copied the whole of it back and forth around
+    every layer's scatter and gather."""
+    import json
+
+    from benchmark.kimi import program, weights
+    from benchmark.manifest import ROOT
+    from deeplearning_mpi_tpu.serving.engine import EngineConfig, PagedForward
+    from deeplearning_mpi_tpu.serving.kv_pool import init_kv_buffers
+
+    cfg = {**json.loads((ROOT / "benchmark/configs/kimi-k2.7-code-l5.json").read_text()), "num_hidden_layers": 2}
+    model = program.model_config(cfg)
+    engine = EngineConfig(**{k: v for k, v in cfg["engine"].items() if k != "why"})
+    on_chip = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)  # noqa: E731
+    params = on_chip(jax.eval_shape(lambda: weights.build(cfg, weights.seed_words(1), jnp.bfloat16)))
+    pools = on_chip(jax.eval_shape(lambda: init_kv_buffers(2, 4801, 128, 64, 192, jnp.bfloat16, latent_dims=(512, 64))))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
+    fwd = PagedForward(model, engine, jnp.bfloat16, window_cut=True)
+    with _no_compile_cache():
+        compiled = jax.jit(fwd.decode_step, donate_argnums=(1,)).lower(
+            params, pools, i32(16, 576), i32(16), i32(16), jax.ShapeDtypeStruct((16,), jnp.bool_, sharding=one_chip),
+        ).compile()
+    text = compiled.as_text()
+    text = text[text.index("\nENTRY "):]
+    copies = [line for line in text.splitlines() if " copy(" in line]
+    assert not [c for c in copies if "bf16[2,4801,128," in c]  # neither of the pool's arrays
+
+
+def test_the_latent_prefill_kernel_compiles_for_v5e_at_the_cells_widths(one_chip):
+    """The prefill chunk's attention of a latent-attention model at
+    ``kimi-serve-long``'s widths: 1,024 queries of 64 heads (128 + 64 wide,
+    values of 128) over the longest table the cell gathers, 576 blocks of
+    128 positions. Mosaic takes the kernel's tiles and fast memory, and the
+    program's temporaries stay under twice the chunk's queries and output:
+    no ``[H, C, L]`` score tensor reaches HBM."""
+    from deeplearning_mpi_tpu.ops.pallas import latent_prefill
+
+    chunk, heads, length = 1024, 64, 576 * 128
+    aval = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    attend = functools.partial(latent_prefill.chunk_attention, scale=0.1447, interpret=False)
+    with _no_compile_cache():
+        compiled = jax.jit(attend).lower(
+            aval(chunk, heads, 128), aval(chunk, heads, 64), aval(heads, length, 128), aval(heads, length, 128),
+            aval(length, 64), start=aval(dtype=jnp.int32),
+        ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * chunk * heads * (128 + 64 + 128) * 2

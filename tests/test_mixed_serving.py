@@ -450,6 +450,13 @@ PARENT_PROGRAMS = {
         "decode@4x16": "d7e1c1413992ebf4", "decode@1x8": "14a23ef253408e9f", "decode@4x2": "6db05cfe0b5e5498",
         "prefill@4": "d40d22133fc4cb46", "prefill@16": "0b3a3f28c993a0ba",
     },
+    # a two-group model (window and full layers, dropless experts), taken on commit 5e200f3, before a model with
+    # latent attention and an expert share existed: the 4-row programs' expert layer is batched, the 1- and 2-row ones'
+    # grouped
+    "mellum-like": {
+        "decode@4x16": "de32bbce6ccd66d4", "decode@2x8": "9296bb8ff1519f79", "decode@1x16": "507663ff9b093ceb",
+        "prefill@2": "8f33235c81ab55c6", "prefill@16": "bbb5c7ce8775bab8",
+    },
 }
 _TINY = dict(vocab_size=128, num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16, d_model=32, d_ff=64, tied_embeddings=False)
 _SMALL = dict(max_slots=4, block_size=4, num_blocks=64, max_blocks_per_seq=16)
@@ -463,6 +470,13 @@ ONE_KIND = {
         ),
         EngineConfig(**_SMALL, prefill_chunk=128), 0,
     ),
+    "mellum-like": (
+        TransformerConfig(
+            **_TINY, moe_experts=8, moe_top_k=2, moe_routing="dropless", moe_d_ff=24,
+            layers=(LayerSpec(16, 500000.0), LayerSpec(0, 500000.0, (4.0, 32, 32.0, 1.0, 1.2772588722239782))),
+        ),
+        EngineConfig(**_SMALL, window_num_blocks=16, prefill_chunk=8), 0,
+    ),
 }
 
 
@@ -470,16 +484,25 @@ def _program_digests(cfg: TransformerConfig, eng: EngineConfig, spec_k: int) -> 
     fwd = PagedForward(cfg, eng, jnp.float32, window_cut=True)
     model = TransformerLM(cfg, dtype=jnp.float32)
     weights_ = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
-    kv = jax.eval_shape(lambda: init_kv_buffers(
-        cfg.num_layers, eng.num_blocks, eng.block_size, cfg.num_kv_heads, cfg.head_dim, jnp.float32,
-        index_dim=cfg.indexer_head_dim if cfg.attention_topk else 0,
+    kv = jax.eval_shape(lambda: tuple(
+        init_kv_buffers(
+            len(group.layers), blocks, eng.block_size, cfg.num_kv_heads, cfg.head_dim, jnp.float32,
+            index_dim=cfg.indexer_head_dim if cfg.attention_topk else 0,
+        )
+        for group, blocks in zip(layer_groups(cfg), (eng.num_blocks, eng.window_num_blocks))
     ))
-    reach = min(eng.max_blocks_per_seq, window_blocks(fwd.decode_window, eng.block_size)) if fwd.decode_window else eng.max_blocks_per_seq
+    kv = kv if fwd.mixed else kv[0]
+    cut = fwd.decode_window and not fwd.mixed  # a two-group model's ladder is on the full group's whole tables
+    reach = min(eng.max_blocks_per_seq, window_blocks(fwd.decode_window, eng.block_size)) if cut else eng.max_blocks_per_seq
     widths, shapes = _table_shapes(eng.max_slots, eng.max_blocks_per_seq, reach)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
     flags = lambda n: jax.ShapeDtypeStruct((n,), jnp.bool_)  # noqa: E731
-    calls = {f"decode@{r}x{w}": (fwd.decode_step, (weights_, kv, i32(r, w), i32(r), i32(r), flags(r))) for r, w in shapes}
-    calls |= {f"prefill@{w}": (fwd.prefill_chunk, (weights_, kv, i32(w), i32(eng.prefill_chunk), i32(), i32())) for w in widths}
+    # the window group's table of a two-group model: ONE width a program kind (ServingEngine._window_widths)
+    reach_of = lambda n: min(window_blocks(n, eng.block_size), eng.max_blocks_per_seq)  # noqa: E731
+    window = (reach_of(fwd.decode_window), reach_of(fwd.decode_window + eng.prefill_chunk - 1)) if fwd.mixed else None
+    table = lambda *s, kind: (i32(*s), i32(*s[:-1], window[kind])) if window else i32(*s)  # noqa: E731
+    calls = {f"decode@{r}x{w}": (fwd.decode_step, (weights_, kv, table(r, w, kind=0), i32(r), i32(r), flags(r))) for r, w in shapes}
+    calls |= {f"prefill@{w}": (fwd.prefill_chunk, (weights_, kv, table(w, kind=1), i32(eng.prefill_chunk), i32(), i32())) for w in widths}
     if spec_k:
         s = eng.max_slots
         calls["verify"] = (fwd.verify_step, (weights_, kv, i32(s, eng.max_blocks_per_seq), i32(s), i32(s, spec_k + 1), i32(s), flags(s)))

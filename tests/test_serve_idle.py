@@ -147,7 +147,9 @@ def test_no_trace_under_the_directory_reads_as_nothing(tmp_path):
 
 def test_the_four_close_per_layer_and_are_read_in_the_four_serving_cells():
     m = Manifest()
-    assert list(m.per_layer)[-4:] == list(NEW)
+    names = list(m.per_layer)
+    # appended together and in this order; a later cell's metrics are appended after them, so compared by name
+    assert names[names.index(NEW[0]) :][:4] == list(NEW)
     serving = ["lm-serve-chat", "keye-serve-long", "lm-serve-long", "mellum-serve-mixed"]
     for name in NEW:
         assert [c for c in m.cells if name in m.cell_per_layer(c)] == serving
