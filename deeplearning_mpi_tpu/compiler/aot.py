@@ -56,12 +56,18 @@ __all__ = [
 ]
 
 
-def mosaic_call_count(compiled: Any) -> int:
+def mosaic_call_count(compiled: Any, kernel: str | None = None) -> int:
     """Mosaic (Pallas TPU) custom calls in a compiled executable's HLO —
     the evidence that a kernel was *taken*, not merely available: the Pallas
     entry points return their XLA references without a word when a block
-    does not tile, and the interpreter lowers to plain HLO (count 0)."""
-    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+    does not tile, and the interpreter lowers to plain HLO (count 0).
+    ``kernel``: only the calls of the kernel of that name (``pallas_call``'s
+    ``name``: its instructions are ``kernel`` and ``kernel.N``)."""
+    text = compiled.as_text()
+    if kernel is None:
+        return text.count('custom_call_target="tpu_custom_call"')
+    call = re.compile(rf'%{re.escape(kernel)}(\.\d+)? = .*custom_call_target="tpu_custom_call"')
+    return sum(1 for line in text.splitlines() if call.search(line))
 
 
 _COLLECTIVES = (

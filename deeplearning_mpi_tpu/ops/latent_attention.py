@@ -19,7 +19,11 @@ A position's cache entry is ONE vector ``[c ; k_pe]``: the normed latent
   the weighted sum, so the keys are the latent itself, read once for every
   head: the form for one query a row (a decode step), where expanding each
   cached position would cost ``2 * kv_rank * H * (nope + v)`` operations a
-  position a step.
+  position a step. :func:`paged_absorbed_attention` is the same form over
+  the paged latent pools in place, each row's live pages walked through its
+  block table in a Pallas kernel (``ops/pallas/latent_decode.py``): what the
+  decode step runs; :func:`absorbed_attention` over gathered pages is its
+  reference.
 
 The two compute the same function and round differently. Both take the
 latent's two parts ``c [B, K, kv_rank]`` and ``k_pe [B, K, rope]`` in the
@@ -35,7 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning_mpi_tpu.ops.attention import NEG_INF
-from deeplearning_mpi_tpu.ops.pallas import latent_prefill
+from deeplearning_mpi_tpu.ops.pallas import latent_decode, latent_prefill
 from deeplearning_mpi_tpu.telemetry.trace import annotate
 
 
@@ -125,3 +129,33 @@ def absorbed_attention(
             "bqhc,chd->bqhd", o_lat, w_uv, preferred_element_type=jnp.float32
         )
     return out.astype(dtype)
+
+
+def paged_absorbed_attention(
+    q_nope: jax.Array, q_pe: jax.Array, c_pool: jax.Array, kpe_pool: jax.Array,
+    layer: int, tables: jax.Array, last: jax.Array, w_kvb: jax.Array, *, scale: float,
+) -> jax.Array:
+    """:func:`absorbed_attention` for one query a row (``q_nope [B, 1, H,
+    nope]``, ``q_pe [B, 1, H, rope]``) over the latent pools in place:
+    row ``b`` attends positions ``0 .. last[b]`` of its block table
+    ``tables [B, MB]`` in layer ``layer`` of ``c_pool [layers, blocks, BS,
+    kv_rank]`` and ``kpe_pool [layers, blocks, rope, BS]``; ``last[b] = -1``
+    yields zeros. -> ``[B, 1, H, v]``."""
+    nope, dtype = q_nope.shape[-1], q_nope.dtype
+    w_kvb = w_kvb.astype(dtype)
+    w_uk, w_uv = w_kvb[..., :nope], w_kvb[..., nope:]
+    with annotate("attn/absorb"):
+        # left in float32: the kernel casts it, as absorbed_attention does
+        q_lat = jnp.einsum(
+            "bhd,chd->bhc", q_nope[:, 0], w_uk, preferred_element_type=jnp.float32
+        )
+    with annotate("attn/latent_core"):
+        o_lat = latent_decode.latent_decode(
+            q_lat, q_pe[:, 0], c_pool, kpe_pool, jnp.int32(layer), tables, last,
+            scale=scale, interpret=not latent_decode._on_tpu(),
+        )
+    with annotate("attn/unabsorb"):
+        out = jnp.einsum(
+            "bhc,chd->bhd", o_lat, w_uv, preferred_element_type=jnp.float32
+        )
+    return out.astype(dtype)[:, None]

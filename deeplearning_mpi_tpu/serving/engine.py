@@ -81,13 +81,15 @@ two GROUPS, each with its own pools, allocator and block table:
 - multi-head latent attention (``kv_lora_rank > 0``, DeepSeek-V3's MLA):
   the pool holds ONE latent vector a position a layer, ``[c ; RoPE(k_pe)]``,
   in two arrays where K and V would be, and no V pool
-  (``init_kv_buffers(latent_dims=...)``). The decode step
-  attends over the gathered latent in the *absorbed* form (the key
-  projection moved onto the query, the value projection past the weighted
-  sum: the latent is read once for every head), the prefill chunk in the
-  *expanded* form (every table position's keys and values expanded once
-  for the chunk's queries, which attend in a Pallas kernel that keeps
-  their scores on chip): ``ops/latent_attention.py``. Speculation, integer pools and a
+  (``init_kv_buffers(latent_dims=...)``). The decode step attends in the
+  *absorbed* form (the key projection moved onto the query, the value
+  projection past the weighted sum: the latent is read once for every
+  head) in a Pallas kernel that walks each row's live pages in the pools
+  through its block table, so its table has one width (the full one) at
+  every row bucket; the prefill chunk in the *expanded* form (every table
+  position's keys and values expanded once for the chunk's queries, which
+  attend in a Pallas kernel that keeps their scores on chip):
+  ``ops/latent_attention.py``. Speculation, integer pools and a
   disaggregated hand-off are refused for such a model.
 """
 
@@ -122,9 +124,10 @@ from deeplearning_mpi_tpu.models.moe import (
     routing_from_config,
 )
 from deeplearning_mpi_tpu.ops.latent_attention import (
-    absorbed_attention,
     chunk_attention,
+    paged_absorbed_attention,
 )
+from deeplearning_mpi_tpu.ops.pallas import latent_decode
 from deeplearning_mpi_tpu.ops.quant import dequantize_kv, quantize_kv
 from deeplearning_mpi_tpu.ops.sparse_attention import (
     attend_masked,
@@ -474,8 +477,17 @@ class PagedForward:
         Quantized storage also writes the per-row scales — data and scales
         land in ONE jitted program, which is what makes the pool's
         scale/block epoch check a real invariant rather than a race
-        window."""
+        window.
+
+        A latent model's pool takes ``c`` at K's place and ``k_pe`` at V's,
+        whose block holds its positions minor (``init_kv_buffers``)."""
         with annotate("attn/kv_scatter"):
+            if self.latent:
+                c_pool, kpe_pool = kv
+                return (
+                    c_pool.at[i, bid, off].set(k.astype(c_pool.dtype)),
+                    kpe_pool.at[i, bid, :, off].set(v.astype(kpe_pool.dtype)),
+                )
             if not self.quantized:
                 k_pool, v_pool, *index = kv
                 out = (
@@ -509,6 +521,10 @@ class PagedForward:
         copy of a layer's whole pool per K and V per layer in every
         program, 13 ms of a 37 ms decode step on the v5e (PERF.md, PR 28)."""
         with annotate("attn/kv_gather"):
+            if self.latent:
+                # (c, k_pe) pages, k_pe's positions brought back second-minor
+                c_pool, kpe_pool = kv
+                return c_pool[i, tables], jnp.swapaxes(kpe_pool[i, tables], -1, -2)
             if not self.quantized:
                 k_pool, v_pool = kv[:2]
                 return k_pool[i, tables], v_pool[i, tables]
@@ -755,9 +771,11 @@ class PagedForward:
 
         A latent model (:attr:`latent`) has a third: its layer scatters ONE
         latent row a position, ``c`` and ``k_pe`` where K and V would go,
-        and ``attend(q_nope, q_pe, c, k_pe, w_kvb)`` runs over the gathered
-        latent pages (``[rows, L, kv_rank]`` and ``[rows, L, rope]``) in the
-        program's own form, absorbed or expanded.
+        and ``attend(q_nope, q_pe, pools, j, tables, w_kvb)`` reads the
+        latent of the group's pools at its index ``j`` through the table in
+        the program's own form: absorbed, each row's live pages in place
+        (the decode step), or expanded over the gathered pages (the prefill
+        chunk).
 
         And two kinds of MLP: dense SwiGLU, or the dropless expert layer
         over the step's rows (a row that writes to the scratch block is
@@ -785,12 +803,8 @@ class PagedForward:
                     pools[g], j, bid, off, c.reshape(bid.shape + c.shape[-1:]),
                     k_pe.reshape(bid.shape + k_pe.shape[-1:]),
                 )
-                pages = [
-                    a.reshape((x.shape[0], span) + a.shape[-1:])
-                    for a in self._kv_gather(pools[g], j, tables)
-                ]
                 ctx = attend(
-                    q_nope, q_pe, *pages,
+                    q_nope, q_pe, pools[g], j, tables,
                     lp["attn"]["kv_b_proj"]["kernel"].reshape(
                         self.latent.kv_rank, cfg.num_heads, -1
                     ),
@@ -896,14 +910,12 @@ class PagedForward:
 
             if self.latent:
                 # one query a row: the absorbed form reads the latent once
-                # for every head
-                k_pos = jnp.arange(MB * BS, dtype=jnp.int32)
-                valid = (k_pos[None, :] <= idx[:, None])[:, None]  # [S, 1, L]
+                # for every head, each row's live pages in place
 
-                def attend(q_nope, q_pe, c, k_pe, w_kvb):
-                    return absorbed_attention(
-                        q_nope, q_pe, c, k_pe, w_kvb,
-                        scale=self.latent.scale, valid=valid,
+                def attend(q_nope, q_pe, kv, j, tables, w_kvb):
+                    return paged_absorbed_attention(
+                        q_nope, q_pe, *kv, j, tables, idx, w_kvb,
+                        scale=self.latent.scale,
                     )
             else:
                 def attend(q: jax.Array, k_seq: jax.Array, v_seq: jax.Array) -> jax.Array:
@@ -994,7 +1006,11 @@ class PagedForward:
                 # the chunk's queries share every table position's expanded
                 # keys and values: expand once, attend in a kernel that
                 # keeps its scores on chip (no [H, C, L] score tensor in HBM)
-                def attend(q_nope, q_pe, c, k_pe, w_kvb):
+                def attend(q_nope, q_pe, kv, j, table, w_kvb):
+                    c, k_pe = (
+                        a.reshape((1, -1) + a.shape[-1:])
+                        for a in self._kv_gather(kv, j, table)
+                    )
                     return chunk_attention(
                         q_nope, q_pe, c, k_pe, w_kvb, scale=self.latent.scale, start=start
                     )
@@ -1428,6 +1444,13 @@ class ServingEngine:
             self._decode_shapes = tuple(
                 s for s in self._decode_shapes if self._moe_form(s[0]) == form
             )
+        if config.latent:
+            # The latent decode kernel walks each row's live pages whatever
+            # the table's width, so a narrower rung saves nothing: one
+            # width, the full table, at every row bucket.
+            self._decode_shapes = tuple(sorted(
+                {(rows, engine.max_blocks_per_seq) for rows, _ in self._decode_shapes}
+            ))
         # KV-cache donation, vetoed where unsafe (XLA:CPU + persistent
         # compile cache — compiler.cache.donation_safe, reached through the
         # compat shim): the engine restores weights from disk and then runs
@@ -1616,6 +1639,14 @@ class ServingEngine:
                 self._metrics.histogram("serve_compile_seconds").observe(
                     prog.lower_seconds + prog.compile_seconds
                 )
+            # The latent decode kernel's calls in the widest decode program:
+            # one a layer of a latent model, none where its entry point fell
+            # back to XLA (or where the interpreter ran it).
+            self._metrics.gauge("serve_decode_kernel_calls").set(
+                aot.mosaic_call_count(
+                    programs["serve_decode_step"].compiled, kernel=latent_decode.NAME
+                )
+            )
         # The table is argument 2 of both programs: its shape picks the
         # executable, so a listed shape never falls through to the jit.
         def fell() -> None:

@@ -376,15 +376,19 @@ def init_kv_buffers(
     cached vector ``[c ; k_pe]`` a layer, every head's keys and values
     expanded from it, and no V pool; ``kv_heads`` and ``head_dim`` size
     nothing. It is kept as two arrays, ``(c, k_pe)`` of ``[num_layers,
-    num_blocks, block_size, kv_rank]`` and ``[..., rope]``, and not as one of
-    ``kv_rank + rope``: the TPU tiles an array's last two dims by (8, 128),
-    and a last dim of 576 (DeepSeek-V3's 512 + 64) is not a multiple of 128,
-    so XLA keeps such a pool in another layout and copies the whole of it
-    back and forth around every layer's scatter and gather (two 3.5 GB copies
-    a layer in a described-v5e compile of the decode step at 4,801 blocks of
-    128); ``c`` of 512 and ``k_pe`` of 64 tile as they are. The two arrays
-    take the places of K and V: one block table, one scatter and one gather
-    serve both.
+    num_blocks, block_size, kv_rank]`` and ``[num_layers, num_blocks, rope,
+    block_size]``, and not as one of ``kv_rank + rope``: the TPU tiles an
+    array's last two dims by (8, 128), and a last dim of 576 (DeepSeek-V3's
+    512 + 64) is not a multiple of 128, so XLA keeps such a pool in another
+    layout and copies the whole of it back and forth around every layer's
+    scatter and gather (two 3.5 GB copies a layer in a described-v5e compile
+    of the decode step at 4,801 blocks of 128). ``c`` of 512 tiles as it is;
+    a block of ``k_pe`` holds its positions minor (``[rope, block_size]``),
+    the layout XLA gave a ``[block_size, 64]`` block anyway (its 64 values
+    would fill half of each tile's 128 lanes), so that the decode kernel
+    (``ops/pallas/latent_decode.py``) copies a block of it as whole tiles.
+    The two arrays take the places of K and V: one block table serves
+    both.
 
     ``index_dim > 0`` (a model with learned sparse attention, float storage
     only) adds a THIRD pool, the indexer keys: ``(k, v, k_index)`` with
@@ -410,7 +414,11 @@ def init_kv_buffers(
     if latent_dims:
         if jnp.issubdtype(jnp.dtype(kv_dtype), jnp.integer):
             raise NotImplementedError("a latent pool in integer storage is not implemented")
-        return tuple(jnp.zeros((num_layers, num_blocks, block_size, n), kv_dtype) for n in latent_dims)
+        kv_rank, rope = latent_dims
+        return (
+            jnp.zeros((num_layers, num_blocks, block_size, kv_rank), kv_dtype),
+            jnp.zeros((num_layers, num_blocks, rope, block_size), kv_dtype),
+        )
     shape = (num_layers, num_blocks, block_size, kv_heads, head_dim)
     k = jnp.zeros(shape, kv_dtype)
     v = jnp.zeros(shape, kv_dtype)

@@ -59,6 +59,9 @@ METRICS: dict[str, tuple[str, frozenset[str]]] = {
     "serve_compile_total": ("counter", frozenset()),
     "serve_decode_held_steps": ("counter", frozenset()),
     "serve_decode_steps": ("counter", frozenset()),
+    # Mosaic (Pallas TPU) calls in the widest compiled decode program
+    # (ServingEngine.warmup): a latent model's decode kernel, one a layer.
+    "serve_decode_kernel_calls": ("gauge", frozenset()),
     # calls a warmed engine's programs handed to the jit net instead of their
     # executable (compiler/aot.py:WarmProgram; 0 on a correctly warmed engine
     # that does not speculate: the verify step and the draft's programs run

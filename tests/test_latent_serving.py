@@ -11,6 +11,7 @@ YaRN over an original length of 32."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import math
 import types
@@ -31,9 +32,15 @@ from deeplearning_mpi_tpu.models.transformer import (
     TransformerLM,
     yarn_inv_freq,
 )
-from deeplearning_mpi_tpu.ops.latent_attention import absorbed_attention, chunk_attention, expanded_attention
-from deeplearning_mpi_tpu.ops.pallas import latent_prefill
-from deeplearning_mpi_tpu.serving.engine import EngineConfig, PagedForward, ServingEngine
+from deeplearning_mpi_tpu.compiler import aot
+from deeplearning_mpi_tpu.ops.latent_attention import (
+    absorbed_attention,
+    chunk_attention,
+    expanded_attention,
+    paged_absorbed_attention,
+)
+from deeplearning_mpi_tpu.ops.pallas import latent_decode, latent_prefill
+from deeplearning_mpi_tpu.serving.engine import EngineConfig, PagedForward, ServingEngine, _table_shapes
 from deeplearning_mpi_tpu.serving.kv_pool import init_kv_buffers
 from deeplearning_mpi_tpu.telemetry.registry import MetricsRegistry
 
@@ -197,6 +204,90 @@ def test_the_prefill_chunk_kernel_is_the_expanded_attention(start, length, block
     np.testing.assert_allclose(np.asarray(through_module), np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
+# -- the decode kernel --------------------------------------------------------
+
+#: rows' lengths (0: an inactive row) and whether the pages lie in scrambled
+#: pool order; tables of 24 blocks of 4
+DECODE_CASES = {
+    "length-1": ([1], False),
+    "page-edge": ([2 * BS, 2 * BS + 1], False),
+    "full-table": ([24 * BS], False),
+    "inactive": ([7, 0], False),
+    "scrambled": ([13, 22, 7], True),
+    "16-rows": ([1, 4, 5, 9, 16, 17, 30, 0, 33, 47, 64, 65, 80, 95, 96, 2], True),
+}
+
+
+def _paged_case(lengths, scrambled, *, width=24, seed=0, blocks=400):
+    """Pools of 2 layers, tables of ``width`` blocks holding each row's pages
+    (``lengths``), ``last`` (-1 for an inactive row) and the operands."""
+    rng = np.random.default_rng(seed)
+    heads, nope, rope, v, kvr = 4, 8, 8, 12, 16
+    arr = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    ids = rng.permutation(np.arange(1, blocks)) if scrambled else np.arange(1, blocks)
+    tables, at = np.zeros((len(lengths), width), np.int32), 0
+    for r, n in enumerate(lengths):
+        pages = -(-n // BS)
+        tables[r, :pages], at = ids[at : at + pages], at + pages
+    return dict(
+        q_nope=arr(len(lengths), 1, heads, nope), q_pe=arr(len(lengths), 1, heads, rope),
+        c_pool=arr(2, blocks, BS, kvr), kpe_pool=arr(2, blocks, rope, BS),
+        tables=jnp.asarray(tables), last=jnp.asarray(np.asarray(lengths) - 1, jnp.int32),
+        w_kvb=arr(kvr, heads, nope + v),
+    )
+
+
+def _paged(case, layer=1):
+    return paged_absorbed_attention(
+        case["q_nope"], case["q_pe"], case["c_pool"], case["kpe_pool"], layer,
+        case["tables"], case["last"], case["w_kvb"], scale=0.3,
+    )
+
+
+@pytest.mark.parametrize("group", [2 * BS, latent_decode.GROUP_POSITIONS], ids=["two-page-groups", "one-group"])
+@pytest.mark.parametrize("case", DECODE_CASES)
+def test_the_decode_kernel_is_the_absorbed_attention(case, group, monkeypatch):
+    """The Pallas decode kernel (interpreted here, float32) walks each row's
+    live pages through its block table, in groups of two pages (a walk of
+    several groups, the last one half read) and in one group, against
+    :func:`absorbed_attention` over the table's gathered pages: a row of one
+    position, rows ending on a page's edge and one past it, a row that fills
+    its table, an inactive row (zeros), pages in scrambled pool order, 1 and
+    16 rows."""
+    monkeypatch.setattr(latent_decode, "latent_decode", functools.partial(latent_decode.latent_decode, group_positions=group))
+    lengths, scrambled = DECODE_CASES[case]
+    op = _paged_case(lengths, scrambled, seed=len(lengths) + sum(lengths))
+    rows, width = op["tables"].shape
+    c = op["c_pool"][1][op["tables"]].reshape(rows, width * BS, -1)
+    k_pe = jnp.swapaxes(op["kpe_pool"][1][op["tables"]], -1, -2).reshape(rows, width * BS, -1)
+    valid = (jnp.arange(width * BS)[None, :] <= op["last"][:, None])[:, None]
+    want = absorbed_attention(op["q_nope"], op["q_pe"], c, k_pe, op["w_kvb"], scale=0.3, valid=valid)
+    got = _paged(op)
+    assert got.shape == want.shape == (rows, 1, 4, 12)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+    assert not np.asarray(got)[np.asarray(lengths) == 0].any()
+
+
+def test_a_rows_decode_output_is_the_same_at_two_widths_and_beside_other_rows():
+    """Row independence with one decode width: a row's output, bit for bit,
+    first in a table of 8 blocks and last in one of 24, beside other rows
+    (the walk's groups are whole pages from the row's first whatever the
+    table's width). The batches have as many rows: XLA:CPU's products of
+    the absorption round a row by the batch's size."""
+    row = _paged_case([30, 0, 0], True, width=8, seed=3)
+    narrow = _paged_case([30, 5, 17], True, width=8, seed=5)
+    wide = _paged_case([11, 60, 30], True, width=24, seed=4)
+    for op, at in ((narrow, 0), (wide, 2)):  # the row's query, latent and pages in each batch
+        for name in ("q_nope", "q_pe"):
+            op[name] = op[name].at[at].set(row[name][0])
+        op["c_pool"], op["kpe_pool"] = row["c_pool"], row["kpe_pool"]
+        op["tables"] = op["tables"].at[at].set(0).at[at, :8].set(row["tables"][0])
+        op["w_kvb"] = row["w_kvb"]
+    alone = np.asarray(_paged(row))[0]
+    np.testing.assert_array_equal(np.asarray(_paged(narrow))[0], alone)
+    np.testing.assert_array_equal(np.asarray(_paged(wide))[2], alone)
+
+
 # -- the expert layer --------------------------------------------------------
 
 def _layer_weights(seed: int, experts: int, d: int = 16, f: int = 8):
@@ -292,10 +383,11 @@ def test_served_tokens_and_last_chunk_logits_are_the_references(params):
 
 def test_the_latent_pool_holds_one_vector_a_position(params):
     """``[c ; k_pe]`` a position a layer, in the two arrays where K and V
-    would be: 16 + 8 values, and no V pool."""
+    would be: 16 + 8 values, and no V pool; a block of ``k_pe`` holds its
+    positions minor."""
     engine = _engine(params)
     c, k_pe = engine._kv
-    assert c.shape == (3, ENGINE.num_blocks, BS, 16) and k_pe.shape == (3, ENGINE.num_blocks, BS, 8)
+    assert c.shape == (3, ENGINE.num_blocks, BS, 16) and k_pe.shape == (3, ENGINE.num_blocks, 8, BS)
     assert engine._kvh.nbytes == 3 * ENGINE.num_blocks * BS * (16 + 8) * 4
     assert init_kv_buffers(3, 8, BS, 4, 16, jnp.float32, latent_dims=(16, 8))[0].shape == (3, 8, BS, 16)
     with pytest.raises(NotImplementedError, match="integer storage"):
@@ -335,6 +427,34 @@ def test_zero_compiles_after_warmup(params):
     assert all(len(t) == 30 for t in served)
     assert registry.snapshot()["serve_compile_total"] == compiled
     assert engine._decode_fn.fallback_calls == 0 and engine._prefill_fn.fallback_calls == 0
+
+
+def test_a_latent_engine_warms_one_decode_width_at_each_row_bucket(params):
+    """A latent model's decode programs take the full table at every row
+    bucket (the kernel walks live pages whatever the width): 3 decode
+    programs (here every bucket takes the expert layer's grouped form: top-1
+    of 16) and the 4 prefill widths; the widest decode program's kernel
+    calls are the gauge ``serve_decode_kernel_calls`` (Mosaic calls: the
+    interpreter here makes none; a described-v5e compile of the program
+    counts one a layer, ``tests/test_loss.py``). A dense model's ladders are
+    ``_table_shapes``' as ever, and its gauge reads 0."""
+    registry = MetricsRegistry()
+    engine = _engine(params, model=dataclasses.replace(MODEL, moe_top_k=1), registry=registry)
+    programs = engine.warmup()
+    assert engine._decode_shapes == ((1, 24), (2, 24), (4, 24)) and len(engine._widths) == 4
+    assert sorted(programs) == sorted(
+        ["serve_decode_step@1x24", "serve_decode_step@2x24", "serve_decode_step"]
+        + ["serve_prefill_chunk@4", "serve_prefill_chunk@8", "serve_prefill_chunk@16", "serve_prefill_chunk"]
+    )
+    widest = programs["serve_decode_step"].compiled
+    assert registry.snapshot()["serve_decode_kernel_calls"] == aot.mosaic_call_count(widest, kernel=latent_decode.NAME)
+    dense_cfg = TransformerConfig.tiny()
+    dense_params = TransformerLM(dense_cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    dense_registry, small = MetricsRegistry(), dataclasses.replace(ENGINE, max_slots=1, max_blocks_per_seq=2)
+    dense = ServingEngine(dense_cfg, dense_params, small, dtype=jnp.float32, registry=dense_registry)
+    assert (dense._widths, dense._decode_shapes) == _table_shapes(1, 2) == ((1, 2), ((1, 1), (1, 2)))
+    dense.warmup()
+    assert dense_registry.snapshot()["serve_decode_kernel_calls"] == 0
 
 
 def test_the_launch_labels_and_counters(params, monkeypatch):

@@ -358,14 +358,37 @@ def test_one_chip_train_step_hlo_is_unchanged_by_the_tpu_options(v5e_2x2, monkey
     assert texts[0] == texts[1]
 
 
-def test_the_latent_decode_step_compiles_for_v5e_without_copying_its_pool(one_chip):
-    """The serving engine's decode step of a latent-attention model at
-    ``kimi-serve-long``'s widths, pool and table (2 of its layers): the
-    latent pool's two arrays, ``c`` of 512 and ``k_pe`` of 64, are scattered
-    into and gathered from in place. Kept as ONE array of 576 a position,
-    not a multiple of the chip's 128-wide tiles, the compiler held the pool
-    in another layout and copied the whole of it back and forth around
-    every layer's scatter and gather."""
+#: the latent decode program's described-v5e compile: rows, table width,
+#: block size and latent width of ``kimi-serve-long``, and 2 of its layers
+KIMI_ROWS, KIMI_WIDTH, KIMI_BS, KIMI_LAYERS = 16, 576, 128, 2
+
+#: a child process lowers that program for the TPU (lowering needs no chip)
+#: and prints its text's digest, the kernel's lowered bodies and call sites
+_LOWER_LATENT_DECODE = """
+import hashlib, json
+import jax, jax.numpy as jnp
+from benchmark.kimi import program, weights
+from benchmark.manifest import ROOT
+from deeplearning_mpi_tpu.ops.pallas import latent_decode
+from deeplearning_mpi_tpu.serving.engine import EngineConfig, PagedForward
+from deeplearning_mpi_tpu.serving.kv_pool import init_kv_buffers
+latent_decode._on_tpu = lambda: True
+cfg = {**json.loads((ROOT / "benchmark/configs/kimi-k2.7-code-l5.json").read_text()), "num_hidden_layers": 2}
+engine = EngineConfig(**{k: v for k, v in cfg["engine"].items() if k != "why"})
+params = jax.eval_shape(lambda: weights.build(cfg, weights.seed_words(1), jnp.bfloat16))
+pools = jax.eval_shape(lambda: init_kv_buffers(2, 4801, 128, 64, 192, jnp.bfloat16, latent_dims=(512, 64)))
+i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+fwd = PagedForward(program.model_config(cfg), engine, jnp.bfloat16, window_cut=True)
+text = jax.jit(fwd.decode_step, donate_argnums=(1,)).trace(
+    params, pools, i32(16, 576), i32(16), i32(16), jax.ShapeDtypeStruct((16,), jnp.bool_),
+).lower(lowering_platforms=("tpu",)).as_text()
+print(hashlib.sha256(text.encode()).hexdigest(), text.count("tpu_custom_call"), text.count("call @latent_decode"))
+"""
+
+
+def _latent_decode_args(sharding):
+    """The decode step of ``kimi-serve-long``'s engine (:data:`KIMI_LAYERS`
+    of its layers) and its arguments as shapes on ``sharding``."""
     import json
 
     from benchmark.kimi import program, weights
@@ -373,22 +396,124 @@ def test_the_latent_decode_step_compiles_for_v5e_without_copying_its_pool(one_ch
     from deeplearning_mpi_tpu.serving.engine import EngineConfig, PagedForward
     from deeplearning_mpi_tpu.serving.kv_pool import init_kv_buffers
 
-    cfg = {**json.loads((ROOT / "benchmark/configs/kimi-k2.7-code-l5.json").read_text()), "num_hidden_layers": 2}
-    model = program.model_config(cfg)
+    cfg = {**json.loads((ROOT / "benchmark/configs/kimi-k2.7-code-l5.json").read_text()), "num_hidden_layers": KIMI_LAYERS}
     engine = EngineConfig(**{k: v for k, v in cfg["engine"].items() if k != "why"})
-    on_chip = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)  # noqa: E731
-    params = on_chip(jax.eval_shape(lambda: weights.build(cfg, weights.seed_words(1), jnp.bfloat16)))
-    pools = on_chip(jax.eval_shape(lambda: init_kv_buffers(2, 4801, 128, 64, 192, jnp.bfloat16, latent_dims=(512, 64))))
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)  # noqa: E731
-    fwd = PagedForward(model, engine, jnp.bfloat16, window_cut=True)
-    with _no_compile_cache():
-        compiled = jax.jit(fwd.decode_step, donate_argnums=(1,)).lower(
-            params, pools, i32(16, 576), i32(16), i32(16), jax.ShapeDtypeStruct((16,), jnp.bool_, sharding=one_chip),
-        ).compile()
-    text = compiled.as_text()
+    placed = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)  # noqa: E731
+    params = placed(jax.eval_shape(lambda: weights.build(cfg, weights.seed_words(1), jnp.bfloat16)))
+    pools = placed(jax.eval_shape(
+        lambda: init_kv_buffers(KIMI_LAYERS, engine.num_blocks, KIMI_BS, 64, 192, jnp.bfloat16, latent_dims=(512, 64))
+    ))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)  # noqa: E731
+    fwd = PagedForward(program.model_config(cfg), engine, jnp.bfloat16, window_cut=True)
+    return fwd.decode_step, (
+        params, pools, i32(KIMI_ROWS, KIMI_WIDTH), i32(KIMI_ROWS), i32(KIMI_ROWS),
+        jax.ShapeDtypeStruct((KIMI_ROWS,), jnp.bool_, sharding=sharding),
+    )
+
+
+@pytest.fixture(scope="module")
+def latent_decode_v5e(one_chip):
+    """The serving engine's decode step of a latent-attention model at
+    ``kimi-serve-long``'s widths, pool and table, compiled for a described
+    v5e with its attention kernel in Mosaic (this process has no TPU, so
+    the program would otherwise interpret it)."""
+    from deeplearning_mpi_tpu.ops.pallas import latent_decode
+
+    step, args = _latent_decode_args(one_chip)
+    with pytest.MonkeyPatch.context() as mp, _no_compile_cache():
+        mp.setattr(latent_decode, "_on_tpu", lambda: True)
+        return jax.jit(step, donate_argnums=(1,)).lower(*args).compile()
+
+
+def test_the_latent_decode_step_compiles_for_v5e_without_copying_its_pool(latent_decode_v5e):
+    """The latent pool's two arrays, ``c`` of 512 and ``k_pe`` of 64 (its
+    blocks with their positions minor), are scattered into and read from in
+    place. Kept as ONE array of 576 a position, not a multiple of the chip's
+    128-wide tiles, the compiler held the pool in another layout and copied
+    the whole of it back and forth around every layer's scatter and
+    gather."""
+    text = latent_decode_v5e.as_text()
     text = text[text.index("\nENTRY "):]
     copies = [line for line in text.splitlines() if " copy(" in line]
-    assert not [c for c in copies if "bf16[2,4801,128," in c]  # neither of the pool's arrays
+    assert not [c for c in copies if "bf16[2,4801," in c]  # neither of the pool's arrays
+
+
+def test_the_latent_decode_step_calls_its_kernel_at_every_layer_on_v5e(latent_decode_v5e):
+    """Mosaic takes the decode kernel (``ops/pallas/latent_decode.py``) at
+    each latent layer: what the engine's gauge ``serve_decode_kernel_calls``
+    reads off its widest decode program."""
+    from deeplearning_mpi_tpu.compiler import aot
+    from deeplearning_mpi_tpu.ops.pallas import latent_decode
+
+    assert "tpu_custom_call" in latent_decode_v5e.as_text()
+    assert aot.mosaic_call_count(latent_decode_v5e, kernel=latent_decode.NAME) == KIMI_LAYERS
+
+
+def test_the_latent_decode_steps_temporaries_hold_no_page_rectangle_on_v5e(latent_decode_v5e):
+    """The kernel reads the pages in place: the program's temporaries stay
+    under ONE ``[rows, width x BS, 512]`` rectangle of latent pages, which
+    the absorbed form in XLA gathered into HBM (nine times a step)."""
+    rectangle = KIMI_ROWS * KIMI_WIDTH * KIMI_BS * 512 * 2
+    assert latent_decode_v5e.memory_analysis().temp_size_in_bytes < rectangle
+
+
+def test_the_latent_decode_step_lowers_to_one_text_under_any_hash_seed():
+    """The persistent compile cache's key is the lowered program: two
+    processes with different ``PYTHONHASHSEED`` lower the decode step (at
+    the cell's widths, for the TPU) to the same text, so a warm start finds
+    what a cold one compiled. In it the Mosaic kernel is lowered once and
+    called at each layer."""
+    import subprocess
+    import sys
+
+    from benchmark.manifest import ROOT
+
+    children = [
+        subprocess.Popen(
+            [sys.executable, "-c", _LOWER_LATENT_DECODE], cwd=ROOT, stdout=subprocess.PIPE, text=True,
+            env={**os.environ, "PYTHONHASHSEED": seed, "JAX_PLATFORMS": "cpu", "PYTHONPATH": str(ROOT)},
+        )
+        for seed in ("1", "2")
+    ]
+    outs = [child.communicate(timeout=600)[0].split() for child in children]
+    assert [child.returncode for child in children] == [0, 0]
+    assert outs[0] == outs[1] and outs[0][1:] == ["1", str(KIMI_LAYERS)]
+
+
+def test_the_decode_kernel_is_traced_once_a_shape_in_a_latent_engines_warmup(monkeypatch):
+    """A small latent engine's warm-up (3 latent layers, 3 decode programs:
+    one a row bucket) traces the kernel's body once a program, not once a
+    layer: every layer calls the one jitted kernel with the same shapes."""
+    import json
+
+    from benchmark.kimi import program, weights
+    from benchmark.manifest import ROOT
+    from deeplearning_mpi_tpu.ops.pallas import latent_decode
+    from deeplearning_mpi_tpu.serving.engine import EngineConfig, ServingEngine
+
+    kimi = json.loads((ROOT / "benchmark/configs/kimi-k2.7-code-l5.json").read_text())
+    cfg = {
+        **kimi, "hidden_size": 32, "intermediate_size": 64, "num_attention_heads": 4, "num_key_value_heads": 4,
+        "q_lora_rank": 16, "kv_lora_rank": 16, "qk_nope_head_dim": 8, "qk_rope_head_dim": 8, "v_head_dim": 12,
+        "moe_intermediate_size": 16, "n_routed_experts": 4, "router_experts": 16, "experts_first": 4,
+        "num_experts_per_tok": 1, "num_hidden_layers": 3, "vocab_size": 64, "torch_dtype": "float32",
+    }
+    traced = []
+    kernel = latent_decode._kernel
+
+    def counted(*refs, **kw):
+        traced.append(kw)
+        return kernel(*refs, **kw)
+
+    monkeypatch.setattr(latent_decode, "_kernel", counted)
+    # a pool of 44 blocks no other test builds: no trace of the kernel is cached
+    engine = ServingEngine(
+        program.model_config(cfg), weights.build(cfg, weights.seed_words(44), jnp.float32),
+        EngineConfig(max_slots=4, block_size=4, num_blocks=44, max_blocks_per_seq=8, prefill_chunk=8),
+        dtype=jnp.float32,
+    )
+    engine.warmup()
+    assert len(engine._decode_shapes) == 3 and len(traced) == 3  # 9 were it traced a layer
 
 
 def test_the_latent_prefill_kernel_compiles_for_v5e_at_the_cells_widths(one_chip):
